@@ -280,7 +280,7 @@ def _text_first_pruned(
     so hot-term queries both move fewer bytes and keep better candidates
     than the unpruned head-of-list truncation.
     """
-    from repro.kernels.text_probe.ops import impact_planes, window_size
+    from repro.kernels.text_probe.ops import window_size
 
     if fused:
         from repro.kernels.text_probe.ops import text_probe_pruned as _pr
@@ -294,10 +294,11 @@ def _text_first_pruned(
     n_win = window_size(mtb)
     Cs = min(budgets.max_candidates, n_win * tidx.POSTING_BLOCK)
     # query-independent inputs, hoisted out of the per-query vmap: the
-    # block-major impact plane and the geo/pagerank remainder bounds.
+    # geo/pagerank remainder bounds (the block-major impact plane is
+    # built with the index).
     # geo: combine_scores adds w_geo·g/max(qm, ε) with g ≤ qm·Σ_r amp_r
     # (area(r ∩ q_s) ≤ area(q_s)), so the normalized term is ≤ w_geo·Σ amps.
-    plane = impact_planes(text.impacts, text.blk_pos, text.blk_len)
+    plane = text.imp_plane
     amp_sum_max = jnp.max(
         jnp.sum(spatial.doc_amps.astype(jnp.float32), axis=-1), initial=0.0
     )
@@ -754,12 +755,33 @@ def oracle(
     k: int,
     weights: ranking.RankWeights = ranking.RankWeights(),
 ) -> TopKResult:
-    """Exact top-k by scoring *every* document (no budgets).  O(N) per query."""
+    """Exact top-k by scoring *every* document (no budgets).  O(N) per query.
+
+    Term at a time: each query term's whole posting list is scattered into
+    dense per-document hit counts and impact sums, so the text side costs
+    the query's postings rather than one binary-search probe (and block
+    decode) per document and term.  Impacts are added in term order, as
+    :func:`text_index.text_score_of_docs` adds them, so text scores are
+    the same bits.
+    """
     N = spatial.n_docs
     all_docs = jnp.arange(N, dtype=jnp.int32)
 
     def one(terms, q_rects, q_amps):
-        match, tscore = tidx.text_score_of_docs(text, terms, all_docs)
+        def add_term(i, carry):
+            hits, tscore = carry
+            t = terms[i]
+            docs, imp, live = tidx.term_postings(text, jnp.maximum(t, 0))
+            slot = jnp.where(live & (t >= 0), docs, N).reshape(-1)  # N: dropped
+            hits = hits.at[slot].add(1, mode="drop")
+            tscore = tscore.at[slot].add(imp.reshape(-1), mode="drop")
+            return hits, tscore
+
+        hits, tscore = jax.lax.fori_loop(
+            0, terms.shape[0], add_term,
+            (jnp.zeros((N,), jnp.int32), jnp.zeros((N,), jnp.float32)),
+        )
+        match = hits == jnp.sum((terms >= 0).astype(jnp.int32))
         g = fp.geo_score(spatial.doc_rects, spatial.doc_amps, q_rects, q_amps)
         qm = fp.query_mass(q_rects, q_amps)
         score = ranking.combine_scores(weights, tscore, g, pagerank, qm)

@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import algorithms as alg
 from repro.core import ranking
@@ -315,6 +315,7 @@ class ShardedGeoIndex:
     blk_len: jax.Array  # i32[S, NBt]
     blk_pos: jax.Array  # i32[S, NBt]
     blk_max_impact: jax.Array  # f32[S, NBt] post-quantization block maxima
+    imp_plane: jax.Array  # [S, NBt, 128] block-major impacts
     blk_term_off: jax.Array  # i32[S, M+1]
     # impact-ordered segment CSR (degenerate under layout="docid")
     seg_term_off: jax.Array  # i32[S, M+1]
@@ -399,8 +400,11 @@ def shard_corpus_np(
         # broadcast global term statistics (IDF) so shards rank like the
         # single-index engine would — built in directly (not rescaled after
         # the fact) so impacts are bit-identical across partitionings
+        # impacts stored as the single-index engine stores them (f16 under
+        # any compressed mode), so a shard scores like GeoSearchEngine
         text = build_text_index_np(
             terms, n_terms, idf=idf_global, compress=(mode != "none"),
+            impact_dtype=(np.float16 if mode != "none" else None),
             layout=layout,
         )
         spatial = build_spatial_index_np(
@@ -463,6 +467,9 @@ def shard_corpus_np(
     stacked["blk_pos"] = np.stack([padded(s[0].blk_pos, NBt_max, 0) for s in shards])
     stacked["blk_max_impact"] = np.stack(
         [padded(s[0].blk_max_impact, NBt_max, 0.0) for s in shards]
+    )
+    stacked["imp_plane"] = np.stack(
+        [padded(s[0].imp_plane, NBt_max, 0.0) for s in shards]
     )
     stacked["blk_term_off"] = np.stack(
         [np.asarray(s[0].blk_term_off) for s in shards]
@@ -528,6 +535,7 @@ def shard_corpus_np(
         blk_len=jnp.asarray(stacked["blk_len"]),
         blk_pos=jnp.asarray(stacked["blk_pos"]),
         blk_max_impact=jnp.asarray(stacked["blk_max_impact"]),
+        imp_plane=jnp.asarray(stacked["imp_plane"]),
         blk_term_off=jnp.asarray(stacked["blk_term_off"]),
         seg_term_off=jnp.asarray(stacked["seg_term_off"]),
         seg_pos=jnp.asarray(stacked["seg_pos"]),
@@ -574,7 +582,8 @@ def sharded_index_specs(
         postings=lead, impacts=lead, offsets=lead,
         post_packed=lead, blk_first=lead, blk_bits=lead, blk_len=lead,
         blk_word_off=lead, blk_n_exc=lead, blk_pos=lead, blk_max_impact=lead,
-        blk_term_off=lead, seg_term_off=lead, seg_pos=lead, seg_len=lead,
+        imp_plane=lead, blk_term_off=lead, seg_term_off=lead, seg_pos=lead,
+        seg_len=lead,
         tp_rects=lead, tp_amps=lead, tp_doc_ids=lead, tp_amp_scale=lead,
         tile_starts=lead, tile_ends=lead,
         doc_rects=lead, doc_amps=lead, doc_mbr=lead, doc_mass=lead,
@@ -658,6 +667,7 @@ def make_serve_fn(
             blk_word_off=idx.blk_word_off[0], blk_n_exc=idx.blk_n_exc[0],
             blk_pos=idx.blk_pos[0],
             blk_max_impact=idx.blk_max_impact[0],
+            imp_plane=idx.imp_plane[0],
             blk_term_off=idx.blk_term_off[0],
             seg_term_off=idx.seg_term_off[0], seg_pos=idx.seg_pos[0],
             seg_len=idx.seg_len[0],
@@ -761,6 +771,6 @@ def make_serve_fn(
         mesh=mesh,
         in_specs=(idx_specs, q_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped)

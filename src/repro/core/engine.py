@@ -192,11 +192,14 @@ class GeoSearchEngine:
 
     def oracle(self, batch: alg.QueryBatch, k: int | None = None) -> alg.TopKResult:
         k = k or self.budgets.top_k
-        return jax.jit(
-            lambda idx, b: alg.oracle(
-                idx.text, idx.spatial, idx.pagerank, b, k, self.weights
+        cache = self.__dict__.setdefault("_oracle_fns", {})
+        if k not in cache:  # one program per k, not one per call
+            cache[k] = jax.jit(
+                lambda idx, b: alg.oracle(
+                    idx.text, idx.spatial, idx.pagerank, b, k, self.weights
+                )
             )
-        )(self.index, batch)
+        return cache[k](self.index, batch)
 
     def _compiled(self, plan: QueryPlan, kw_key) -> Callable:
         """Plan-keyed compiled-function cache (one jit program per plan)."""

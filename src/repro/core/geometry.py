@@ -17,6 +17,11 @@ import numpy as np
 
 EMPTY_RECT = np.array([1.0, 1.0, 0.0, 0.0], dtype=np.float32)  # x1 < x0 => empty
 
+# worker threads of the vectorised host builders (numpy releases the GIL in
+# their kernels): fixed, so their temporaries stay bounded on a host that
+# reports hundreds of cores
+HOST_THREADS = 8
+
 
 # ---------------------------------------------------------------------------
 # Rectangle math (jit-safe)

@@ -46,6 +46,7 @@ lossy compression.  ``block_size`` must divide the Pallas streaming tile
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import jax
@@ -53,7 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import geometry
-from repro.core.footprint import footprint_mbr_np
 
 INVALID = np.int32(2**31 - 1)
 SCALE_BLOCK = 128  # toe prints per int8 amplitude-scale block (= kernel lanes)
@@ -191,27 +191,21 @@ def build_spatial_index_np(
     rects, amps, doc_idx = rects[order], amps[order], doc_idx[order]
     T = len(rects)
 
-    # Tile grid: toe-print IDs intersecting each tile, compressed to m intervals.
-    tile_starts = np.full((grid * grid, m_intervals), INVALID, dtype=np.int32)
-    tile_ends = np.full((grid * grid, m_intervals), INVALID, dtype=np.int32)
+    # Tile grid: toe-print IDs intersecting each tile, compressed to m
+    # intervals.  The host's cost is one step per (tile, toe print) pair —
+    # ~1.6e11 for one chip's share of geoweb at grid 1024 — so on a TPU the
+    # table comes from the patch-walking kernel, which returns the same
+    # arrays (tests/test_builders.py).
+    if jax.default_backend() == "tpu":
+        from repro.kernels.tile_intervals.ops import tile_intervals
 
-    # enumerate (tile, toeprint) pairs
-    x0, y0, x1, y1 = geometry.rect_cell_bounds_np(rects, grid)
-    tile_lists: dict[int, list[int]] = {}
-    for t in range(T):
-        for ty in range(y0[t], y1[t] + 1):
-            base = ty * grid
-            for tx in range(x0[t], x1[t] + 1):
-                tile_lists.setdefault(base + tx, []).append(t)
-
-    for tile, ids in tile_lists.items():
-        ivs = _coalesce_to_m(np.asarray(ids, dtype=np.int64), m_intervals)
-        for j, (s, e) in enumerate(ivs):
-            tile_starts[tile, j] = s
-            tile_ends[tile, j] = e
+        cells = geometry.rect_cell_bounds_np(rects, grid)
+        tile_starts, tile_ends = tile_intervals(*cells, grid, m_intervals)
+    else:
+        tile_starts, tile_ends = tile_intervals_np(rects, grid, m_intervals)
 
     # doc-major mirrors
-    mbr = np.stack([footprint_mbr_np(doc_rects[i]) for i in range(N)], axis=0)
+    mbr = doc_mbr_np(doc_rects)
     area = np.maximum(doc_rects[:, :, 2] - doc_rects[:, :, 0], 0) * np.maximum(
         doc_rects[:, :, 3] - doc_rects[:, :, 1], 0
     )
@@ -303,32 +297,110 @@ def block_metadata_np(
     )
 
 
-def _coalesce_to_m(ids: np.ndarray, m: int) -> list[tuple[int, int]]:
-    """Cover sorted toe-print IDs with ≤ m [start, end) intervals.
+def doc_mbr_np(doc_rects: np.ndarray) -> np.ndarray:
+    """Per-doc MBR over its non-empty rects — f32[N, 4]; docs without a
+    footprint get ``EMPTY_RECT`` (as :func:`footprint_mbr_np` per doc)."""
+    valid = doc_rects[:, :, 2] > doc_rects[:, :, 0]
+    inf = np.float32(np.inf)
+    lo = np.where(valid[..., None], doc_rects[..., :2], inf).min(axis=1)
+    hi = np.where(valid[..., None], doc_rects[..., 2:], -inf).max(axis=1)
+    mbr = np.concatenate([lo, hi], axis=1).astype(np.float32)
+    mbr[~valid.any(axis=1)] = geometry.EMPTY_RECT
+    return mbr
 
-    Greedy-optimal: cut at the m−1 largest gaps (minimizes covered slack).
+
+def tile_intervals_np(
+    rects: np.ndarray, grid: int, m: int
+) -> tuple[np.ndarray, np.ndarray]:
+    """Per tile, ≤ ``m`` toe-print-ID intervals covering every toe print
+    that intersects it — ``(tile_starts, tile_ends)`` i32[grid², m].
+
+    ``rects`` are the Morton-ordered toe prints (ID = row).  A tile's
+    sorted ID set is cut at its ``m − 1`` largest gaps (the first of equal
+    gaps wins; gaps of 1 are never cut), which minimizes covered slack;
+    tiles no toe print touches stay ``INVALID``.
+
+    Vectorised per unit of one grid row × ``_COLS`` columns: every
+    (column, ID) pair of the unit is materialized, ordered by column with
+    a stable radix sort (IDs stay ascending within a column), and each
+    column's gaps are reduced with segmented maxima.  The work is the
+    number of (tile, toe print) pairs, spread over a thread pool (numpy
+    releases the GIL in these kernels); units bound the memory per thread.
     """
-    if len(ids) == 0:
-        return []
-    ids = np.unique(ids)
-    if len(ids) == 1:
-        return [(int(ids[0]), int(ids[0]) + 1)]
-    gaps = np.diff(ids)
-    n_cuts = min(m - 1, len(gaps))
-    if n_cuts > 0:
-        cut_pos = np.argsort(-gaps, kind="stable")[:n_cuts]
-        # only cut where the gap is > 1 (else no benefit)
-        cut_pos = cut_pos[gaps[cut_pos] > 1]
-        cut_pos = np.sort(cut_pos)
-    else:
-        cut_pos = np.array([], dtype=np.int64)
-    bounds = np.concatenate([[-1], cut_pos, [len(ids) - 1]])
-    out = []
-    for i in range(len(bounds) - 1):
-        s = int(ids[bounds[i] + 1])
-        e = int(ids[bounds[i + 1]]) + 1
-        out.append((s, e))
-    return out
+    G = grid
+    starts = np.full((G * G, m), INVALID, dtype=np.int32)
+    ends = np.full((G * G, m), INVALID, dtype=np.int32)
+    x0, y0, x1, y1 = geometry.rect_cell_bounds_np(rects, G)
+    live = (x1 >= x0) & (y1 >= y0)
+    x0, y0, x1, y1 = (v.astype(np.int32) for v in (x0, y0, x1, y1))
+    band = 16  # grid rows whose candidate IDs are selected together
+
+    def do_band(r0):
+        r1 = min(r0 + band, G)
+        cand = np.flatnonzero((y0 < r1) & (y1 >= r0) & live).astype(np.int32)
+        cy0, cy1, cx0, cx1 = y0[cand], y1[cand], x0[cand], x1[cand]
+        for ty in range(r0, r1):
+            on_row = (cy0 <= ty) & (cy1 >= ty)
+            ids, rx0, rx1 = cand[on_row], cx0[on_row], cx1[on_row]
+            for c0 in range(0, G, _COLS):
+                c1 = min(c0 + _COLS, G)
+                sel = (rx0 < c1) & (rx1 >= c0)
+                if sel.any():
+                    a = np.maximum(rx0[sel], c0)
+                    w = np.minimum(rx1[sel], c1 - 1) - a + 1
+                    tiles, s, e = _unit_intervals(ids[sel], a - c0, w, m)
+                    starts[ty * G + c0 + tiles] = s
+                    ends[ty * G + c0 + tiles] = e
+
+    with ThreadPoolExecutor(geometry.HOST_THREADS) as pool:
+        list(pool.map(do_band, range(0, G, band)))
+    return starts, ends
+
+
+_COLS = 256  # grid columns per unit: a column fits in uint8 (radix sort)
+
+
+def _unit_intervals(ids, a, w, m):
+    """Intervals of one unit's tiles (see :func:`tile_intervals_np`).
+
+    ``ids`` ascending, covering local columns ``[a, a + w)``.  Returns the
+    unit's non-empty local columns and their ``[m]`` starts / ends.
+    """
+    p = int(w.sum())
+    off = np.cumsum(w) - w
+    col = np.repeat(a - off, w) + np.arange(p, dtype=np.int32)
+    order = np.argsort(col.astype(np.uint8), kind="stable")
+    tid = np.repeat(ids, w)[order]
+    cnt = np.bincount(col, minlength=_COLS)
+    cols = np.flatnonzero(cnt)
+    seg_end = np.cumsum(cnt)[cols]
+    seg = seg_end - cnt[cols]
+    # key per gap after pair i: (gap << 32) | ~i — the max picks the
+    # largest gap, and among equal gaps the first; -1 where a column ends
+    gk = np.full((p,), -1 << 32, np.int64)
+    gk[:-1] = (tid[1:] - tid[:-1]).astype(np.int64) << 32
+    gk |= 0xFFFFFFFF - np.arange(p, dtype=np.int64)
+    gk[seg_end - 1] = -1 << 32
+    ncut = max(m - 1, 1)
+    cuts = np.full((len(cols), ncut), p, np.int64)
+    for c in range(m - 1):
+        mx = np.maximum.reduceat(gk, seg)
+        ok = (mx >> 32) > 1
+        first = 0xFFFFFFFF - (mx & 0xFFFFFFFF)
+        cuts[ok, c] = first[ok]
+        gk[first[ok]] = -1 << 32
+    cuts.sort(axis=1)
+    has = cuts < p
+    safe = np.where(has, cuts, 0)
+    tid = tid.astype(np.int64)
+    s = np.full((len(cols), m), INVALID, np.int64)
+    e = np.full((len(cols), m), INVALID, np.int64)
+    s[:, 0] = tid[seg]
+    if m > 1:
+        s[:, 1:] = np.where(has, tid[np.minimum(safe + 1, p - 1)], INVALID)
+    e[:, :ncut] = np.where(has, tid[safe] + 1, INVALID)
+    e[np.arange(len(cols)), has.sum(axis=1)] = tid[seg_end - 1] + 1
+    return cols, s.astype(np.int32), e.astype(np.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -79,11 +79,14 @@ the modeled ``posting_bytes`` (see the property) is what actually streams.
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.geometry import HOST_THREADS
 
 BLOCK = 128  # docs per bitmap block
 WORDS_PER_BLOCK = BLOCK // 32
@@ -130,6 +133,9 @@ class TextIndex:
     blk_max_impact: jax.Array  # f32[NB]
     # PForDelta exception words per block ([0] when uncompressed)
     blk_n_exc: jax.Array  # i32[NB]
+    # block-major copy of the stored impacts, zero past blk_len: the rows
+    # the pruned TEXT-FIRST probe copies block by block (impact_plane_np)
+    imp_plane: jax.Array  # [NB, POSTING_BLOCK] impact dtype
     # impact-ordered segment CSR (degenerate under layout="docid": the
     # probes never read it, so it stays one zero entry)
     seg_term_off: jax.Array  # i32[M+1] CSR of impact segments per term
@@ -217,13 +223,40 @@ def block_max_impacts_np(
     """
     NB = blk_pos.shape[0]
     out = np.zeros((NB,), np.float32)
-    P = int(np.sum(blk_len))
-    if P > 0:
+    live = blk_len > 0
+    if live.any():
         # blocks tile the CSR contiguously and in order in both layouts,
-        # so posting p belongs to the block repeated at position p
-        bid = np.repeat(np.arange(NB), blk_len)
-        np.maximum.at(out, bid, np.asarray(impacts[:P]).astype(np.float32))
+        # so each live block is the run starting at its blk_pos
+        v = np.asarray(impacts).astype(np.float32)
+        mx = np.maximum.reduceat(v, blk_pos[live].astype(np.int64))
+        out[live] = np.maximum(mx, 0.0)
     return out
+
+
+def impact_plane_np(
+    impacts: np.ndarray, blk_pos: np.ndarray, blk_len: np.ndarray
+) -> np.ndarray:
+    """Block-major impact plane — ``[NB, POSTING_BLOCK]`` in the impact dtype.
+
+    Row b holds block b's impacts (``impacts[blk_pos[b] : +blk_len[b]]``)
+    zero-padded past ``blk_len``.  Built once with the index: gathering it
+    inside every query call is a gather of the whole posting store.
+    """
+    v = np.asarray(impacts)
+    NB = blk_pos.shape[0]
+    plane = np.zeros((NB, POSTING_BLOCK), v.dtype)
+    j = np.arange(POSTING_BLOCK, dtype=np.int64)
+
+    def rows(b0):
+        b1 = min(b0 + _PACK_CHUNK, NB)
+        idx = blk_pos[b0:b1, None].astype(np.int64) + j[None, :]
+        live = j[None, :] < blk_len[b0:b1, None]
+        plane[b0:b1] = np.where(live, v[np.where(live, idx, 0)], 0)
+
+    if v.shape[0]:
+        with ThreadPoolExecutor(HOST_THREADS) as pool:
+            list(pool.map(rows, range(0, NB, _PACK_CHUNK)))
+    return plane
 
 
 def _empty_pack(offsets: np.ndarray) -> dict[str, np.ndarray]:
@@ -237,24 +270,59 @@ def _empty_pack(offsets: np.ndarray) -> dict[str, np.ndarray]:
     )
 
 
-def _pfor_width_np(real_deltas: np.ndarray) -> tuple[int, int]:
-    """Pick a block's PForDelta base width — ``(bits, n_exc)``.
+_PACK_CHUNK = 1 << 11  # blocks per vectorised packing step (bounds temporaries)
+
+
+def _chunk_deltas_np(postings, blk_pos, blk_len):
+    """Doc-id deltas of a contiguous run of blocks, flat over its postings.
+
+    Blocks tile the CSR contiguously and in order, so the run's postings
+    are one slice.  Returns ``(block i64[n], slot i64[n], delta i64[n])``:
+    each block's slot 0 stores 0 (its first id lives in ``blk_first``),
+    the others the gap to the previous id.
+    """
+    p0 = int(blk_pos[0])
+    lens = blk_len.astype(np.int64)
+    ids = postings[p0 : p0 + int(lens.sum())].astype(np.int64)
+    block = np.repeat(np.arange(len(lens), dtype=np.int64), lens)
+    slot = np.arange(len(ids), dtype=np.int64) - np.repeat(np.cumsum(lens) - lens, lens)
+    delta = np.zeros_like(ids)
+    delta[1:] = ids[1:] - ids[:-1]
+    delta[slot == 0] = 0
+    return block, slot, delta
+
+
+def _pfor_widths_np(block, delta, blk_len):
+    """PForDelta base width per block — ``(bits i64[nb], n_exc i64[nb])``.
 
     Minimizes total stored words: ``ceil(len·bits/32)`` tail-trimmed base
     words plus one exception word per delta exceeding the base width.
     The floor ``bits ≥ bit_length(max) − PFOR_HIGH_BITS`` keeps every
-    exception's high bits inside one 24-bit patch field; ties break
-    toward the wider base (fewer exceptions → cheaper decode).
+    exception's high bits inside one 24-bit patch field.  The full width
+    wins ties against every narrower one; among narrower widths the
+    narrowest of the cheapest wins.
     """
-    n = len(real_deltas)
-    maxbits = max(int(real_deltas.max(initial=0)).bit_length(), 1)
-    best_bits, best_exc, best_words = maxbits, 0, max(-(-n * maxbits // 32), 1)
-    for width in range(max(1, maxbits - PFOR_HIGH_BITS), maxbits):
-        n_exc = int(np.count_nonzero(real_deltas >> width))
-        words = max(-(-n * width // 32), 1) + n_exc
-        if words < best_words:
-            best_bits, best_exc, best_words = width, n_exc, words
-    return best_bits, best_exc
+    nb = len(blk_len)
+    bl = np.frexp(delta.astype(np.float64))[1]  # bit_length (exact < 2^53)
+    hist = np.bincount(block * 34 + bl, minlength=nb * 34).reshape(nb, 34)
+    # n_over[:, w] = deltas whose bit length exceeds w
+    n_over = hist[:, ::-1].cumsum(axis=1)[:, ::-1][:, 1:].astype(np.int64)
+    maxbits = np.maximum(33 - np.argmax(hist[:, ::-1][:, :33] > 0, axis=1), 1)
+    maxbits = np.where(hist[:, 1:].sum(axis=1) > 0, maxbits, 1)
+    w = np.arange(33, dtype=np.int64)
+    n = blk_len.astype(np.int64)[:, None]
+    words = np.maximum(-(-n * w[None, :] // 32), 1) + n_over
+    cand = (w[None, :] >= np.maximum(1, maxbits - PFOR_HIGH_BITS)[:, None]) & (
+        w[None, :] < maxbits[:, None]
+    )
+    big = np.iinfo(np.int64).max
+    cw = np.where(cand, words, big)
+    best = cw.argmin(axis=1)  # first (narrowest) cheapest candidate
+    full_words = np.maximum(-(-blk_len.astype(np.int64) * maxbits // 32), 1)
+    take = cw[np.arange(nb), best] < full_words
+    bits = np.where(take, best, maxbits)
+    n_exc = np.where(take, n_over[np.arange(nb), best], 0)
+    return bits, n_exc
 
 
 def pack_postings_np(
@@ -268,7 +336,7 @@ def pack_postings_np(
     delta 0 (its doc id lives in ``blk_first``) and subsequent deltas are
     strictly ≥ 1 (postings are sorted unique doc ids within a term).
     Framing is PForDelta: each block picks the total-word-minimizing base
-    width (:func:`_pfor_width_np`) and stores ``ceil(len·bits/32)``
+    width (:func:`_pfor_widths_np`) and stores ``ceil(len·bits/32)``
     tail-trimmed base words holding every delta's low ``bits`` bits,
     followed by one patch word per delta that overflows the base width —
     ``slot | high_bits << PFOR_SLOT_BITS``.  The tail padding a ragged
@@ -278,86 +346,107 @@ def pack_postings_np(
     therefore garbage (they read into the exception words or the next
     block) and every consumer masks them before trusting membership.
 
+    Vectorised over blocks in chunks; the slots past ``blk_len`` are
+    packed with the filler delta 1 wherever they share a stored word with
+    real slots, exactly as the per-block reference loop in the tests.
+
     When ``impacts`` is given (the *stored*, possibly quantized, values)
     the dict additionally carries ``blk_max_impact`` — the per-block score
     upper bound driving the pruned traversal (see
     :func:`block_max_impacts_np` for why it must be computed
     post-quantization).
     """
-    M = len(offsets) - 1
-    blk_term_off = np.zeros((M + 1,), np.int32)
-    firsts: list[int] = []
-    bits_l: list[int] = []
-    lens: list[int] = []
-    poss: list[int] = []
-    word_off: list[int] = []
-    n_exc_l: list[int] = []
-    chunks: list[np.ndarray] = []
-    w = 0
-    j64 = np.arange(POSTING_BLOCK, dtype=np.int64)
-    for t in range(M):
-        lo, hi = int(offsets[t]), int(offsets[t + 1])
-        nb = (hi - lo + POSTING_BLOCK - 1) // POSTING_BLOCK
-        blk_term_off[t + 1] = blk_term_off[t] + nb
-        for b in range(nb):
-            s = lo + b * POSTING_BLOCK
-            e = min(s + POSTING_BLOCK, hi)
-            ids = postings[s:e].astype(np.int64)
-            deltas = np.ones((POSTING_BLOCK,), np.int64)
-            deltas[0] = 0
-            deltas[1:e - s] = np.diff(ids)
-            real = deltas[: e - s]
-            bits, n_exc = _pfor_width_np(real)
-            low = deltas & ((np.int64(1) << bits) - 1)
-            nw = (POSTING_BLOCK * bits) // 32  # 128·bits/32 = 4·bits exactly
-            buf = np.zeros((nw,), np.uint64)
-            bitpos = j64 * bits
-            wi = bitpos >> 5
-            off = (bitpos & 31).astype(np.uint64)
-            lo64 = low.astype(np.uint64) << off
-            np.bitwise_or.at(buf, wi, lo64 & np.uint64(0xFFFFFFFF))
-            spill = lo64 >> np.uint64(32)
-            # a nonzero spill always lands inside the block (the last delta
-            # ends exactly at the block's word boundary), so the clamp only
-            # ever redirects zero-valued ORs
-            np.bitwise_or.at(buf, np.minimum(wi + 1, nw - 1), spill)
-            # store only the words real postings reach: a ragged last block
-            # keeps ceil(len·bits/32) words instead of the full 4·bits
-            nw_t = max(-(-(e - s) * bits // 32), 1)
-            words = buf[:nw_t].astype(np.uint32)
-            if n_exc:
-                slots = np.flatnonzero(real >> bits).astype(np.uint32)
-                high = (real[slots] >> bits).astype(np.uint32)
-                words = np.concatenate(
-                    [words, slots | (high << np.uint32(PFOR_SLOT_BITS))]
-                )
-            chunks.append(words)
-            firsts.append(int(ids[0]))
-            bits_l.append(bits)
-            lens.append(e - s)
-            poss.append(s)
-            word_off.append(w)
-            n_exc_l.append(n_exc)
-            w += nw_t + n_exc
-    if not firsts:  # empty posting store: one degenerate empty block
-        chunks.append(np.zeros((4,), np.uint32))
-        firsts, bits_l, lens, poss, word_off = [0], [1], [0], [0], [0]
-        n_exc_l = [0]
-    out = dict(
-        post_packed=np.concatenate(chunks),
-        blk_first=np.asarray(firsts, np.int32),
-        blk_bits=np.asarray(bits_l, np.int32),
-        blk_len=np.asarray(lens, np.int32),
-        blk_word_off=np.asarray(word_off, np.int32),
-        blk_pos=np.asarray(poss, np.int32),
-        blk_term_off=blk_term_off,
-        blk_n_exc=np.asarray(n_exc_l, np.int32),
-    )
+    blk_term_off, blk_pos, blk_len = logical_posting_blocks_np(offsets)
+    if int(blk_term_off[-1]) == 0:  # empty posting store: one degenerate block
+        z = np.zeros((1,), np.int32)
+        out = dict(
+            post_packed=np.zeros((4,), np.uint32), blk_first=z.copy(),
+            blk_bits=np.ones((1,), np.int32), blk_len=z.copy(),
+            blk_word_off=z.copy(), blk_pos=z.copy(),
+            blk_term_off=blk_term_off, blk_n_exc=z.copy(),
+        )
+    else:
+        out = _pack_blocks_np(postings, blk_term_off, blk_pos, blk_len)
     if impacts is not None:
         out["blk_max_impact"] = block_max_impacts_np(
             impacts, out["blk_pos"], out["blk_len"]
         )
     return out
+
+
+def _pack_blocks_np(postings, blk_term_off, blk_pos, blk_len):
+    NB = blk_pos.shape[0]
+    chunks = [(c0, min(c0 + _PACK_CHUNK, NB)) for c0 in range(0, NB, _PACK_CHUNK)]
+    bits = np.zeros((NB,), np.int64)
+    n_exc = np.zeros((NB,), np.int64)
+
+    def widths(c):
+        c0, c1 = c
+        block, _, delta = _chunk_deltas_np(postings, blk_pos[c0:c1], blk_len[c0:c1])
+        bits[c0:c1], n_exc[c0:c1] = _pfor_widths_np(block, delta, blk_len[c0:c1])
+
+    lens = blk_len.astype(np.int64)
+    with ThreadPoolExecutor(HOST_THREADS) as pool:
+        list(pool.map(widths, chunks))
+        nw_t = np.maximum(-(-lens * bits // 32), 1)  # tail-trimmed base words
+        word_off = np.zeros((NB,), np.int64)
+        word_off[1:] = np.cumsum(nw_t + n_exc)[:-1]
+        W = int(word_off[-1] + nw_t[-1] + n_exc[-1])
+        packed = np.zeros((W,), np.uint32)
+
+        def pack(c):
+            c0, c1 = c
+            block, slot, delta = _chunk_deltas_np(
+                postings, blk_pos[c0:c1], blk_len[c0:c1]
+            )
+            b, nt, ln = bits[c0:c1], nw_t[c0:c1], lens[c0:c1]
+            # filler slots past blk_len store delta 1 wherever they reach
+            # into the stored words (slots starting past them add nothing)
+            n_fill = np.maximum(np.minimum(-(-nt * 32 // b), POSTING_BLOCK) - ln, 0)
+            fb = np.repeat(np.arange(c1 - c0, dtype=np.int64), n_fill)
+            fs = np.arange(len(fb), dtype=np.int64) - np.repeat(
+                np.cumsum(n_fill) - n_fill, n_fill
+            ) + ln[fb]
+            blk = np.concatenate([block, fb])
+            sl = np.concatenate([slot, fs])
+            bb = b[blk]
+            low = np.concatenate([delta, np.ones_like(fb)]) & ((1 << bb) - 1)
+            bitpos = sl * bb
+            wi = bitpos >> 5
+            lo64 = low << (bitpos & 31)
+            spill = np.minimum(wi + 1, 4 * bb - 1)  # 128·bits/32 words per block
+            wo = word_off[c0:c1][blk] - word_off[c0]
+            ntb = nt[blk]
+            # slots write disjoint bit fields, so OR-ing them is a sum; every
+            # partial sum stays < 2^32, exact in bincount's float64 weights
+            keep_lo, keep_sp = wi < ntb, spill < ntb
+            idx = np.concatenate([(wo + wi)[keep_lo], (wo + spill)[keep_sp]])
+            val = np.concatenate([(lo64 & 0xFFFFFFFF)[keep_lo], (lo64 >> 32)[keep_sp]])
+            span = int(word_off[c1 - 1] + nw_t[c1 - 1] + n_exc[c1 - 1] - word_off[c0])
+            acc = np.bincount(idx, weights=val.astype(np.float64), minlength=span)
+            # exception (patch) words follow each block's base words, in
+            # slot order
+            high = delta >> b[block]
+            exc = high != 0
+            eb = block[exc]
+            first_exc = np.searchsorted(eb, np.arange(c1 - c0))
+            rank = np.arange(len(eb)) - first_exc[eb]
+            ew = word_off[c0:c1][eb] - word_off[c0] + nt[eb] + rank
+            acc[ew] = slot[exc] | (high[exc] << PFOR_SLOT_BITS)
+            packed[word_off[c0] : word_off[c0] + span] = acc.astype(np.uint32)
+
+        list(pool.map(pack, chunks))
+    first = postings[blk_pos.astype(np.int64)]
+    return dict(
+        post_packed=packed,
+        blk_first=first.astype(np.int32),
+        blk_bits=bits.astype(np.int32),
+        blk_len=blk_len.astype(np.int32),
+        blk_word_off=word_off.astype(np.int32),
+        blk_pos=blk_pos.astype(np.int32),
+        blk_term_off=blk_term_off,
+        blk_n_exc=n_exc.astype(np.int32),
+    )
 
 
 def impact_levels_np(impacts: np.ndarray) -> np.ndarray:
@@ -391,31 +480,23 @@ def _impact_order_np(
     """
     lvl = impact_levels_np(impacts)
     M = len(offsets) - 1
-    post2 = postings.copy()
-    imp2 = impacts.copy()
+    counts = np.diff(offsets.astype(np.int64))
+    term = np.repeat(np.arange(M, dtype=np.int64), counts)
+    order = np.lexsort((postings, lvl, term))
+    post2, imp2, lv, term = postings[order], impacts[order], lvl[order], term
+    P = len(post2)
     seg_term_off = np.zeros((M + 1,), np.int32)
-    seg_pos_l: list[int] = []
-    seg_len_l: list[int] = []
-    for t in range(M):
-        lo, hi = int(offsets[t]), int(offsets[t + 1])
-        ns = 0
-        if hi > lo:
-            order = np.lexsort((postings[lo:hi], lvl[lo:hi]))
-            post2[lo:hi] = postings[lo:hi][order]
-            imp2[lo:hi] = impacts[lo:hi][order]
-            lv = lvl[lo:hi][order]
-            starts = np.flatnonzero(np.r_[True, lv[1:] != lv[:-1]])
-            ends = np.r_[starts[1:], hi - lo]
-            for a, b in zip(starts, ends):
-                seg_pos_l.append(lo + int(a))
-                seg_len_l.append(int(b - a))
-            ns = len(starts)
-        seg_term_off[t + 1] = seg_term_off[t] + ns
-    if not seg_pos_l:  # empty store: one degenerate empty segment
-        seg_pos_l, seg_len_l = [0], [0]
+    if P == 0:  # empty store: one degenerate empty segment
+        z = np.zeros((1,), np.int32)
+        return post2, imp2, seg_term_off, z, z.copy()
+    new = np.ones((P,), bool)
+    new[1:] = (lv[1:] != lv[:-1]) | (term[1:] != term[:-1])
+    starts = np.flatnonzero(new)
+    seg_len = np.diff(np.append(starts, P))
+    seg_term_off[1:] = np.cumsum(np.bincount(term[starts], minlength=M))
     return (
         post2, imp2, seg_term_off,
-        np.asarray(seg_pos_l, np.int32), np.asarray(seg_len_l, np.int32),
+        starts.astype(np.int32), seg_len.astype(np.int32),
     )
 
 
@@ -427,12 +508,22 @@ def _suffix_max_per_term_np(
     ``out[b] = max(blk_max[b : term_end])`` within each term's block run:
     a safe upper bound for block ``b`` that is monotone non-increasing
     along the run, which is what lets the pruned kernel early-exit.
+    Vectorised as one running max over keys ``(term rank, value)`` walked
+    backwards: a later term's rank resets the max, and the value bits are
+    mapped so that integer order is float order.
     """
     out = np.asarray(blk_max, np.float32).copy()
-    for t in range(len(blk_term_off) - 1):
-        b0, b1 = int(blk_term_off[t]), int(blk_term_off[t + 1])
-        if b1 > b0:
-            out[b0:b1] = np.maximum.accumulate(out[b0:b1][::-1])[::-1]
+    n = int(blk_term_off[-1])
+    if n == 0:
+        return out
+    M = len(blk_term_off) - 1
+    term = np.repeat(np.arange(M, dtype=np.int64), np.diff(blk_term_off))
+    b = out[:n].view(np.int32).astype(np.int64)
+    key = (b ^ ((b >> 31) & 0x7FFFFFFF)) + 2**31  # float order, ≥ 0
+    key = ((M - 1 - term) << 32) | key
+    run = np.maximum.accumulate(key[::-1])[::-1] & 0xFFFFFFFF
+    b = run - 2**31
+    out[:n] = (b ^ ((b >> 31) & 0x7FFFFFFF)).astype(np.int32).view(np.float32)
     return out
 
 
@@ -445,8 +536,56 @@ def _trivial_segments_np(M: int) -> dict[str, np.ndarray]:
     )
 
 
+_DOC_CHUNK = 1 << 13  # documents per vectorised step (bounds temporaries)
+
+
+def doc_term_matrix_np(doc_terms) -> np.ndarray:
+    """Documents as one ``[N, L]`` term-id matrix, ``-1`` padded.
+
+    A 2-D array (what :func:`repro.corpus.make_corpus` returns) passes
+    through; a list of per-doc arrays (repetitions = frequencies) is
+    padded to its longest document.
+    """
+    if isinstance(doc_terms, np.ndarray) and doc_terms.ndim == 2:
+        return doc_terms
+    lens = np.fromiter((len(t) for t in doc_terms), np.int64, len(doc_terms))
+    mat = np.full((len(doc_terms), max(int(lens.max(initial=0)), 1)), -1, np.int32)
+    if lens.sum():
+        mat[np.arange(mat.shape[1])[None, :] < lens[:, None]] = np.concatenate(
+            [np.asarray(t).ravel() for t in doc_terms]
+        )
+    return mat
+
+
+def _doc_term_runs_np(mat: np.ndarray, c0: int, c1: int):
+    """Distinct terms of docs ``[c0, c1)`` with their frequencies.
+
+    Returns ``(doc i64[p], term i64[p], tf i64[p])`` in doc-major,
+    term-ascending order.
+    """
+    x = np.sort(mat[c0:c1], axis=1)
+    L = x.shape[1]
+    new = np.ones(x.shape, bool)
+    new[:, 1:] = x[:, 1:] != x[:, :-1]
+    idx = np.flatnonzero(new)
+    tf = np.diff(np.append(idx, x.size))  # runs end at the next run or row
+    term = x.ravel()[idx].astype(np.int64)
+    keep = term >= 0
+    return (c0 + idx[keep] // L).astype(np.int64), term[keep], tf[keep]
+
+
+def document_frequencies_np(doc_terms, n_terms: int) -> np.ndarray:
+    """Documents containing each term — f64[n_terms]."""
+    mat = doc_term_matrix_np(doc_terms)
+    df = np.zeros((n_terms,), np.float64)
+    for c0 in range(0, mat.shape[0], _DOC_CHUNK):
+        _, term, _ = _doc_term_runs_np(mat, c0, min(c0 + _DOC_CHUNK, mat.shape[0]))
+        df += np.bincount(term, minlength=n_terms)
+    return df
+
+
 def build_text_index_np(
-    doc_terms: list[np.ndarray],
+    doc_terms,
     n_terms: int,
     n_bitmap_terms: int = 0,
     idf: np.ndarray | None = None,
@@ -454,10 +593,12 @@ def build_text_index_np(
     impact_dtype: np.dtype | str | None = None,
     layout: str = "docid",
 ) -> TextIndex:
-    """Build from per-doc term-id arrays (with repetitions = frequencies).
+    """Build from per-doc term ids (with repetitions = frequencies): a
+    list of arrays or a ``-1``-padded ``[N, L]`` matrix.
 
     Pure-numpy index construction (host side, analogous to the paper's
-    offline index build).  ``idf`` overrides the collection IDF — shard
+    offline index build), vectorised over documents and postings.
+    ``idf`` overrides the collection IDF — shard
     builders pass the *corpus-global* IDF (:func:`global_idf_np`) so each
     posting's impact is rounded to f32 exactly once from statistics that
     do not depend on the partitioning, making per-doc scores bit-identical
@@ -476,37 +617,55 @@ def build_text_index_np(
     """
     if layout not in ("docid", "impact"):
         raise ValueError(f"unknown posting layout: {layout!r}")
+    mat = doc_term_matrix_np(doc_terms)
     n_docs = len(doc_terms)
-    # term frequencies per doc, collection document frequencies
-    doc_ids_per_term: list[list[int]] = [[] for _ in range(n_terms)]
-    freq_per_term: list[list[int]] = [[] for _ in range(n_terms)]
-    doc_len = np.zeros((n_docs,), dtype=np.float64)
-    for d, terms in enumerate(doc_terms):
-        doc_len[d] = max(len(terms), 1)
-        uniq, counts = np.unique(terms, return_counts=True)
-        for w, c in zip(uniq, counts):
-            doc_ids_per_term[int(w)].append(d)
-            freq_per_term[int(w)].append(int(c))
+    M = n_terms
+    doc_len = np.maximum((mat >= 0).sum(axis=1), 1).astype(np.float64)
+    # one sortable key per posting: term | doc | frequency, in bit fields
+    tf_bits = max(int(mat.shape[1]).bit_length(), 1)
+    doc_bits = max(int(n_docs - 1).bit_length(), 1)
+    if max(M - 1, 1).bit_length() + doc_bits + tf_bits > 63:
+        raise ValueError("corpus too large for one 64-bit posting key")
+    tf_mask, doc_mask = (1 << tf_bits) - 1, (1 << doc_bits) - 1
 
-    df = np.array([len(x) for x in doc_ids_per_term], dtype=np.float64)
-    if idf is None:
-        idf = np.log(1.0 + n_docs / np.maximum(df, 1.0))
+    def keys(c0):
+        doc, term, tf = _doc_term_runs_np(mat, c0, min(c0 + _DOC_CHUNK, n_docs))
+        return (((term << doc_bits) | doc) << tf_bits) | tf
 
-    offsets = np.zeros((n_terms + 1,), dtype=np.int32)
-    offsets[1:] = np.cumsum([len(x) for x in doc_ids_per_term])
-    P = int(offsets[-1])
-    postings = np.zeros((P,), dtype=np.int32)
-    impacts = np.zeros((P,), dtype=np.float32)
-    for w in range(n_terms):
-        lo, hi = offsets[w], offsets[w + 1]
-        if hi == lo:
-            continue
-        ids = np.asarray(doc_ids_per_term[w], dtype=np.int32)
-        fr = np.asarray(freq_per_term[w], dtype=np.float64)
-        order = np.argsort(ids)
-        postings[lo:hi] = ids[order]
-        imp = idf[w] * (1.0 + np.log(fr[order])) / np.sqrt(doc_len[ids[order]])
-        impacts[lo:hi] = imp.astype(np.float32)
+    with ThreadPoolExecutor(HOST_THREADS) as pool:
+        chunks = list(pool.map(keys, range(0, n_docs, _DOC_CHUNK)))
+        key = np.concatenate(chunks) if chunks else np.zeros((0,), np.int64)
+        del chunks
+        key.sort()  # term-major, doc ids ascending within each term
+        P = key.shape[0]
+        spans = [(a, min(a + _DOC_CHUNK * 128, P)) for a in range(0, P, _DOC_CHUNK * 128)]
+        postings = np.empty((P,), np.int32)
+
+        def decode(span):
+            k = key[span[0] : span[1]]
+            postings[span[0] : span[1]] = (k >> tf_bits) & doc_mask
+            return np.bincount(k >> (tf_bits + doc_bits), minlength=M)
+
+        df = np.zeros((M,), np.float64)
+        for part in pool.map(decode, spans):
+            df += part
+        if idf is None:
+            idf = np.log(1.0 + n_docs / np.maximum(df, 1.0))
+        offsets = np.zeros((M + 1,), dtype=np.int32)
+        offsets[1:] = np.cumsum(df).astype(np.int64)
+        impacts = np.empty((P,), np.float32)
+
+        def impact(span):
+            k = key[span[0] : span[1]]
+            fr = (k & tf_mask).astype(np.float64)
+            doc = (k >> tf_bits) & doc_mask
+            imp = idf[k >> (tf_bits + doc_bits)] * (1.0 + np.log(fr)) / np.sqrt(
+                doc_len[doc]
+            )
+            impacts[span[0] : span[1]] = imp.astype(np.float32)
+
+        list(pool.map(impact, spans))
+    del key
 
     # block bitmaps for the most frequent terms
     n_blocks = (n_docs + BLOCK - 1) // BLOCK
@@ -556,6 +715,7 @@ def build_text_index_np(
         pack["blk_max_impact"] = _suffix_max_per_term_np(
             pack["blk_max_impact"], pack["blk_term_off"]
         )
+    pack["imp_plane"] = impact_plane_np(impacts, pack["blk_pos"], pack["blk_len"])
     term_blocks = np.diff(pack["blk_term_off"])
     term_segments = np.diff(seg["seg_term_off"])
     return TextIndex(
@@ -586,8 +746,12 @@ def _with_impacts(index: TextIndex, impacts: jax.Array) -> TextIndex:
     )
     if index.layout == "impact":
         bm = _suffix_max_per_term_np(bm, np.asarray(index.blk_term_off))
+    plane = impact_plane_np(
+        np.asarray(impacts), np.asarray(index.blk_pos), np.asarray(index.blk_len)
+    )
     return dataclasses.replace(
-        index, impacts=impacts, blk_max_impact=jnp.asarray(bm)
+        index, impacts=impacts, blk_max_impact=jnp.asarray(bm),
+        imp_plane=jnp.asarray(plane),
     )
 
 
@@ -602,11 +766,9 @@ def quantize_impacts(index: TextIndex, dtype=jnp.float16) -> TextIndex:
     return _with_impacts(index, index.impacts.astype(dtype))
 
 
-def global_idf_np(doc_terms: list[np.ndarray], n_terms: int) -> np.ndarray:
+def global_idf_np(doc_terms, n_terms: int) -> np.ndarray:
     """Corpus-wide IDF, matching ``build_text_index_np``'s formula."""
-    df = np.zeros((n_terms,), dtype=np.float64)
-    for terms in doc_terms:
-        np.add.at(df, np.unique(terms), 1.0)
+    df = document_frequencies_np(doc_terms, n_terms)
     return np.log(1.0 + len(doc_terms) / np.maximum(df, 1.0))
 
 
@@ -878,6 +1040,10 @@ def conjunction_candidates(
         # once and flatten, instead of per-element block decodes
         NB = index.blk_first.shape[0]
         nbd = (max_candidates + POSTING_BLOCK - 1) // POSTING_BLOCK
+        if index.layout == "impact":
+            # framing restarts at every segment, so the first max_candidates
+            # postings can end one ragged block per segment further on
+            nbd += index.max_term_segments
         blocks = jnp.clip(
             index.blk_term_off[t0] + jnp.arange(nbd, dtype=jnp.int32), 0, NB - 1
         )
@@ -927,6 +1093,38 @@ def conjunction_candidates(
     cand = jnp.where(valid, cand, jnp.int32(2**31 - 1))
     score = jnp.where(valid, score, 0.0)
     return cand, valid, score
+
+
+def term_postings(
+    index: TextIndex, term: jax.Array
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One term's whole posting list, block by block — ``(docs i32,
+    impacts f32, live bool)``, each ``[max_term_blocks, POSTING_BLOCK]``.
+
+    Slots past a block's length or the term's block run are not live.
+    Both layouts and both stores: a term's blocks are one contiguous run,
+    and block b's postings sit at CSR positions ``blk_pos[b] + j``.
+    """
+    shape = (index.max_term_blocks, POSTING_BLOCK)
+    P = index.n_postings
+    if P == 0:
+        return (
+            jnp.zeros(shape, jnp.int32), jnp.zeros(shape, jnp.float32),
+            jnp.zeros(shape, bool),
+        )
+    NB = index.blk_pos.shape[0]
+    b0 = index.blk_term_off[term]
+    nb = index.blk_term_off[term + 1] - b0
+    i = jnp.arange(shape[0], dtype=jnp.int32)
+    blocks = jnp.clip(b0 + i, 0, NB - 1)
+    j = jnp.arange(POSTING_BLOCK, dtype=jnp.int32)
+    live = (i < nb)[:, None] & (j[None, :] < index.blk_len[blocks][:, None])
+    pos = jnp.clip(index.blk_pos[blocks][:, None] + j[None, :], 0, P - 1)
+    if index.is_compressed:
+        docs = decode_posting_blocks(index, blocks)
+    else:
+        docs = index.postings[pos]
+    return docs, index.impacts[pos].astype(jnp.float32), live
 
 
 def text_score_of_docs(

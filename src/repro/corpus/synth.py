@@ -21,22 +21,116 @@ extracted footprints, plus a realistic geographic query trace):
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, replace
 
 import numpy as np
 
 from repro.core.algorithms import QueryBatch
+from repro.core.geometry import HOST_THREADS
 import jax.numpy as jnp
 
 
 @dataclass
 class SynthCorpus:
-    doc_terms: list[np.ndarray]
+    doc_terms: np.ndarray  # i32[N, doc_len] term ids (repeats = frequencies)
     doc_rects: np.ndarray  # [N, R, 4]
     doc_amps: np.ndarray  # [N, R]
     pagerank: np.ndarray  # [N]
     n_terms: int
     cities: np.ndarray  # [C, 3]: x, y, radius
+
+
+def _zipf_tail(a: float, n: int) -> float:
+    """``sum_{j >= n} j^-a`` (a > 1): 10^5 terms summed, the rest by
+    Euler–Maclaurin (error far below float64 resolution)."""
+    K = 100_000
+    j = np.arange(n, n + K, dtype=np.float64)
+    m = float(n + K)
+    em = (
+        m ** (1 - a) / (a - 1)
+        + 0.5 * m**-a
+        + a * m ** (-a - 1) / 12
+        - a * (a + 1) * (a + 2) * m ** (-a - 3) / 720
+    )
+    return float(np.sum(j**-a)) + em
+
+
+def _alias_table(p: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Walker/Vose alias table for the pmf ``p`` (O(n), exact)."""
+    n = len(p)
+    q = p * n
+    prob = np.ones((n,), np.float64)
+    alias = np.arange(n, dtype=np.int32)
+    small = list(np.flatnonzero(q < 1.0))
+    large = list(np.flatnonzero(q >= 1.0))
+    q = q.tolist()
+    while small and large:
+        s, g = small.pop(), large[-1]
+        prob[s], alias[s] = q[s], g
+        q[g] -= 1.0 - q[s]
+        if q[g] < 1.0:
+            small.append(large.pop())
+    return prob, alias
+
+
+def _zipf_pmf(zipf_a: float, n_terms: int) -> np.ndarray:
+    """Probabilities of ``min(zipf(zipf_a) - 1, n_terms - 1)``: the last
+    term takes the whole clipped tail."""
+    head = np.arange(1, n_terms, dtype=np.float64) ** -zipf_a
+    tail = _zipf_tail(zipf_a, n_terms)
+    return np.append(head, tail) / (head.sum() + tail)
+
+
+def doc_len_for_postings(postings: float, n_terms: int, zipf_a: float = 1.3) -> int:
+    """Fewest Zipf draws per document whose expected number of *distinct*
+    terms (= postings per document) reaches ``postings``.
+
+    A configuration states postings per document; :func:`make_corpus`
+    draws terms with repetition, so it needs more draws than postings.
+    """
+    p = _zipf_pmf(zipf_a, n_terms)
+
+    def distinct(n):
+        return float(np.sum(-np.expm1(n * np.log1p(-p))))
+
+    lo, hi = 1, max(int(postings), 1)
+    while distinct(hi) < postings:
+        lo, hi = hi, hi * 2
+    while lo < hi:
+        mid = (lo + hi) // 2
+        lo, hi = (mid + 1, hi) if distinct(mid) < postings else (lo, mid)
+    return lo
+
+
+def zipf_terms(
+    rng: np.random.Generator, shape: tuple[int, int], zipf_a: float, n_terms: int
+) -> np.ndarray:
+    """i32 term ids distributed as ``min(zipf(zipf_a) - 1, n_terms - 1)``.
+
+    Sampled by an alias table over the exact probabilities (the last term
+    takes the whole clipped tail) rather than by ``rng.zipf``'s rejection
+    loop, in fixed chunks of rows, each from its own spawned generator, on
+    a thread pool — the result depends on the seed, not on the threads.
+    """
+    prob, alias = _alias_table(_zipf_pmf(zipf_a, n_terms))
+    n_rows, n_cols = shape
+    out = np.empty(shape, np.int32)
+    rows = 1 << 14
+    starts = range(0, n_rows, rows)
+    gens = rng.spawn(len(starts))
+
+    def fill(i):
+        r0 = starts[i]
+        r1 = min(r0 + rows, n_rows)
+        g = gens[i]
+        cand = g.integers(0, n_terms, (r1 - r0, n_cols), dtype=np.int32)
+        keep = g.random((r1 - r0, n_cols)) < prob[cand]
+        out[r0:r1] = np.where(keep, cand, alias[cand])
+
+    with ThreadPoolExecutor(HOST_THREADS) as pool:
+        list(pool.map(fill, range(len(starts))))
+    return out
 
 
 def make_corpus(
@@ -48,6 +142,14 @@ def make_corpus(
     zipf_a: float = 1.3,
     seed: int = 0,
 ) -> SynthCorpus:
+    """Synthetic corpus (see the module docstring), vectorised over docs.
+
+    ``doc_terms`` is one ``[n_docs, doc_len]`` matrix of Zipf term ids.
+    Each doc has 1..``max_rects`` places, each an address-style (small,
+    high amplitude) or town-style (larger, low amplitude) rect around a
+    population-weighted city; a draw that clips to nothing leaves its
+    slot empty.
+    """
     rng = np.random.default_rng(seed)
     # cities: power-law sizes
     cx = rng.uniform(0.05, 0.95, n_cities)
@@ -58,34 +160,29 @@ def make_corpus(
     cities = np.stack([cx, cy, radius], axis=1).astype(np.float32)
     city_p = pop / pop.sum()
 
-    # documents
-    doc_terms = []
-    rects = np.zeros((n_docs, max_rects, 4), dtype=np.float32)
-    rects[:, :, 0] = 1.0  # empty-rect padding (x1 < x0)
-    rects[:, :, 1] = 1.0
-    amps = np.zeros((n_docs, max_rects), dtype=np.float32)
-    for i in range(n_docs):
-        terms = np.minimum(rng.zipf(zipf_a, doc_len) - 1, n_terms - 1)
-        doc_terms.append(terms.astype(np.int32))
-        n_places = rng.integers(1, max_rects + 1)
-        chosen = rng.choice(n_cities, size=n_places, p=city_p, replace=True)
-        for j, c in enumerate(chosen):
-            x, y, r = cities[c]
-            # address-style small rect (high amp) or town-style larger (low amp)
-            if rng.random() < 0.5:
-                w = r * rng.uniform(0.05, 0.2)
-                amp = rng.uniform(0.7, 1.0)
-            else:
-                w = r * rng.uniform(0.5, 1.5)
-                amp = rng.uniform(0.2, 0.6)
-            px = np.clip(x + rng.normal(0, r / 2), 0.001, 0.999)
-            py = np.clip(y + rng.normal(0, r / 2), 0.001, 0.999)
-            x0, x1 = np.clip(px - w, 0, 1), np.clip(px + w, 0, 1)
-            y0, y1 = np.clip(py - w, 0, 1), np.clip(py + w, 0, 1)
-            if x1 <= x0 or y1 <= y0:
-                continue
-            rects[i, j] = (x0, y0, x1, y1)
-            amps[i, j] = amp
+    doc_terms = zipf_terms(rng, (n_docs, doc_len), zipf_a, n_terms)
+
+    # footprints: one candidate place per slot, the first n_places kept
+    shape = (n_docs, max_rects)
+    n_places = rng.integers(1, max_rects + 1, n_docs)
+    cdf = np.cumsum(city_p)
+    city = np.minimum(np.searchsorted(cdf, rng.random(shape) * cdf[-1], "right"),
+                      n_cities - 1)
+    x, y, r = (cities[city, i].astype(np.float64) for i in range(3))
+    address = rng.random(shape) < 0.5
+    w = r * np.where(address, rng.uniform(0.05, 0.2, shape), rng.uniform(0.5, 1.5, shape))
+    amp = np.where(address, rng.uniform(0.7, 1.0, shape), rng.uniform(0.2, 0.6, shape))
+    px = np.clip(x + rng.normal(0, 1, shape) * (r / 2), 0.001, 0.999)
+    py = np.clip(y + rng.normal(0, 1, shape) * (r / 2), 0.001, 0.999)
+    x0, x1 = np.clip(px - w, 0, 1), np.clip(px + w, 0, 1)
+    y0, y1 = np.clip(py - w, 0, 1), np.clip(py + w, 0, 1)
+    ok = (np.arange(max_rects)[None, :] < n_places[:, None]) & (x1 > x0) & (y1 > y0)
+    rects = np.where(
+        ok[..., None],
+        np.stack([x0, y0, x1, y1], axis=-1),
+        np.array([1.0, 1.0, 0.0, 0.0]),  # empty-rect padding (x1 < x0)
+    ).astype(np.float32)
+    amps = np.where(ok, amp, 0.0).astype(np.float32)
 
     pagerank = rng.pareto(2.0, n_docs).astype(np.float32)
     pagerank = pagerank / max(pagerank.max(), 1e-9)
@@ -331,10 +428,9 @@ def stamp_arrivals(
 
 def term_document_frequencies(corpus: SynthCorpus) -> np.ndarray:
     """Per-term document frequency (docs containing the term), f64[n_terms]."""
-    df = np.zeros((corpus.n_terms,), dtype=np.float64)
-    for terms in corpus.doc_terms:
-        np.add.at(df, np.unique(terms), 1.0)
-    return df
+    from repro.core.text_index import document_frequencies_np
+
+    return document_frequencies_np(corpus.doc_terms, corpus.n_terms)
 
 
 def make_mixture_trace(

@@ -46,7 +46,7 @@ def _make_kernel(d: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitmap_and_popcount_planar(
     bitmaps: jax.Array,  # u32[d, rows, 128]
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     d, rows, lanes = bitmaps.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0

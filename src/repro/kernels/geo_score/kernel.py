@@ -11,7 +11,7 @@ Layout decisions (TPU-native, DESIGN.md §2):
   ``[rows, 128]`` (ops.py transposes/pads) — lane dimension = toe prints, so
   every min/max/mul is a full-width VPU op.  The packed ``[T, 4]`` layout
   would put the 4 coordinates in lanes and waste 124/128 of the vector unit.
-* The query footprint (≤ Q_MAX rects) is tiny: it sits unblocked in VMEM and
+* The query footprint (≤ Q_MAX rects) is tiny: it sits unblocked in SMEM and
   the kernel unrolls a static Python loop over its rows — each iteration is
   a scalar-broadcast VPU multiply-accumulate over the [BLOCK_ROWS, 128] tile.
 * Block shape (BLOCK_ROWS × 128) f32 = 8 sublanes × 128 lanes per input
@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 8  # sublane-aligned f32 tile
@@ -45,33 +46,34 @@ def _geo_score_kernel(qr_ref, qa_ref, x0_ref, y0_ref, x1_ref, y1_ref, amp_ref, o
         qy1 = qr_ref[j, 3]
         w = jnp.maximum(jnp.minimum(x1, qx1) - jnp.maximum(x0, qx0), 0.0)
         h = jnp.maximum(jnp.minimum(y1, qy1) - jnp.maximum(y0, qy0), 0.0)
-        acc = acc + (w * h) * qa_ref[j]
+        acc = acc + (w * h) * qa_ref[0, j]
     out_ref[...] = acc * amp_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def geo_score_planar(
     q_rects: jax.Array,  # f32[Q_MAX, 4]
-    q_amps: jax.Array,  # f32[Q_MAX]
+    q_amps: jax.Array,  # f32[1, Q_MAX] (2-D: a 1-D block fails under vmap)
     x0: jax.Array,  # f32[rows, 128]
     y0: jax.Array,
     x1: jax.Array,
     y1: jax.Array,
     amp: jax.Array,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Raw pallas_call on pre-planarized inputs. Prefer ops.geo_score_toeprints."""
     rows = x0.shape[0]
     assert rows % BLOCK_ROWS == 0, rows
-    assert q_rects.shape == (Q_MAX, 4) and q_amps.shape == (Q_MAX,)
+    assert q_rects.shape == (Q_MAX, 4) and q_amps.shape == (1, Q_MAX)
     grid = (rows // BLOCK_ROWS,)
     plane = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _geo_score_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((Q_MAX, 4), lambda i: (0, 0)),  # query rects: whole, VMEM
-            pl.BlockSpec((Q_MAX,), lambda i: (0,)),
+            # query rects / amps are read as scalars: whole, in SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             plane, plane, plane, plane, plane,
         ],
         out_specs=plane,

@@ -34,7 +34,7 @@ def geo_score_toeprints(
 
     # pad query to Q_MAX with zero-amp empty rects
     qr = jnp.zeros((Q_MAX, 4), jnp.float32).at[:Q].set(q_rects.astype(jnp.float32))
-    qa = jnp.zeros((Q_MAX,), jnp.float32).at[:Q].set(q_amps.astype(jnp.float32))
+    qa = jnp.zeros((1, Q_MAX), jnp.float32).at[0, :Q].set(q_amps.astype(jnp.float32))
 
     # planarize: [T,4] -> four [rows,128] planes (pad T up to tile multiple)
     tile = BLOCK_ROWS * LANES

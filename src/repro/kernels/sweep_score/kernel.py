@@ -29,12 +29,27 @@ selection.  Per-block ``scored`` flags are emitted so the caller can
 count skipped blocks and charge only the bytes actually streamed.
 
 Layout mirrors kernels/geo_score: planar coordinate arrays with the lane
-dimension along toe prints ([rows, 128] f32 tiles), query rects unrolled
-from VMEM scalars.  Sweep starts are block-aligned by ops.py (rounded down
-to the 1024-element tile); masking against the true [start, end) range
-happens in ops.py for the unpruned kernel, and in-kernel (positions derived
-from the prefetched starts) for the pruned one, whose θ updates must see
-only genuine candidates.
+dimension along toe prints ([rows, 128] tiles), query rects unrolled from
+SMEM scalars.  Sweep starts are tile-aligned by ops.py (rounded down to the
+kernel's tile); masking against the true [start, end) range happens in
+ops.py for the unpruned kernel, and in-kernel (positions derived from the
+prefetched starts) for the pruned one, whose θ updates must see only
+genuine candidates.
+
+TPU tiling constraints (found by compiling for a v5e):
+
+* the unpruned kernel streams the stored dtypes (f32/f16 coords,
+  f32/f16/int8 amps) through BlockSpecs of ``PLAIN_ROWS = 32`` rows — the
+  minimum sublane tile of an 8-bit plane (16-bit needs 16, 32-bit 8), so
+  one block shape loads every stored dtype;
+* the v5e cannot load f16 vectors: 16-bit float planes arrive as their
+  int16 bit patterns and are widened in-register (``f16_bits_to_f32``);
+* the pruned kernel copies single 128-toe-print rows by hand, and a row
+  slice of a 16- or 8-bit HBM array is not tile-aligned, so its planes
+  arrive decoded to f32 (ops.py; the decode is exact, and the int8 amp
+  scale is folded into the amp plane there);
+* the per-block ``scored`` flags are one SMEM output block spanning the
+  whole array (a ``(1, bpt)`` block violates the (8, 128) block rule).
 """
 from __future__ import annotations
 
@@ -47,8 +62,45 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 8
-TILE = BLOCK_ROWS * LANES  # toe prints per grid step
+TILE = BLOCK_ROWS * LANES  # toe prints per pruned grid step
+PLAIN_ROWS = 32  # unpruned block rows: the 8-bit sublane tile
+PLAIN_TILE = PLAIN_ROWS * LANES  # toe prints per unpruned grid step
 Q_MAX = 8
+
+# query rects / amps are read as scalars: whole arrays in SMEM
+_Q_SPECS = [
+    pl.BlockSpec(memory_space=pltpu.SMEM),
+    pl.BlockSpec(memory_space=pltpu.SMEM),
+]
+
+
+def f16_bits_to_f32(h: jax.Array) -> jax.Array:
+    """Decode f16 bit patterns (any integer dtype) to f32, exactly.
+
+    The v5e cannot load f16 vectors, so 16-bit float planes reach the
+    kernel as their int16 bit patterns and are widened here with integer
+    ops: bit-identical to ``astype(float32)`` for every f16 value
+    (normals, subnormals, signed zeros, inf and NaN).
+    """
+    h = h.astype(jnp.int32) & 0xFFFF
+    sign = (h >> 15) << 31
+    ex = (h >> 10) & 0x1F
+    man = h & 0x3FF
+    normal = jax.lax.bitcast_convert_type(
+        sign | ((ex + 112) << 23) | (man << 13), jnp.float32
+    )
+    special = jax.lax.bitcast_convert_type(
+        sign | 0x7F800000 | (man << 13), jnp.float32
+    )
+    sub = man.astype(jnp.float32) * jnp.float32(2.0**-24)
+    sub = jnp.where(sign != 0, -sub, sub)
+    return jnp.where(ex == 0, sub, jnp.where(ex == 31, special, normal))
+
+
+def _decode(v: jax.Array) -> jax.Array:
+    # int16 planes carry f16 bit patterns (see f16_bits_to_f32); every
+    # other stored dtype (f32, int8) converts directly
+    return f16_bits_to_f32(v) if v.dtype == jnp.int16 else v.astype(jnp.float32)
 
 
 def _kernel(
@@ -56,14 +108,15 @@ def _kernel(
     out_ref,
 ):
     # starts_ref is scalar-prefetch (used only by the index maps).  The
-    # planes arrive in their STORED dtype (f32/f16 coords, f32/f16/int8
-    # amps) and are decoded in-register: astype f32, then × the per-row
-    # amp scale (all-ones for non-int8 stores — ×1.0 is bitwise exact).
-    x0 = x0_ref[...].astype(jnp.float32)
-    y0 = y0_ref[...].astype(jnp.float32)
-    x1 = x1_ref[...].astype(jnp.float32)
-    y1 = y1_ref[...].astype(jnp.float32)
-    amp = amp_ref[...].astype(jnp.float32) * sc_ref[...]
+    # planes arrive in their STORED dtype (f32 or f16 bits as int16 coords,
+    # f32/f16 bits/int8 amps) and are decoded in-register to f32, then
+    # the amps × the per-row scale (all-ones for non-int8 stores — ×1.0 is
+    # bitwise exact).
+    x0 = _decode(x0_ref[...])
+    y0 = _decode(y0_ref[...])
+    x1 = _decode(x1_ref[...])
+    y1 = _decode(y1_ref[...])
+    amp = _decode(amp_ref[...]) * sc_ref[...]
     acc = jnp.zeros_like(x0)
     for j in range(Q_MAX):  # static unroll over query rects
         qx0 = qr_ref[j, 0]
@@ -78,45 +131,43 @@ def _kernel(
 
 @functools.partial(jax.jit, static_argnames=("n_sweeps", "budget", "interpret"))
 def sweep_score_planar(
-    block_starts: jax.Array,  # i32[k] sweep starts in BLOCK units (rows/BLOCK_ROWS)
+    block_starts: jax.Array,  # i32[k] sweep starts in PLAIN_TILE units
     q_rects: jax.Array,  # f32[Q_MAX, 4]
     q_amps: jax.Array,  # f32[Q_MAX]
-    x0: jax.Array,  # [rows, 128] — the ENTIRE toe-print store, planar,
-    y0: jax.Array,  # in its stored dtype (f32/f16 coords, f32/f16/int8 amps)
+    x0: jax.Array,  # [rows, 128] — the ENTIRE toe-print store, planar, in
+    y0: jax.Array,  # its stored dtype (f16 planes as int16 bit patterns)
     x1: jax.Array,
     y1: jax.Array,
     amp: jax.Array,
     scale: jax.Array,  # f32[rows, 1] per-row amp scale (ones unless int8)
     n_sweeps: int,
-    budget: int,  # toe prints fetched per sweep; multiple of TILE
-    interpret: bool = True,
+    budget: int,  # toe prints fetched per sweep; multiple of PLAIN_TILE
+    interpret: bool,
 ) -> jax.Array:
     """Returns per-sweep scores f32[k, budget // LANES, 128].
 
-    grid = (k, budget/TILE); block (i, j) reads store rows
-    ``block_starts[i] + j*BLOCK_ROWS`` — a streaming DMA from the sweep
+    grid = (k, budget/PLAIN_TILE); block (i, j) reads store rows
+    ``(block_starts[i] + j) * PLAIN_ROWS`` — a streaming DMA from the sweep
     offset, fused with scoring.
     """
-    assert budget % TILE == 0
-    rows = x0.shape[0]
-    n_blocks = budget // TILE
+    assert budget % PLAIN_TILE == 0
+    n_blocks = budget // PLAIN_TILE
 
     def in_map(i, j, starts):
-        # starts[i] is in BLOCK units (TILE-aligned rows / BLOCK_ROWS)
+        # starts[i] is in PLAIN_TILE units
         return (starts[i] + j, 0)
 
-    plane = pl.BlockSpec((BLOCK_ROWS, LANES), in_map)
+    plane = pl.BlockSpec((PLAIN_ROWS, LANES), in_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_sweeps, n_blocks),
         in_specs=[
-            pl.BlockSpec((Q_MAX, 4), lambda i, j, s: (0, 0)),
-            pl.BlockSpec((Q_MAX,), lambda i, j, s: (0,)),
+            *_Q_SPECS,
             plane, plane, plane, plane, plane,
-            pl.BlockSpec((BLOCK_ROWS, 1), in_map),
+            pl.BlockSpec((PLAIN_ROWS, 1), in_map),
         ],
         out_specs=pl.BlockSpec(
-            (1, BLOCK_ROWS, LANES), lambda i, j, s: (i, j, 0)
+            (1, PLAIN_ROWS, LANES), lambda i, j, s: (i, j, 0)
         ),
     )
     out = pl.pallas_call(
@@ -137,23 +188,21 @@ def _pruned_kernel(
     bounds_ref,  # SMEM i32[k, 2]: exact [start, end) element offsets
     floor_ref,  # SMEM f32[1]: select-stage score floor (prune_eps × mass)
     ub_ref,  # SMEM f32[k, n_tiles*bpt]: per-metadata-block upper bounds
-    qr_ref,
-    qa_ref,
-    x0_hbm,  # ANY-space planar store (full arrays; copied per block below)
+    qr_ref,  # SMEM f32[Q_MAX, 4]
+    qa_ref,  # SMEM f32[Q_MAX]
+    x0_hbm,  # ANY-space planar f32 store (full arrays; copied per block)
     y0_hbm,
     x1_hbm,
     y1_hbm,
-    amp_hbm,
-    sc_hbm,
+    amp_hbm,  # decoded amps (× the int8 row scale, folded in by ops.py)
     out_ref,  # VMEM f32[BLOCK_ROWS, LANES] tile of the score output
-    scored_ref,  # SMEM i32[1, bpt] per-metadata-block scored flags
+    scored_ref,  # SMEM i32[k, n_tiles*bpt] per-metadata-block scored flags
     buf_ref,  # VMEM scratch f32[cb*BLOCK_ROWS, LANES]: partial top-C heap
-    x0_s,  # VMEM scratch [BLOCK_ROWS, LANES] in the store dtypes: the
-    y0_s,  # manually-DMA'd tile (only scored blocks' rows are copied in)
+    x0_s,  # VMEM scratch f32[BLOCK_ROWS, LANES]: the manually-DMA'd
+    y0_s,  # tile (only scored blocks' rows are copied in)
     x1_s,
     y1_s,
     amp_s,
-    sc_s,  # VMEM scratch f32[BLOCK_ROWS, 1]
     copy_sem,  # DMA semaphore for the per-block copies
     *,
     n_tiles: int,
@@ -179,7 +228,7 @@ def _pruned_kernel(
     any_scored = False
     for b in range(bpt):  # static unroll over the tile's metadata blocks
         sb = ub_ref[i, j * bpt + b] > theta
-        scored_ref[0, b] = sb.astype(jnp.int32)
+        scored_ref[i, j * bpt + b] = sb.astype(jnp.int32)
         mask = mask | (sb & (rows // rows_per_block == b))
         any_scored = sb | any_scored
 
@@ -197,7 +246,6 @@ def _pruned_kernel(
                 (x1_hbm, x1_s),
                 (y1_hbm, y1_s),
                 (amp_hbm, amp_s),
-                (sc_hbm, sc_s),
             ):
                 cp = pltpu.make_async_copy(
                     src.at[pl.ds(src_row, rows_per_block), :],
@@ -209,12 +257,11 @@ def _pruned_kernel(
 
     @pl.when(any_scored)
     def _score():
-        # in-register decode of the stored dtypes (see _kernel)
-        x0 = x0_s[...].astype(jnp.float32)
-        y0 = y0_s[...].astype(jnp.float32)
-        x1 = x1_s[...].astype(jnp.float32)
-        y1 = y1_s[...].astype(jnp.float32)
-        amp = amp_s[...].astype(jnp.float32) * sc_s[...]
+        x0 = x0_s[...]
+        y0 = y0_s[...]
+        x1 = x1_s[...]
+        y1 = y1_s[...]
+        amp = amp_s[...]
         acc = jnp.zeros_like(x0)
         for q in range(Q_MAX):  # static unroll over query rects
             qx0 = qr_ref[q, 0]
@@ -253,17 +300,16 @@ def sweep_score_pruned_planar(
     block_ub: jax.Array,  # f32[k, (budget // TILE) * bpt] per-block bounds
     q_rects: jax.Array,  # f32[Q_MAX, 4]
     q_amps: jax.Array,  # f32[Q_MAX]
-    x0: jax.Array,  # [rows, 128] — the ENTIRE toe-print store, planar,
-    y0: jax.Array,  # in its stored dtype (f32/f16 coords, f32/f16/int8 amps)
+    x0: jax.Array,  # f32[rows, 128] — the ENTIRE toe-print store, planar,
+    y0: jax.Array,  # widened to f32 (16/8-bit rows cannot be copied singly)
     x1: jax.Array,
     y1: jax.Array,
-    amp: jax.Array,
-    scale: jax.Array,  # f32[rows, 1] per-row amp scale (ones unless int8)
+    amp: jax.Array,  # f32[rows, 128] decoded amps (× int8 row scale)
     n_sweeps: int,
     budget: int,  # toe prints fetched per sweep; multiple of TILE
     max_candidates: int,  # C of the partial top-C threshold buffer
     bpt: int,  # metadata blocks per TILE (1, 2, 4 or 8)
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Pruned fused sweep: (scores f32[k, budget//LANES, 128],
     scored i32[k, (budget//TILE)*bpt] per-metadata-block flags).
@@ -287,7 +333,7 @@ def sweep_score_pruned_planar(
     cb = max(1, -(-max_candidates // TILE))
 
     # store planes: full arrays, manually copied block-wise in-kernel
-    plane = pl.BlockSpec(memory_space=pltpu.ANY)
+    plane = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_sweeps, n_tiles),
@@ -301,9 +347,7 @@ def sweep_score_pruned_planar(
                 lambda i, j, s: (0, 0),
                 memory_space=pltpu.SMEM,
             ),
-            pl.BlockSpec((Q_MAX, 4), lambda i, j, s: (0, 0)),
-            pl.BlockSpec((Q_MAX,), lambda i, j, s: (0,)),
-            plane,
+            *_Q_SPECS,
             plane,
             plane,
             plane,
@@ -312,24 +356,24 @@ def sweep_score_pruned_planar(
         ],
         out_specs=[
             pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i, j, s: (i, j, 0)),
-            pl.BlockSpec((1, bpt), lambda i, j, s: (i, j), memory_space=pltpu.SMEM),
+            # one resident block over the whole flag array: every grid
+            # step writes its own bpt entries
+            pl.BlockSpec(
+                (n_sweeps, n_tiles * bpt),
+                lambda i, j, s: (0, 0),
+                memory_space=pltpu.SMEM,
+            ),
         ],
         scratch_shapes=[
             pltpu.VMEM((cb * BLOCK_ROWS, LANES), jnp.float32),
-            pltpu.VMEM((BLOCK_ROWS, LANES), x0.dtype),
-            pltpu.VMEM((BLOCK_ROWS, LANES), y0.dtype),
-            pltpu.VMEM((BLOCK_ROWS, LANES), x1.dtype),
-            pltpu.VMEM((BLOCK_ROWS, LANES), y1.dtype),
-            pltpu.VMEM((BLOCK_ROWS, LANES), amp.dtype),
-            pltpu.VMEM((BLOCK_ROWS, 1), jnp.float32),
+            *[pltpu.VMEM((BLOCK_ROWS, LANES), jnp.float32) for _ in range(5)],
             pltpu.SemaphoreType.DMA,
         ],
     )
     kernel = functools.partial(_pruned_kernel, n_tiles=n_tiles, cb=cb, bpt=bpt)
     scores, scored = pl.pallas_call(
-        lambda s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, g, o, f, buf, sa, sb, sc_, sd, se, sg, sem: kernel(
-            s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, g,
-            o.at[0], f, buf, sa, sb, sc_, sd, se, sg, sem
+        lambda s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, o, *rest: kernel(
+            s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, o.at[0], *rest
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -339,6 +383,6 @@ def sweep_score_pruned_planar(
         interpret=interpret,
     )(
         block_starts, bounds, floor, block_ub, q_rects, q_amps,
-        x0, y0, x1, y1, amp, scale,
+        x0, y0, x1, y1, amp,
     )
     return scores, scored

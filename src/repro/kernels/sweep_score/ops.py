@@ -1,7 +1,7 @@
 """jit'd wrappers for the fused sweep_score kernels.
 
 Handles: planarization of the toe-print store, block alignment of sweep
-starts (the kernels DMA TILE-aligned blocks; we align the window down and
+starts (the kernels DMA tile-aligned blocks; we align the window down and
 enlarge the in-kernel budget by one tile so the true [start, end) range is
 always covered), masking back to exact sweep bounds, and — for the pruned
 variant — computing the per-tile block-max upper bounds that drive the
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.core.spatial_index import SCALE_BLOCK
 from repro.kernels.sweep_score.kernel import (
     LANES,
+    PLAIN_TILE,
     Q_MAX,
     TILE,
     sweep_score_planar,
@@ -32,15 +33,14 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _planarize(tp_rects, tp_amps, tp_amp_scale, budget):
+def _planarize(tp_rects, tp_amps, tp_amp_scale, budget, tile):
     """Planar [rows, 128] views of the store in its STORED dtype, padded
     for alignment slop, plus a per-row f32 amp-scale plane [rows, 1].
 
-    Compressed stores keep their narrow dtypes here — the kernels stream
-    the stored bytes and decode in-register (astype f32, × row scale).
     One planar row is exactly one amp-scale block (SCALE_BLOCK == LANES);
     stores without a scale column get an all-ones plane, and ×1.0 keeps
-    the uncompressed path bit-identical.
+    the uncompressed path bit-identical.  Callers adapt the planes to
+    their kernel (:func:`_f16_as_bits`, :func:`_decoded`).
 
     Returns (planes, pad_budget): 6 planes (x0, y0, x1, y1, amp, scale)
     and the per-sweep in-kernel budget (the requested budget rounded up to
@@ -48,8 +48,8 @@ def _planarize(tp_rects, tp_amps, tp_amp_scale, budget):
     """
     assert SCALE_BLOCK == LANES
     T = tp_rects.shape[0]
-    pad_budget = (budget + TILE - 1) // TILE * TILE + TILE
-    Tp = (T + TILE - 1) // TILE * TILE + pad_budget  # tail room for last sweep
+    pad_budget = (budget + tile - 1) // tile * tile + tile
+    Tp = (T + tile - 1) // tile * tile + pad_budget  # tail room for last sweep
     rows = Tp // LANES
 
     def plane(v, fill):
@@ -69,6 +69,23 @@ def _planarize(tp_rects, tp_amps, tp_amp_scale, budget):
         scale,
     )
     return planes, pad_budget
+
+
+def _f16_as_bits(v):
+    """f16 planes as their int16 bit patterns: the v5e cannot load f16
+    vectors, so the unpruned kernel decodes the bits in-register."""
+    if v.dtype == jnp.float16:
+        return jax.lax.bitcast_convert_type(v, jnp.int16)
+    return v
+
+
+def _decoded(planes):
+    """The pruned kernel's f32 planes: each stored plane decoded exactly
+    as the kernels decode (astype f32, amps × row scale).  It copies
+    single rows, and a 16- or 8-bit HBM row is not tile-aligned."""
+    x0, y0, x1, y1, am, sc = planes
+    f32 = [p.astype(jnp.float32) for p in (x0, y0, x1, y1)]
+    return (*f32, am.astype(jnp.float32) * sc)
 
 
 def _pad_query(q_rects, q_amps):
@@ -189,13 +206,14 @@ def sweep_score(
     T = tp_rects.shape[0]
     k = sweep_starts.shape[0]
     qr, qa = _pad_query(q_rects, q_amps)
-    (x0, y0, x1, y1, am, sc), pad_budget = _planarize(
-        tp_rects, tp_amps, tp_amp_scale, budget
+    planes, pad_budget = _planarize(
+        tp_rects, tp_amps, tp_amp_scale, budget, PLAIN_TILE
     )
+    x0, y0, x1, y1, am, sc = (_f16_as_bits(p) for p in planes)
 
     safe = jnp.where(sweep_starts == INVALID, 0, sweep_starts)
-    aligned = (safe // TILE) * TILE  # align down to tile
-    block_starts = (aligned // TILE).astype(jnp.int32)  # TILE units
+    aligned = (safe // PLAIN_TILE) * PLAIN_TILE  # align down to tile
+    block_starts = (aligned // PLAIN_TILE).astype(jnp.int32)  # tile units
 
     out = sweep_score_planar(
         block_starts,
@@ -213,7 +231,7 @@ def sweep_score(
     )  # [k, pad_budget/LANES, LANES]
     flat = out.reshape(k, pad_budget)
     # re-window to exactly [start, start+budget) and mask to [start, end)
-    offs = safe - aligned  # [k] in [0, TILE)
+    offs = safe - aligned  # [k] in [0, PLAIN_TILE)
     idx = offs[:, None] + jnp.arange(budget, dtype=jnp.int32)[None, :]
     scores = jnp.take_along_axis(flat, idx, axis=1)
     pos = safe[:, None] + jnp.arange(budget, dtype=jnp.int32)[None, :]
@@ -263,9 +281,8 @@ def sweep_score_pruned(
     k = sweep_starts.shape[0]
     bpt = TILE // block_size
     qr, qa = _pad_query(q_rects, q_amps)
-    (x0, y0, x1, y1, am, sc), pad_budget = _planarize(
-        tp_rects, tp_amps, tp_amp_scale, budget
-    )
+    planes, pad_budget = _planarize(tp_rects, tp_amps, tp_amp_scale, budget, TILE)
+    x0, y0, x1, y1, am = _decoded(planes)
     n_tiles = pad_budget // TILE
 
     safe, aligned, block_starts, bounds = sweep_window_offsets(
@@ -288,7 +305,6 @@ def sweep_score_pruned(
         x1,
         y1,
         am,
-        sc,
         n_sweeps=k,
         budget=pad_budget,
         max_candidates=max_candidates,

@@ -1,4 +1,3 @@
 from repro.kernels.text_probe.ops import (  # noqa: F401
-    impact_planes,
     text_probe_pruned,
 )

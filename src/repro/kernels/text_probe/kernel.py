@@ -26,9 +26,12 @@ block can never contain a candidate the top-C select stage would keep.
 
 One planar row = one posting block (LANES = 128 postings), so the DMA
 unit is a single ``[1, 128]`` row and no tile alignment of the driver's
-first block is needed.  Grid = (n_win // BLOCK_ROWS,) walked sequentially;
-under ``vmap`` the batch axis becomes the outer grid dimension and the
-``j == 0`` re-init gives every query a fresh θ.
+first block is needed.  The plane is f32 whatever the stored impact
+dtype: a row slice of a 16-bit HBM array is not tile-aligned on the TPU
+(found by compiling for a v5e), and the widening is exact.
+Grid = (n_win // BLOCK_ROWS,) walked sequentially; under ``vmap`` the
+batch axis becomes the outer grid dimension and the ``j == 0`` re-init
+gives every query a fresh θ.
 
 ``monotone=True`` (the impact-ordered layout, whose ``blk_max_impact`` is
 a per-term suffix-max envelope — non-increasing along the block run)
@@ -67,11 +70,38 @@ def slot_theta(bv, floor, c_sel: int):
     ends: slots no candidate ever reaches (lanes past a ragged block's
     length, rows past a short driver's block count) pin the min at the
     floor forever, while for C ≪ 1024 streamed-heavy buffers approximate
-    the stream *minimum* rather than the C-th best.  Shared by the
-    kernel and ``ref.py`` so skip decisions stay bit-identical.
+    the stream *minimum* rather than the C-th best.  ``ref.py`` reads θ
+    through this function; the kernel computes the same value by
+    bisection (:func:`_kth_largest`), since Pallas TPU has no ``top_k``.
     """
     vals = jax.lax.top_k(bv.reshape(-1), c_sel)[0]
     return jnp.maximum(vals[c_sel - 1], floor)
+
+
+def _kth_largest(bv, c_sel: int):
+    """The ``c_sel``-th largest value of ``bv``, by bisection on bits.
+
+    Every slot value is ≥ 0 (slots start at the non-negative floor and
+    only take maxima), and the bit patterns of non-negative floats order
+    like the floats (``& 0x7FFFFFFF`` maps -0.0 to +0.0), so the largest
+    bit pattern ``t`` with ``count(bits >= t) >= c_sel`` is exactly the
+    c_sel-th largest value — equal to ``top_k(bv, c_sel)[0][-1]``.
+    """
+    bits = jax.lax.bitcast_convert_type(bv, jnp.int32) & 0x7FFFFFFF
+
+    def body(_, lh):
+        lo, hi = lh
+        mid = lo + (hi - lo + 1) // 2
+        ok = jnp.sum((bits >= mid).astype(jnp.int32)) >= c_sel
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
+
+    # 31 halvings cover every non-negative bit pattern up to +inf
+    lo, _ = jax.lax.fori_loop(
+        0, 31, body, (jnp.int32(0), jnp.int32(0x7F800000))
+    )
+    # ``lo`` is the bit pattern of an element of bv; read that element back
+    # (Mosaic cannot bitcast a scalar)
+    return jnp.max(jnp.where(bits == lo, bv, -jnp.inf))
 
 
 def _pruned_kernel(
@@ -80,11 +110,11 @@ def _pruned_kernel(
     len_ref,  # SMEM i32[n_win] valid postings per window block
     wb_ref,  # SMEM f32[2]: (w_text, rest_ub) — the optimistic-score affine
     floor_ref,  # SMEM f32[1]: select-stage score floor
-    imp_hbm,  # ANY-space impact plane [rows, LANES] (stored dtype)
-    out_ref,  # VMEM f32[BLOCK_ROWS, LANES] tile of optimistic scores
-    scored_ref,  # SMEM i32[1, BLOCK_ROWS] per-block scored flags
+    imp_hbm,  # ANY-space impact plane f32[rows, LANES]
+    out_ref,  # VMEM f32[BLOCK_ROWS, LANES] tile of optimistic scores;
+    # rows of skipped blocks are -inf (the per-block scored flags)
     buf_ref,  # VMEM scratch f32[cb*BLOCK_ROWS, LANES]: partial top-C heap
-    imp_s,  # VMEM scratch [BLOCK_ROWS, LANES] stored dtype: fetched rows
+    imp_s,  # VMEM scratch f32[BLOCK_ROWS, LANES]: fetched rows
     copy_sem,  # DMA semaphore for the per-block copies
     cut_ref,  # SMEM scratch i32[1]: early-exit cut flag (monotone only)
     *,
@@ -102,7 +132,7 @@ def _pruned_kernel(
         buf_ref[...] = jnp.full_like(buf_ref, floor_ref[0])
         cut_ref[0] = jnp.int32(0)
 
-    theta = slot_theta(buf_ref[...], floor_ref[0], c_sel)
+    theta = jnp.maximum(_kth_largest(buf_ref[...], c_sel), floor_ref[0])
     # under a monotone (non-increasing) bound run the first failing block
     # proves every later block fails too (θ only ever rises): once the cut
     # flag is set, the whole remainder of the term is skipped without even
@@ -111,6 +141,7 @@ def _pruned_kernel(
     rows = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, LANES), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, LANES), 1)
     mask = jnp.zeros((BLOCK_ROWS, LANES), dtype=bool)
+    row_scored = jnp.zeros((BLOCK_ROWS, LANES), dtype=bool)
     any_scored = False
     any_fail = False
     for b in range(BLOCK_ROWS):  # static unroll over the tile's blocks
@@ -119,7 +150,7 @@ def _pruned_kernel(
         any_fail = jnp.logical_not(sb) | any_fail
         if monotone:
             sb = sb & jnp.logical_not(cut)
-        scored_ref[0, b] = sb.astype(jnp.int32)
+        row_scored = row_scored | (sb & (rows == b))
         mask = mask | (sb & (rows == b) & (cols < len_ref[w]))
         any_scored = sb | any_scored
 
@@ -138,11 +169,10 @@ def _pruned_kernel(
 
     @pl.when(any_scored)
     def _score():
-        # in-register decode of the stored dtype, then the optimistic
-        # affine: every posting's best possible final score
-        opt = imp_s[...].astype(jnp.float32) * wb_ref[0] + wb_ref[1]
+        # the optimistic affine: every posting's best possible final score
+        opt = imp_s[...] * wb_ref[0] + wb_ref[1]
         sc = jnp.where(mask, opt, 0.0)
-        out_ref[...] = sc
+        out_ref[...] = jnp.where(row_scored, sc, -jnp.inf)
         # cyclic top-C approximation: fold this tile into its buffer slice
         r0 = (j % cb) * BLOCK_ROWS
         sl = buf_ref[pl.ds(r0, BLOCK_ROWS), :]
@@ -150,7 +180,7 @@ def _pruned_kernel(
 
     @pl.when(jnp.logical_not(any_scored))
     def _skip():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[...] = jnp.full_like(out_ref, -jnp.inf)
 
     if monotone:
         cut_ref[0] = jnp.where(any_fail | cut, 1, 0).astype(jnp.int32)
@@ -165,14 +195,21 @@ def text_probe_pruned_planar(
     lens: jax.Array,  # i32[n_win] valid postings per window block
     wb: jax.Array,  # f32[2]: (w_text, rest_ub)
     floor: jax.Array,  # f32[1] select-stage score floor
-    imp_plane: jax.Array,  # [rows, LANES] impact plane in its stored dtype
+    imp_plane: jax.Array,  # f32[rows, LANES] impact plane
     n_win: int,  # window blocks; multiple of BLOCK_ROWS
     max_candidates: int,  # C of the partial top-C threshold buffer
-    interpret: bool = True,
+    interpret: bool,
     monotone: bool = False,  # bounds non-increasing → early-exit cut flag
 ) -> tuple[jax.Array, jax.Array]:
     """Pruned driver-block walk: (opt f32[n_tiles, BLOCK_ROWS, LANES],
-    scored i32[n_tiles, BLOCK_ROWS] per-block flags)."""
+    scored i32[n_tiles, BLOCK_ROWS] per-block flags).
+
+    The kernel marks a skipped block by writing its row as -inf (a
+    streamed block's lane 0 is a genuine posting, so its score is
+    finite); the flags are read back from that here, because a
+    ``(1, BLOCK_ROWS)`` SMEM output block breaks the TPU's (8, 128)
+    block rule and a whole-array one overflows SMEM at real widths.
+    """
     assert n_win % BLOCK_ROWS == 0
     n_tiles = n_win // BLOCK_ROWS
     # C rounded up to whole tiles: θ is the c_sel-th largest slot value
@@ -191,17 +228,12 @@ def text_probe_pruned_planar(
             pl.BlockSpec((n_win,), lambda j, s: (0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((2,), lambda j, s: (0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((1,), lambda j, s: (0,), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # impact plane
+            pl.BlockSpec(memory_space=pl.ANY),  # impact plane
         ],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda j, s: (j, 0, 0)),
-            pl.BlockSpec(
-                (1, BLOCK_ROWS), lambda j, s: (j, 0), memory_space=pltpu.SMEM
-            ),
-        ],
+        out_specs=pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda j, s: (j, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((cb * BLOCK_ROWS, LANES), jnp.float32),
-            pltpu.VMEM((BLOCK_ROWS, LANES), imp_plane.dtype),
+            pltpu.VMEM((BLOCK_ROWS, LANES), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SMEM((1,), jnp.int32),
         ],
@@ -209,15 +241,13 @@ def text_probe_pruned_planar(
     kernel = functools.partial(
         _pruned_kernel, cb=cb, c_sel=c_sel, monotone=monotone
     )
-    opt, scored = pl.pallas_call(
-        lambda s_ref, ub_r, ln_r, wb_r, fl_r, plane, o, f, buf, sc_, sem, cut: kernel(
-            s_ref, ub_r, ln_r, wb_r, fl_r, plane, o.at[0], f, buf, sc_, sem, cut
+    raw = pl.pallas_call(
+        lambda s_ref, ub_r, ln_r, wb_r, fl_r, plane, o, *rest: kernel(
+            s_ref, ub_r, ln_r, wb_r, fl_r, plane, o.at[0], *rest
         ),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, BLOCK_ROWS, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, BLOCK_ROWS), jnp.int32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((n_tiles, BLOCK_ROWS, LANES), jnp.float32),
         interpret=interpret,
     )(start, ub, lens, wb, floor, imp_plane)
-    return opt, scored
+    skipped = raw == -jnp.inf
+    return jnp.where(skipped, 0.0, raw), 1 - skipped[:, :, 0].astype(jnp.int32)

@@ -1,8 +1,8 @@
 """jit'd wrapper for the fused text_probe kernel.
 
-Handles: the block-major impact plane (one planar row per 128-posting
-block, query-independent — built once per index and closed over by the
-vmapped query fn), the per-window upper bounds / lengths that drive the
+Handles: the block-major impact plane (``TextIndex.imp_plane``, one row
+per 128-posting block, built with the index; widened to f32 here), the
+per-window upper bounds / lengths that drive the
 in-kernel skip test, and the re-flattening of the kernel's tile outputs
 into the per-position (opt, valid, streamed) contract that
 ``core/algorithms.text_first`` consumes.  The bound/length prologue is
@@ -35,28 +35,6 @@ def window_size(max_term_blocks: int) -> int:
     """Static window-block count: max blocks of any term, whole tiles."""
     mtb = max(max_term_blocks, 1)
     return -(-mtb // BLOCK_ROWS) * BLOCK_ROWS
-
-
-def impact_planes(
-    impacts: jax.Array,  # [P] stored dtype (f32 or f16)
-    blk_pos: jax.Array,  # i32[NB]
-    blk_len: jax.Array,  # i32[NB]
-) -> jax.Array:
-    """Block-major impact plane [NB, LANES] in the STORED dtype.
-
-    Row b holds block b's impacts (``impacts[blk_pos[b] : +blk_len[b]]``)
-    zero-padded past ``blk_len`` — query-independent, so callers hoist it
-    out of the per-query vmap.  The kernel streams these stored bytes and
-    decodes in-register (astype f32, then the optimistic affine).
-    """
-    NB = blk_pos.shape[0]
-    P = impacts.shape[0]
-    if P == 0:
-        return jnp.zeros((NB, LANES), impacts.dtype)
-    j = jnp.arange(LANES, dtype=jnp.int32)
-    ap = jnp.clip(blk_pos[:, None] + j[None, :], 0, P - 1)
-    v = impacts[ap]
-    return jnp.where(j[None, :] < blk_len[:, None], v, jnp.zeros((), v.dtype))
 
 
 def window_term_bounds(
@@ -92,7 +70,7 @@ def window_term_bounds(
     static_argnames=("max_candidates", "max_term_blocks", "interpret", "monotone"),
 )
 def text_probe_pruned(
-    imp_plane: jax.Array,  # [NB, LANES] stored-dtype plane (impact_planes)
+    imp_plane: jax.Array,  # [NB, LANES] stored-dtype plane (TextIndex.imp_plane)
     blk_max_impact: jax.Array,  # f32[NB]
     blk_len: jax.Array,  # i32[NB]
     b0: jax.Array,  # i32 scalar: driver term's first block
@@ -138,7 +116,7 @@ def text_probe_pruned(
         lens,
         wb,
         floor_c.reshape(1),
-        imp_plane,
+        imp_plane.astype(jnp.float32),
         n_win=n_win,
         max_candidates=max_candidates,
         interpret=interpret,
@@ -166,7 +144,6 @@ __all__ = [
     "LANES",
     "TILE",
     "INVALID",
-    "impact_planes",
     "text_probe_pruned",
     "window_size",
     "window_term_bounds",

@@ -8,6 +8,14 @@ import; tests and benches must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple[AxisType, ...]:
+    # jax.make_mesh defaults to Explicit axes, under which the logical
+    # ``with_sharding_constraint`` rules (sharding/specs.py) raise; these
+    # meshes are driven by sharding constraints, so their axes are Auto.
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2×16×16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes
-    )
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(
@@ -28,6 +34,4 @@ def make_host_mesh(
     if shape is None:
         shape = (n, 1) if n > 1 else (1, 1)
         axes = ("data", "model")
-    return jax.make_mesh(
-        shape, axes
-    )
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))

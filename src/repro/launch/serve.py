@@ -86,6 +86,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import GeoSearchEngine, QueryBudgets
 from repro.core.distributed import resolve_partitioner
 from repro.corpus import (
@@ -333,6 +334,7 @@ def main() -> None:
         # would never hold a half-full bucket for seconds
         args.max_wait_ms = float("inf") if args.arrival == "closed" else 5.0
 
+    enable_compile_cache()
     print(f"building corpus: {args.n_docs} docs, {args.n_terms} terms …")
     corpus = make_corpus(args.n_docs, args.n_terms, seed=args.seed)
     server, budgets = build_stack(args, corpus)

@@ -504,6 +504,7 @@ def build_geoweb_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Cell:
         blk_len=sh((S, NBt), jnp.int32, lead + (None,)),
         blk_pos=sh((S, NBt), jnp.int32, lead + (None,)),
         blk_max_impact=sh((S, NBt), jnp.float32, lead + (None,)),
+        imp_plane=sh((S, NBt, POSTING_BLOCK), ft, lead + (None, None)),
         blk_term_off=sh((S, M + 1), jnp.int32, lead + (None,)),
         # docID layout: the impact-segment CSR is degenerate (see
         # core/text_index.py build_text_index_np)

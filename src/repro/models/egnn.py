@@ -155,7 +155,7 @@ def make_sharded_loss(cfg: EGNNConfig, mesh):
     batch node arrays must be padded to a multiple of the total device count
     (``pad_nodes``), edge arrays likewise (senders/receivers use GLOBAL ids).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
@@ -241,7 +241,7 @@ def make_sharded_loss(cfg: EGNNConfig, mesh):
         },
     )
     return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()), check_rep=False
+        body, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()), check_vma=False
     )
 
 

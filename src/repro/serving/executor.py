@@ -445,7 +445,13 @@ class MeshExecutor:
         layout: str = "docid",
         **kw,
     ) -> "MeshExecutor":
-        from repro.core.distributed import make_serve_fn, shard_corpus_np
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.core.distributed import (
+            make_serve_fn,
+            shard_corpus_np,
+            sharded_index_specs,
+        )
         from repro.sharding.specs import DEFAULT_RULES
 
         _reject_partition_kwarg(kw)
@@ -462,6 +468,21 @@ class MeshExecutor:
             doc_terms, doc_rects, doc_amps, pagerank, n_terms,
             n_shards, partitioner, grid=grid, compress=compress,
             layout=layout,
+        )
+        # each device holds its own shard: place every field by its spec
+        # (left on one device, every step would re-shard the whole index)
+        specs = sharded_index_specs(
+            doc_axes, grid, n_terms, block_size=sharded.block_size,
+            coverage_grid=sharded.coverage_grid,
+            max_term_blocks=sharded.max_term_blocks, layout=sharded.layout,
+            max_term_segments=sharded.max_term_segments,
+        )
+        sharded = jax.device_put(
+            sharded,
+            jax.tree.map(
+                lambda s: NamedSharding(mesh, s), specs,
+                is_leaf=lambda s: isinstance(s, PartitionSpec),
+            ),
         )
         # sweeps cannot exceed a shard's toe-print store (same clamp as
         # GeoSearchEngine.build applies for the single-index case)

@@ -140,3 +140,54 @@ def test_quantized_impacts_similar_ranking(engine_and_trace):
     # top-1 must agree on ≥90% of queries
     agree = (np.asarray(a.ids)[:, 0] == np.asarray(b.ids)[:, 0]).mean()
     assert agree >= 0.9
+
+
+def _per_doc_oracle(eng, batch, k):
+    """The oracle as a per-document scan: every document probed for every
+    query term (``text_score_of_docs``) — the reference for the
+    term-at-a-time oracle."""
+    from repro.core import footprint as fp
+    from repro.core import ranking
+    from repro.core.text_index import text_score_of_docs
+
+    idx = eng.index
+    all_docs = jnp.arange(idx.spatial.n_docs, dtype=jnp.int32)
+
+    def one(terms, q_rects, q_amps):
+        match, tscore = text_score_of_docs(idx.text, terms, all_docs)
+        g = fp.geo_score(idx.spatial.doc_rects, idx.spatial.doc_amps, q_rects, q_amps)
+        qm = fp.query_mass(q_rects, q_amps)
+        score = ranking.combine_scores(eng.weights, tscore, g, idx.pagerank, qm)
+        return ranking.top_k(jnp.where(match, score, -jnp.inf), all_docs, k)
+
+    return jax.vmap(one)(batch.terms, batch.rects, batch.amps)
+
+
+@pytest.mark.parametrize("layout", ["docid", "impact"])
+@pytest.mark.parametrize("compress", ["none", "f16", "int8"])
+def test_oracle_matches_per_doc_scan(layout, compress):
+    """Ids identical to the per-document scan, including a repeated term,
+    a one-term query and an all-padding query.  Text scores are the same
+    bits; the final scores may differ in the last bit, where XLA fuses the
+    weighted sum differently in the two programs."""
+    from repro.corpus import TraceQuery, make_zipf_trace, pad_trace_batch
+
+    corpus = make_corpus(n_docs=700, n_terms=90, seed=21)
+    eng = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=32, compress=compress, layout=layout,
+    )
+    trace = make_zipf_trace(corpus, n_queries=20, pool_size=20, seed=22)
+    r, a = trace[0].rects, trace[0].amps
+    trace += [
+        TraceQuery(terms=np.array([1, 1], np.int32), rects=r, amps=a),
+        TraceQuery(terms=np.array([0], np.int32), rects=r, amps=a),
+        TraceQuery(terms=np.zeros((0,), np.int32), rects=r, amps=a),
+    ]
+    batch = pad_trace_batch(trace)
+    got = eng.oracle(batch, 10)
+    want_ids, want_scores = _per_doc_oracle(eng, batch, 10)
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want_ids))
+    np.testing.assert_allclose(
+        np.asarray(got.scores), np.asarray(want_scores), rtol=2e-7, atol=0
+    )

@@ -25,6 +25,7 @@ def run_in_subprocess(code: str) -> dict:
 def test_distributed_serve_matches_oracle():
     r = run_in_subprocess(textwrap.dedent("""
         import json, numpy as np, jax
+        from repro.launch.mesh import make_host_mesh
         from repro.corpus import make_corpus, make_query_trace
         from repro.core import GeoSearchEngine, QueryBudgets
         from repro.core.distributed import (
@@ -34,7 +35,7 @@ def test_distributed_serve_matches_oracle():
         corpus = make_corpus(n_docs=512, n_terms=100, seed=0)
         budgets = QueryBudgets(max_candidates=512, max_tiles=256, k_sweeps=4,
                                sweep_budget=256, top_k=10)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2), ("data", "model"))
         sharded = shard_corpus_np(corpus.doc_terms, corpus.doc_rects,
                                   corpus.doc_amps, corpus.pagerank,
                                   corpus.n_terms, 4, MortonPartitioner(),
@@ -63,6 +64,7 @@ def test_distributed_lm_train_step_matches_single_device():
     single-device step (same init, same batch)."""
     r = run_in_subprocess(textwrap.dedent("""
         import json, numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_host_mesh
         from repro.models.transformer import TransformerConfig, loss_fn
         from repro.train.loop import make_train_step
         from repro.train.optimizer import OptimizerConfig, init_opt_state
@@ -81,7 +83,7 @@ def test_distributed_lm_train_step_matches_single_device():
         p1, _, m1 = step1(params, init_opt_state(opt, params), batch)
 
         # 4x2 mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2), ("data", "model"))
         with use_sharding(mesh), mesh:
             stepN = make_train_step(lambda p, b: loss_fn(cfg, p, b), opt, donate=False)
             pN, _, mN = stepN(params, init_opt_state(opt, params), batch)
@@ -98,11 +100,12 @@ def test_compressed_psum_matches_mean():
     """int8 compressed gradient all-reduce ≈ exact mean across shards."""
     r = run_in_subprocess(textwrap.dedent("""
         import json, numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_host_mesh
         from jax.sharding import PartitionSpec as P
         from jax.experimental.shard_map import shard_map
         from repro.train.compression import psum_compressed
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_host_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(0, 1, (8, 512)).astype(np.float32))
 
@@ -127,10 +130,11 @@ def test_zero1_moment_sharding():
     """ZeRO-1: optimizer moments are sharded over data; params replicated."""
     r = run_in_subprocess(textwrap.dedent("""
         import json, numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_host_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train.optimizer import OptimizerConfig, zero1_sharding
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2), ("data", "model"))
         spec = P(None, "model")
         sh = zero1_sharding(mesh, spec, (64, 32))
         print(json.dumps({"spec": str(sh.spec)}))
@@ -143,6 +147,7 @@ def test_mesh_executor_serving_stack():
     with full budgets, mesh-served results must match the exact oracle."""
     r = run_in_subprocess(textwrap.dedent("""
         import json, numpy as np, jax
+        from repro.launch.mesh import make_host_mesh
         from repro.corpus import make_corpus, make_query_trace, make_zipf_trace
         from repro.core import GeoSearchEngine, QueryBudgets
         from repro.serving import (
@@ -152,7 +157,7 @@ def test_mesh_executor_serving_stack():
         corpus = make_corpus(n_docs=512, n_terms=100, seed=0)
         budgets = QueryBudgets(max_candidates=1024, max_tiles=2048, k_sweeps=8,
                                sweep_budget=1024, top_k=10)
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_host_mesh((8, 1), ("data", "model"))
         from repro.core.distributed import MortonPartitioner
         mx = MeshExecutor.build(
             corpus.doc_terms, corpus.doc_rects, corpus.doc_amps,

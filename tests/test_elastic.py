@@ -25,6 +25,7 @@ def run_in_subprocess(code: str) -> dict:
 def test_elastic_resume_on_smaller_mesh():
     r = run_in_subprocess(textwrap.dedent("""
         import json, tempfile, numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_host_mesh
         from repro.models.transformer import TransformerConfig, loss_fn
         from repro.train.loop import make_train_step
         from repro.train.optimizer import OptimizerConfig, init_opt_state
@@ -41,7 +42,7 @@ def test_elastic_resume_on_smaller_mesh():
         dc = LMDataConfig(vocab=256, seq_len=32, global_batch=8)
 
         # phase 1: 8 devices as (data=4, model=2)
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_host_mesh((4, 2), ("data", "model"))
         losses = []
         with tempfile.TemporaryDirectory() as d:
             with use_sharding(mesh1), mesh1:
