@@ -407,13 +407,16 @@ def _text_first_pruned(
         # stored (possibly compressed) record sizes — static per index
         pb = text.posting_bytes
         db = spatial.doc_bytes
+        # the probe streams the scored driver blocks' f32 impact-plane rows
+        # (skipped blocks move zero bytes); the lanes past a short block's
+        # length ride along in its row copy but are not charged
+        streamed_bytes = streamed_valid * jnp.float32(tidx.PLANE_BYTES)
         stats = {
             "candidates": n_c,
             "bytes_spatial": n_c * jnp.float32(R * db),
-            # ONLY the streamed driver blocks count (skipped blocks move
-            # zero bytes), plus the selected candidates' random reads
-            "bytes_postings": streamed_valid * jnp.float32(pb)
-            + n_sel * jnp.float32(pb),
+            # the streamed plane rows, plus the selected candidates'
+            # random reads of the stored postings
+            "bytes_postings": streamed_bytes + n_sel * jnp.float32(pb),
             "fetch_runs": fetch_runs,
             "seeks": fetch_runs + n_terms_real,
             "n_probes": n_c * probes_per,
@@ -423,7 +426,7 @@ def _text_first_pruned(
             # streamed driver posting
             "probes_saved": jnp.maximum(streamed_valid - n_sel, 0)
             * probes_per,
-            "bytes_seq": streamed_valid * jnp.float32(pb),
+            "bytes_seq": streamed_bytes,
             "bytes_random": n_c * jnp.float32(R * db)
             + n_c * probes_per * 32
             + n_sel * jnp.float32(pb),
@@ -569,10 +572,16 @@ def k_sweep(
             # provably holds no candidate the selection would keep.
             if fused:
                 from repro.kernels.sweep_score.ops import sweep_score_pruned as _pr
+
+                # the kernel reads the index's resident 32-bit planes
+                store = dict(planes=spatial.tp_planes)
             else:
                 from repro.kernels.sweep_score.ref import (
                     sweep_score_pruned_ref as _pr,
                 )
+
+                has_scale = spatial.tp_amp_scale.shape[0] > 0
+                store = dict(tp_amp_scale=spatial.tp_amp_scale if has_scale else None)
             floor = jnp.maximum(
                 jnp.float32(budgets.prune_eps) * fp.query_mass(q_rects, q_amps), 0.0
             )
@@ -590,9 +599,7 @@ def k_sweep(
                 budgets.max_candidates,
                 bs,
                 floor,
-                tp_amp_scale=(
-                    spatial.tp_amp_scale if spatial.tp_amp_scale.shape[0] else None
-                ),
+                **store,
             )
             part = part2d.reshape(-1)
             ok = ok2d.reshape(-1)
@@ -696,15 +703,17 @@ def k_sweep(
             probes_saved = (_count_unique(docs, ok) - n_uniq) * n_terms_real
         else:
             probes_saved = jnp.int32(0)
-        # stored (possibly compressed) record sizes — static per index
+        # stored (possibly compressed) record sizes — static per index;
+        # the pruned sweep streams the 32-bit tp_planes instead
         tpb = spatial.tp_bytes
+        stream_b = spatial.pruned_tp_bytes if budgets.prune else tpb
         pb = text.posting_bytes
         stats = {
             "candidates": fetched,
             "sweeps": n_sweeps,
             # bytes actually streamed: ≤k contiguous streams, minus any
             # block-max-skipped blocks on the pruned path
-            "bytes_spatial": streamed_tp * jnp.float32(tpb),
+            "bytes_spatial": streamed_tp * jnp.float32(stream_b),
             "sweep_slack": n_sweeps * budgets.sweep_budget - fetched,
             # toe prints surviving to candidate aggregation (≠ streamed
             # when early termination or pruning drops candidates)
@@ -720,7 +729,7 @@ def k_sweep(
             "n_probes": (
                 text_probes if text_probes is not None else n_uniq * n_terms_real
             ),
-            "bytes_seq": streamed_tp * jnp.float32(tpb),
+            "bytes_seq": streamed_tp * jnp.float32(stream_b),
             "bytes_random": n_uniq * n_terms_real * 32,
         }
         return ids, vals, stats

@@ -82,6 +82,7 @@ from repro.core.text_index import (
 )
 from repro.core import geometry
 from repro.core.planner import coarse_cells
+from repro.kernels.sweep_score.ops import pruned_store_planes
 
 #: Side length of the per-shard coverage bbox grid.  Matches the planner's
 #: ``tp_span`` grid resolution (``planner._SPAN_GRID``): fine enough that
@@ -326,6 +327,7 @@ class ShardedGeoIndex:
     tp_amps: jax.Array  # f32[S, T]
     tp_doc_ids: jax.Array  # i32[S, T]
     tp_amp_scale: jax.Array  # f32[S, ceil(T/SCALE_BLOCK)] ([S, 0] unless int8)
+    tp_planes: jax.Array  # i32[S, P, rows, 128] (SpatialIndex.tp_planes)
     tile_starts: jax.Array  # i32[S, G*G, m]
     tile_ends: jax.Array  # i32[S, G*G, m]
     doc_rects: jax.Array  # f32[S, N, R, 4]
@@ -498,6 +500,15 @@ def shard_corpus_np(
     stacked["tp_amp_scale"] = np.stack(
         [padded(s[1].tp_amp_scale, SB_max, 1.0) for s in shards]
     )
+    # the pruned sweep's 32-bit planes, of the padded store each shard
+    # view serves
+    stacked["tp_planes"] = np.stack([
+        np.asarray(pruned_store_planes(
+            jnp.asarray(stacked["tp_rects"][i]), jnp.asarray(stacked["tp_amps"][i]),
+            jnp.asarray(stacked["tp_amp_scale"][i]), block_size,
+        ))
+        for i in range(len(shards))
+    ])
     stacked["tile_starts"] = np.stack([np.asarray(s[1].tile_starts) for s in shards])
     stacked["tile_ends"] = np.stack([np.asarray(s[1].tile_ends) for s in shards])
     stacked["doc_rects"] = np.stack(
@@ -544,6 +555,7 @@ def shard_corpus_np(
         tp_amps=jnp.asarray(stacked["tp_amps"]),
         tp_doc_ids=jnp.asarray(stacked["tp_doc_ids"]),
         tp_amp_scale=jnp.asarray(stacked["tp_amp_scale"]),
+        tp_planes=jnp.asarray(stacked["tp_planes"]),
         tile_starts=jnp.asarray(stacked["tile_starts"]),
         tile_ends=jnp.asarray(stacked["tile_ends"]),
         doc_rects=jnp.asarray(stacked["doc_rects"]),
@@ -585,7 +597,7 @@ def sharded_index_specs(
         imp_plane=lead, blk_term_off=lead, seg_term_off=lead, seg_pos=lead,
         seg_len=lead,
         tp_rects=lead, tp_amps=lead, tp_doc_ids=lead, tp_amp_scale=lead,
-        tile_starts=lead, tile_ends=lead,
+        tp_planes=lead, tile_starts=lead, tile_ends=lead,
         doc_rects=lead, doc_amps=lead, doc_mbr=lead, doc_mass=lead,
         blk_mbr=lead, blk_max_amp=lead, blk_max_mass=lead,
         pagerank=lead, doc_offset=lead, coverage_sat=lead,
@@ -679,6 +691,7 @@ def make_serve_fn(
         spatial = SpatialIndex(
             tp_rects=idx.tp_rects[0], tp_amps=idx.tp_amps[0],
             tp_doc_ids=idx.tp_doc_ids[0], tp_amp_scale=idx.tp_amp_scale[0],
+            tp_planes=idx.tp_planes[0],
             tile_starts=idx.tile_starts[0], tile_ends=idx.tile_ends[0],
             doc_rects=idx.doc_rects[0], doc_amps=idx.doc_amps[0],
             doc_mbr=idx.doc_mbr[0], doc_mass=idx.doc_mass[0],

@@ -65,6 +65,7 @@ import numpy as np
 from repro.core import algorithms as alg
 from repro.core import geometry
 from repro.core.spatial_index import INVALID
+from repro.core.text_index import PLANE_BYTES
 
 # objective keys: the per-stage counters every algorithm reports
 COST_KEYS = ("n_probes", "bytes_postings", "bytes_spatial")
@@ -139,6 +140,8 @@ class CostModel:
     # predicted bytes_* exactly like they shrink the measured counters
     posting_bytes: float = float(alg.POSTING_BYTES)
     tp_bytes: float = float(alg.TP_BYTES)
+    # what the pruned sweep streams per toe print (32-bit tp_planes + id)
+    pruned_tp_bytes: float = float(alg.TP_BYTES)
     doc_bytes: float = 20.0  # doc-major rect + amp slot
     tp_id_bytes: float = 4.0  # toe-print doc-id column entry
     # (algorithm, counter) -> multiplicative calibration scale
@@ -229,6 +232,7 @@ class CostModel:
             budgets=budgets,
             posting_bytes=float(text.posting_bytes),
             tp_bytes=float(spatial.tp_bytes),
+            pruned_tp_bytes=float(spatial.pruned_tp_bytes),
             doc_bytes=float(spatial.doc_bytes),
             tp_id_bytes=float(spatial.tp_doc_ids.dtype.itemsize),
         )
@@ -259,6 +263,7 @@ class CostModel:
             # mode); weight the amortized per-posting metadata anyway
             posting_bytes=sum(p.posting_bytes * p.n_postings for p in parts) / tot_p,
             tp_bytes=sum(p.tp_bytes * p.n_toeprints for p in parts) / tot_t,
+            pruned_tp_bytes=parts[0].pruned_tp_bytes,
             doc_bytes=parts[0].doc_bytes,
             tp_id_bytes=parts[0].tp_id_bytes,
         )
@@ -326,6 +331,9 @@ class CostModel:
             budgets=budgets,
             posting_bytes=float(posting_bytes),
             tp_bytes=float(plane_b + sharded.tp_doc_ids.dtype.itemsize),
+            pruned_tp_bytes=float(
+                4 * sharded.tp_planes.shape[1] + sharded.tp_doc_ids.dtype.itemsize
+            ),
             doc_bytes=float(
                 4 * sharded.doc_rects.dtype.itemsize
                 + sharded.doc_amps.dtype.itemsize
@@ -408,13 +416,14 @@ class CostModel:
             n_c = min(f.df_min, mc)  # driver-list bound on survivors
             if bud.prune:
                 # block-max pruned traversal: the whole driver list streams
-                # at worst (block skips are modeled as zero, a safe upper
-                # bound like K-SWEEP's — calibration learns the skip rate),
-                # then the select stage caps candidates at mc, so hot-term
-                # queries probe/fetch far fewer docs than they stream
+                # at worst, from the f32 impact plane (block skips are
+                # modeled as zero, a safe upper bound like K-SWEEP's —
+                # calibration learns the skip rate), then the select stage
+                # caps candidates at mc, so hot-term queries probe/fetch far
+                # fewer docs than they stream
                 est = {
                     "n_probes": n_c * max(d - 1, 0),
-                    "bytes_postings": f.df_min * pb + n_c * pb,
+                    "bytes_postings": f.df_min * PLANE_BYTES + n_c * pb,
                     "bytes_spatial": n_c * R * db,
                 }
             else:
@@ -450,7 +459,8 @@ class CostModel:
                 "bytes_postings": n_uniq * logp * pb,
                 # pruning is modeled as zero skips (a safe upper bound);
                 # calibration learns the workload's actual skip rate
-                "bytes_spatial": streamed * tpb,
+                "bytes_spatial": streamed
+                * (self.pruned_tp_bytes if bud.prune else tpb),
             }
         else:
             raise ValueError(f"cost model has no estimator for {plan.algorithm!r}")
@@ -471,11 +481,10 @@ class CostModel:
         """
         bud = plan.budgets
         if plan.algorithm == "text_first":
-            if bud.prune:
-                # pruned traversal sees the WHOLE driver list and keeps the
-                # best-bound ``max_candidates`` — a score-aware cut, not a
-                # blind head-of-list truncation, so no coverage charge
-                return 0.0
+            # pruned or not, at most ``max_candidates`` driver postings
+            # reach the probes: pruning keeps the best by driver impact,
+            # which knows nothing of the footprint, so a driver list longer
+            # than the budget drops candidates either way
             return max(0.0, f.df_min - bud.max_candidates)
         if plan.algorithm == "geo_first":
             return max(0.0, f.tp_est - bud.max_candidates)

@@ -71,6 +71,11 @@ class SpatialIndex:
     tp_amps: jax.Array  # f32[T] (f16 / int8 when compressed)
     tp_doc_ids: jax.Array  # i32[T] (i16 when compressed and n_docs fits)
     tp_amp_scale: jax.Array  # f32[ceil(T/SCALE_BLOCK)] ([0] unless int8)
+    # the pruned sweep kernel's 32-bit copy of the store (it copies single
+    # rows, which a 16- or 8-bit array cannot give it on the TPU):
+    # i32[5, rows, 128] f32 coords + amp, or i32[3, rows, 128] f16 coord
+    # pairs + f32 amp (kernels/sweep_score/ops.py::pruned_store_planes)
+    tp_planes: jax.Array
     # --- tile grid: per tile, m toe-print-ID intervals [start, end) ---
     tile_starts: jax.Array  # i32[G*G, m]
     tile_ends: jax.Array  # i32[G*G, m]
@@ -115,6 +120,12 @@ class SpatialIndex:
         """Modeled bytes per full toe-print record (planes + doc id) — the
         unit behind ``bytes_spatial``/``bytes_scored``.  24 uncompressed."""
         return self.plane_bytes + self.tp_doc_ids.dtype.itemsize
+
+    @property
+    def pruned_tp_bytes(self) -> float:
+        """Bytes per toe print the pruned sweep streams: its 32-bit
+        ``tp_planes`` (20 B with f32 coordinates, 12 B with f16) + doc id."""
+        return 4.0 * self.tp_planes.shape[0] + self.tp_doc_ids.dtype.itemsize
 
     @property
     def doc_bytes(self) -> float:
@@ -230,11 +241,17 @@ def build_spatial_index_np(
         dec_amps,
         block_size,
     )
+    from repro.kernels.sweep_score.ops import pruned_store_planes
+
+    tp_rects = jnp.asarray(rects.astype(ft))
+    tp_amps = jnp.asarray(tp_amps_store)
+    tp_amp_scale = jnp.asarray(tp_amp_scale)
     return SpatialIndex(
-        tp_rects=jnp.asarray(rects.astype(ft)),
-        tp_amps=jnp.asarray(tp_amps_store),
+        tp_rects=tp_rects,
+        tp_amps=tp_amps,
         tp_doc_ids=jnp.asarray(doc_idx.astype(_id_dtype(N, mode))),
-        tp_amp_scale=jnp.asarray(tp_amp_scale),
+        tp_amp_scale=tp_amp_scale,
+        tp_planes=pruned_store_planes(tp_rects, tp_amps, tp_amp_scale, block_size),
         tile_starts=jnp.asarray(tile_starts),
         tile_ends=jnp.asarray(tile_ends),
         doc_rects=jnp.asarray(doc_rects.astype(ft)),

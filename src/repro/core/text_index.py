@@ -91,6 +91,8 @@ from repro.core.geometry import HOST_THREADS
 BLOCK = 128  # docs per bitmap block
 WORDS_PER_BLOCK = BLOCK // 32
 POSTING_BLOCK = 128  # postings per delta/bit-pack compression block
+# bytes per posting in the pruned probe's f32 impact plane (imp_plane)
+PLANE_BYTES = 4
 # PForDelta patch word: slot (8 bits, block slots < 128) | high_bits << 8
 PFOR_SLOT_BITS = 8
 PFOR_HIGH_BITS = 32 - PFOR_SLOT_BITS
@@ -133,9 +135,10 @@ class TextIndex:
     blk_max_impact: jax.Array  # f32[NB]
     # PForDelta exception words per block ([0] when uncompressed)
     blk_n_exc: jax.Array  # i32[NB]
-    # block-major copy of the stored impacts, zero past blk_len: the rows
-    # the pruned TEXT-FIRST probe copies block by block (impact_plane_np)
-    imp_plane: jax.Array  # [NB, POSTING_BLOCK] impact dtype
+    # block-major copy of the stored impacts decoded to f32, zero past
+    # blk_len: the rows the pruned TEXT-FIRST probe copies block by block
+    # (impact_plane_np)
+    imp_plane: jax.Array  # f32[NB, POSTING_BLOCK]
     # impact-ordered segment CSR (degenerate under layout="docid": the
     # probes never read it, so it stays one zero entry)
     seg_term_off: jax.Array  # i32[M+1] CSR of impact segments per term
@@ -236,15 +239,17 @@ def block_max_impacts_np(
 def impact_plane_np(
     impacts: np.ndarray, blk_pos: np.ndarray, blk_len: np.ndarray
 ) -> np.ndarray:
-    """Block-major impact plane — ``[NB, POSTING_BLOCK]`` in the impact dtype.
+    """Block-major impact plane — f32 ``[NB, POSTING_BLOCK]``.
 
     Row b holds block b's impacts (``impacts[blk_pos[b] : +blk_len[b]]``)
-    zero-padded past ``blk_len``.  Built once with the index: gathering it
-    inside every query call is a gather of the whole posting store.
+    zero-padded past ``blk_len``, decoded to f32 (exact for every stored
+    dtype).  The pruned probe kernel copies one row per block, and a row of
+    a 16-bit array is not tile-aligned on the TPU, so the plane is built
+    once in f32 rather than widened in every query call.
     """
     v = np.asarray(impacts)
     NB = blk_pos.shape[0]
-    plane = np.zeros((NB, POSTING_BLOCK), v.dtype)
+    plane = np.zeros((NB, POSTING_BLOCK), np.float32)
     j = np.arange(POSTING_BLOCK, dtype=np.int64)
 
     def rows(b0):

@@ -45,9 +45,12 @@ TPU tiling constraints (found by compiling for a v5e):
 * the v5e cannot load f16 vectors: 16-bit float planes arrive as their
   int16 bit patterns and are widened in-register (``f16_bits_to_f32``);
 * the pruned kernel copies single 128-toe-print rows by hand, and a row
-  slice of a 16- or 8-bit HBM array is not tile-aligned, so its planes
-  arrive decoded to f32 (ops.py; the decode is exact, and the int8 amp
-  scale is folded into the amp plane there);
+  slice of a 16- or 8-bit HBM array is not tile-aligned, so it reads a
+  32-bit copy of the store built once with the index
+  (``SpatialIndex.tp_planes``, see ``ops.pruned_store_planes``): f32
+  coordinates as 4 planes, or f16 coordinates packed two per 32-bit word
+  as 2, plus the amp decoded to f32 (× the int8 row scale) — 20 or 12
+  bytes per toe print, decoded exactly in-register;
 * the per-block ``scored`` flags are one SMEM output block spanning the
   whole array (a ``(1, bpt)`` block violates the (8, 128) block rule).
 """
@@ -183,6 +186,25 @@ def sweep_score_planar(
     return out
 
 
+def _unpack_planes(planes_s, n_planes: int):
+    """(x0, y0, x1, y1, amp) f32 tiles from the copied 32-bit planes: 5
+    planes hold f32 bit patterns; 3 hold the f16 bit pairs ``x0 | y0 << 16``
+    and ``x1 | y1 << 16`` and the f32 amp (``ops.pruned_store_planes``)."""
+    def f32(p):
+        return jax.lax.bitcast_convert_type(planes_s[p], jnp.float32)
+
+    if n_planes == 5:
+        return tuple(f32(p) for p in range(5))
+    lo, hi = planes_s[0], planes_s[1]
+    return (
+        f16_bits_to_f32(lo),
+        f16_bits_to_f32(lo >> 16),
+        f16_bits_to_f32(hi),
+        f16_bits_to_f32(hi >> 16),
+        f32(2),
+    )
+
+
 def _pruned_kernel(
     starts_ref,  # scalar prefetch: i32[k] sweep starts in TILE units
     bounds_ref,  # SMEM i32[k, 2]: exact [start, end) element offsets
@@ -190,24 +212,18 @@ def _pruned_kernel(
     ub_ref,  # SMEM f32[k, n_tiles*bpt]: per-metadata-block upper bounds
     qr_ref,  # SMEM f32[Q_MAX, 4]
     qa_ref,  # SMEM f32[Q_MAX]
-    x0_hbm,  # ANY-space planar f32 store (full arrays; copied per block)
-    y0_hbm,
-    x1_hbm,
-    y1_hbm,
-    amp_hbm,  # decoded amps (× the int8 row scale, folded in by ops.py)
+    planes_hbm,  # ANY-space i32[P, rows, LANES] store planes (copied per block)
     out_ref,  # VMEM f32[BLOCK_ROWS, LANES] tile of the score output
     scored_ref,  # SMEM i32[k, n_tiles*bpt] per-metadata-block scored flags
     buf_ref,  # VMEM scratch f32[cb*BLOCK_ROWS, LANES]: partial top-C heap
-    x0_s,  # VMEM scratch f32[BLOCK_ROWS, LANES]: the manually-DMA'd
-    y0_s,  # tile (only scored blocks' rows are copied in)
-    x1_s,
-    y1_s,
-    amp_s,
+    planes_s,  # VMEM scratch i32[P, BLOCK_ROWS, LANES]: the manually-DMA'd
+    # tile (only scored blocks' rows are copied in)
     copy_sem,  # DMA semaphore for the per-block copies
     *,
     n_tiles: int,
     cb: int,
     bpt: int,  # metadata blocks per tile
+    n_planes: int,
 ):
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -240,16 +256,10 @@ def _pruned_kernel(
         def _fetch(b=b):
             src_row = row0 + b * rows_per_block
             dst_row = b * rows_per_block
-            for src, dst in (
-                (x0_hbm, x0_s),
-                (y0_hbm, y0_s),
-                (x1_hbm, x1_s),
-                (y1_hbm, y1_s),
-                (amp_hbm, amp_s),
-            ):
+            for p in range(n_planes):
                 cp = pltpu.make_async_copy(
-                    src.at[pl.ds(src_row, rows_per_block), :],
-                    dst.at[pl.ds(dst_row, rows_per_block), :],
+                    planes_hbm.at[p, pl.ds(src_row, rows_per_block), :],
+                    planes_s.at[p, pl.ds(dst_row, rows_per_block), :],
                     copy_sem,
                 )
                 cp.start()
@@ -257,11 +267,7 @@ def _pruned_kernel(
 
     @pl.when(any_scored)
     def _score():
-        x0 = x0_s[...]
-        y0 = y0_s[...]
-        x1 = x1_s[...]
-        y1 = y1_s[...]
-        amp = amp_s[...]
+        x0, y0, x1, y1, amp = _unpack_planes(planes_s, n_planes)
         acc = jnp.zeros_like(x0)
         for q in range(Q_MAX):  # static unroll over query rects
             qx0 = qr_ref[q, 0]
@@ -300,11 +306,8 @@ def sweep_score_pruned_planar(
     block_ub: jax.Array,  # f32[k, (budget // TILE) * bpt] per-block bounds
     q_rects: jax.Array,  # f32[Q_MAX, 4]
     q_amps: jax.Array,  # f32[Q_MAX]
-    x0: jax.Array,  # f32[rows, 128] — the ENTIRE toe-print store, planar,
-    y0: jax.Array,  # widened to f32 (16/8-bit rows cannot be copied singly)
-    x1: jax.Array,
-    y1: jax.Array,
-    amp: jax.Array,  # f32[rows, 128] decoded amps (× int8 row scale)
+    planes: jax.Array,  # i32[P, rows, 128] — the ENTIRE toe-print store in
+    # 32-bit planes (16/8-bit rows cannot be copied singly; P = 5 or 3)
     n_sweeps: int,
     budget: int,  # toe prints fetched per sweep; multiple of TILE
     max_candidates: int,  # C of the partial top-C threshold buffer
@@ -328,6 +331,8 @@ def sweep_score_pruned_planar(
     """
     assert budget % TILE == 0
     assert BLOCK_ROWS % bpt == 0
+    n_planes = planes.shape[0]
+    assert planes.dtype == jnp.int32 and n_planes in (3, 5)
     n_tiles = budget // TILE
     # C rounded up to whole tiles: a larger buffer only lowers θ (safer)
     cb = max(1, -(-max_candidates // TILE))
@@ -349,10 +354,6 @@ def sweep_score_pruned_planar(
             ),
             *_Q_SPECS,
             plane,
-            plane,
-            plane,
-            plane,
-            plane,
         ],
         out_specs=[
             pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i, j, s: (i, j, 0)),
@@ -366,14 +367,16 @@ def sweep_score_pruned_planar(
         ],
         scratch_shapes=[
             pltpu.VMEM((cb * BLOCK_ROWS, LANES), jnp.float32),
-            *[pltpu.VMEM((BLOCK_ROWS, LANES), jnp.float32) for _ in range(5)],
+            pltpu.VMEM((n_planes, BLOCK_ROWS, LANES), jnp.int32),
             pltpu.SemaphoreType.DMA,
         ],
     )
-    kernel = functools.partial(_pruned_kernel, n_tiles=n_tiles, cb=cb, bpt=bpt)
+    kernel = functools.partial(
+        _pruned_kernel, n_tiles=n_tiles, cb=cb, bpt=bpt, n_planes=n_planes
+    )
     scores, scored = pl.pallas_call(
-        lambda s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, o, *rest: kernel(
-            s_ref, bd, fl, ub, qr, qa, a, b, c, d, e, o.at[0], *rest
+        lambda s_ref, bd, fl, ub, qr, qa, pln, o, *rest: kernel(
+            s_ref, bd, fl, ub, qr, qa, pln, o.at[0], *rest
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -382,7 +385,6 @@ def sweep_score_pruned_planar(
         ],
         interpret=interpret,
     )(
-        block_starts, bounds, floor, block_ub, q_rects, q_amps,
-        x0, y0, x1, y1, amp,
+        block_starts, bounds, floor, block_ub, q_rects, q_amps, planes,
     )
     return scores, scored

@@ -40,7 +40,7 @@ def _planarize(tp_rects, tp_amps, tp_amp_scale, budget, tile):
     One planar row is exactly one amp-scale block (SCALE_BLOCK == LANES);
     stores without a scale column get an all-ones plane, and ×1.0 keeps
     the uncompressed path bit-identical.  Callers adapt the planes to
-    their kernel (:func:`_f16_as_bits`, :func:`_decoded`).
+    their kernel (:func:`_f16_as_bits`).
 
     Returns (planes, pad_budget): 6 planes (x0, y0, x1, y1, amp, scale)
     and the per-sweep in-kernel budget (the requested budget rounded up to
@@ -71,21 +71,51 @@ def _planarize(tp_rects, tp_amps, tp_amp_scale, budget, tile):
     return planes, pad_budget
 
 
+def pruned_store_planes(tp_rects, tp_amps, tp_amp_scale, block_size: int):
+    """The pruned kernel's copy of the toe-print store, i32[P, rows, LANES].
+
+    The kernel copies single 128-toe-print rows, which must be 32-bit on
+    the TPU, so the store is re-laid once (with the index, as
+    ``SpatialIndex.tp_planes``): f32 coordinates as their 4 bit-pattern
+    planes (P = 5), or f16 coordinates packed two per word as
+    ``x0 | y0 << 16`` and ``x1 | y1 << 16`` (P = 3); the last plane is the
+    amp decoded to f32 (× the int8 row scale).  Rows cover the store
+    rounded up to whole metadata blocks — only blocks overlapping a sweep
+    are ever copied.  Every value decodes exactly to the kernels' f32.
+    """
+    T = tp_rects.shape[0]
+    rows = -(-T // block_size) * (block_size // LANES)
+
+    def plane(v, fill):
+        return jnp.pad(v, (0, rows * LANES - T), constant_values=fill).reshape(
+            rows, LANES
+        )
+
+    amp = tp_amps.astype(jnp.float32)
+    if tp_amp_scale is not None and tp_amp_scale.shape[0]:
+        amp = amp * tp_amp_scale[jnp.arange(T) // SCALE_BLOCK]
+    rect = [plane(tp_rects[:, i], f) for i, f in enumerate((1.0, 1.0, 0.0, 0.0))]
+    if tp_rects.dtype == jnp.float16:
+        bits = [
+            jax.lax.bitcast_convert_type(v, jnp.uint16).astype(jnp.int32)
+            for v in rect
+        ]
+        words = [bits[0] | (bits[1] << 16), bits[2] | (bits[3] << 16)]
+    else:
+        words = [
+            jax.lax.bitcast_convert_type(v.astype(jnp.float32), jnp.int32)
+            for v in rect
+        ]
+    amp_bits = jax.lax.bitcast_convert_type(plane(amp, 0.0), jnp.int32)
+    return jnp.stack([*words, amp_bits])
+
+
 def _f16_as_bits(v):
     """f16 planes as their int16 bit patterns: the v5e cannot load f16
     vectors, so the unpruned kernel decodes the bits in-register."""
     if v.dtype == jnp.float16:
         return jax.lax.bitcast_convert_type(v, jnp.int16)
     return v
-
-
-def _decoded(planes):
-    """The pruned kernel's f32 planes: each stored plane decoded exactly
-    as the kernels decode (astype f32, amps × row scale).  It copies
-    single rows, and a 16- or 8-bit HBM row is not tile-aligned."""
-    x0, y0, x1, y1, am, sc = planes
-    f32 = [p.astype(jnp.float32) for p in (x0, y0, x1, y1)]
-    return (*f32, am.astype(jnp.float32) * sc)
 
 
 def _pad_query(q_rects, q_amps):
@@ -263,8 +293,14 @@ def sweep_score_pruned(
     floor: jax.Array | float = 0.0,  # select-stage score floor (scalar)
     interpret: bool | None = None,
     tp_amp_scale: jax.Array | None = None,  # f32[ceil(T/SCALE_BLOCK)] (int8 store)
+    planes: jax.Array | None = None,  # SpatialIndex.tp_planes of this store
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused fetch+score+select with block-max pruning.
+
+    The kernel reads the store through ``planes``, the index's resident
+    ``tp_planes`` (:func:`pruned_store_planes`).  Called without them, as
+    the kernel tests call it with bare store columns, it builds them in
+    the call — a pass over the whole store.
 
     Returns ``(scores f32[k, budget], valid bool[k, budget], streamed
     bool[k, budget], blocks_scored i32, blocks_active i32)``: ``streamed``
@@ -281,8 +317,10 @@ def sweep_score_pruned(
     k = sweep_starts.shape[0]
     bpt = TILE // block_size
     qr, qa = _pad_query(q_rects, q_amps)
-    planes, pad_budget = _planarize(tp_rects, tp_amps, tp_amp_scale, budget, TILE)
-    x0, y0, x1, y1, am = _decoded(planes)
+    if planes is None:
+        planes = pruned_store_planes(tp_rects, tp_amps, tp_amp_scale, block_size)
+    # whole tiles plus one tile of alignment slop
+    pad_budget = -(-budget // TILE) * TILE + TILE
     n_tiles = pad_budget // TILE
 
     safe, aligned, block_starts, bounds = sweep_window_offsets(
@@ -300,11 +338,7 @@ def sweep_score_pruned(
         win_ub,
         qr,
         qa,
-        x0,
-        y0,
-        x1,
-        y1,
-        am,
+        planes,
         n_sweeps=k,
         budget=pad_budget,
         max_candidates=max_candidates,

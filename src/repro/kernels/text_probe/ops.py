@@ -1,8 +1,6 @@
 """jit'd wrapper for the fused text_probe kernel.
 
-Handles: the block-major impact plane (``TextIndex.imp_plane``, one row
-per 128-posting block, built with the index; widened to f32 here), the
-per-window upper bounds / lengths that drive the
+Handles: the per-window upper bounds / lengths that drive the
 in-kernel skip test, and the re-flattening of the kernel's tile outputs
 into the per-position (opt, valid, streamed) contract that
 ``core/algorithms.text_first`` consumes.  The bound/length prologue is
@@ -70,7 +68,7 @@ def window_term_bounds(
     static_argnames=("max_candidates", "max_term_blocks", "interpret", "monotone"),
 )
 def text_probe_pruned(
-    imp_plane: jax.Array,  # [NB, LANES] stored-dtype plane (TextIndex.imp_plane)
+    imp_plane: jax.Array,  # f32[NB, LANES] impact plane (TextIndex.imp_plane)
     blk_max_impact: jax.Array,  # f32[NB]
     blk_len: jax.Array,  # i32[NB]
     b0: jax.Array,  # i32 scalar: driver term's first block
@@ -116,7 +114,7 @@ def text_probe_pruned(
         lens,
         wb,
         floor_c.reshape(1),
-        imp_plane.astype(jnp.float32),
+        imp_plane,
         n_win=n_win,
         max_candidates=max_candidates,
         interpret=interpret,

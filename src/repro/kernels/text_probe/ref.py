@@ -19,7 +19,7 @@ import jax.numpy as jnp
     jax.jit, static_argnames=("max_candidates", "max_term_blocks", "monotone")
 )
 def text_probe_pruned_ref(
-    imp_plane: jax.Array,  # [NB, LANES] stored-dtype plane (TextIndex.imp_plane)
+    imp_plane: jax.Array,  # f32[NB, LANES] impact plane (TextIndex.imp_plane)
     blk_max_impact: jax.Array,  # f32[NB]
     blk_len: jax.Array,  # i32[NB]
     b0: jax.Array,  # i32 scalar: driver term's first block

@@ -504,7 +504,7 @@ def build_geoweb_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Cell:
         blk_len=sh((S, NBt), jnp.int32, lead + (None,)),
         blk_pos=sh((S, NBt), jnp.int32, lead + (None,)),
         blk_max_impact=sh((S, NBt), jnp.float32, lead + (None,)),
-        imp_plane=sh((S, NBt, POSTING_BLOCK), ft, lead + (None, None)),
+        imp_plane=sh((S, NBt, POSTING_BLOCK), jnp.float32, lead + (None, None)),
         blk_term_off=sh((S, M + 1), jnp.int32, lead + (None,)),
         # docID layout: the impact-segment CSR is degenerate (see
         # core/text_index.py build_text_index_np)
@@ -515,6 +515,10 @@ def build_geoweb_cell(spec: ArchSpec, shape: ShapeSpec, mesh) -> Cell:
         tp_amps=sh((S, Tt), at, lead + (None,)),
         tp_doc_ids=sh((S, Tt), it, lead + (None,)),
         tp_amp_scale=sh((S, SBn), jnp.float32, lead + (None,)),
+        tp_planes=sh(
+            (S, 3 if mode != "none" else 5, NB * (block_size // 128), 128),
+            jnp.int32, lead + (None, None, None),
+        ),
         tile_starts=sh((S, G2, cfg.m_intervals), jnp.int32, lead + (None, None)),
         tile_ends=sh((S, G2, cfg.m_intervals), jnp.int32, lead + (None, None)),
         doc_rects=sh((S, N, R, 4), ft, lead + (None, None, None)),

@@ -35,6 +35,7 @@ from repro.core.text_index import (
     impact_levels_np,
 )
 from repro.corpus import make_corpus
+from repro.kernels.sweep_score.ops import pruned_store_planes
 
 
 # ---------------------------------------------------------------------------
@@ -64,8 +65,9 @@ def _block_max_ref(
 
 
 def _impact_plane_ref(impacts, blk_pos, blk_len):
-    """Block b's impacts in row b, zero-padded past its length."""
-    plane = np.zeros((blk_pos.shape[0], POSTING_BLOCK), impacts.dtype)
+    """Block b's impacts decoded to f32 in row b, zero-padded past its
+    length."""
+    plane = np.zeros((blk_pos.shape[0], POSTING_BLOCK), np.float32)
     for b, (p, n) in enumerate(zip(blk_pos, blk_len)):
         plane[b, :n] = impacts[p : p + n]
     return plane
@@ -465,11 +467,15 @@ def _build_spatial_index_ref(
         dec_amps,
         block_size,
     )
+    tp_rects = jnp.asarray(rects.astype(ft))
+    tp_amps = jnp.asarray(tp_amps_store)
+    tp_amp_scale = jnp.asarray(tp_amp_scale)
     return SpatialIndex(
-        tp_rects=jnp.asarray(rects.astype(ft)),
-        tp_amps=jnp.asarray(tp_amps_store),
+        tp_rects=tp_rects,
+        tp_amps=tp_amps,
         tp_doc_ids=jnp.asarray(doc_idx.astype(_id_dtype(N, mode))),
-        tp_amp_scale=jnp.asarray(tp_amp_scale),
+        tp_amp_scale=tp_amp_scale,
+        tp_planes=pruned_store_planes(tp_rects, tp_amps, tp_amp_scale, block_size),
         tile_starts=jnp.asarray(tile_starts),
         tile_ends=jnp.asarray(tile_ends),
         doc_rects=jnp.asarray(doc_rects.astype(ft)),

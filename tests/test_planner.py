@@ -1,6 +1,8 @@
 """Cost-based query planner tests: cost-model properties, plan-choice
 goldens, the mixture-trace acceptance gate, plan-homogeneous batching, and
 plan threading through the executors and the serving report."""
+from dataclasses import replace
+
 import numpy as np
 import pytest
 
@@ -110,9 +112,10 @@ def test_cost_model_truncation_risk(small_engine):
         tp_span=bud.k_sweeps * bud.sweep_budget * 10.0,
     )
     for algo in ["text_first", "geo_first", "k_sweep"]:
-        plan = QueryPlan(algo, bud)
-        assert model.truncation(plan, covered) == 0.0, algo
-        assert model.truncation(plan, huge) > 0.0, algo
+        for prune in (False, True):
+            plan = QueryPlan(algo, replace(bud, prune=prune))
+            assert model.truncation(plan, covered) == 0.0, (algo, prune)
+            assert model.truncation(plan, huge) > 0.0, (algo, prune)
 
 
 def test_cost_model_calibration_scales(small_engine):

@@ -95,30 +95,41 @@ def test_sweep_score_pruned_compiles(one_chip, mode):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    rects, amps, scale = _store(sds, mode)
+    rects, amps, _ = _store(sds, mode)
     meta = (
         sds((NB_TP, 4), jnp.float32),
         sds((NB_TP,), jnp.float32),
         sds((NB_TP,), jnp.float32),
     )
+    # the index's resident 32-bit planes (SpatialIndex.tp_planes)
+    planes = sds((5 if mode == "none" else 3, NB_TP, 128), jnp.int32)
 
-    def sweep_pruned(rects, amps, mbr, mamp, mmass, s, e, qr, qa, scale):
+    def sweep_pruned(rects, amps, mbr, mamp, mmass, s, e, qr, qa, planes):
         return jax.vmap(
             lambda s, e, qr, qa: sweep_score_pruned(
                 rects, amps, mbr, mamp, mmass, s, e, qr, qa,
                 KB.sweep_budget, KB.max_candidates, 128, 0.0,
-                interpret=False, tp_amp_scale=scale,
+                interpret=False, planes=planes,
             )
         )(s, e, qr, qa)
 
-    hlo = _compile(sweep_pruned, rects, amps, *meta, *_query(sds), scale)
+    hlo = _compile(sweep_pruned, rects, amps, *meta, *_query(sds), planes)
     assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("monotone", [False, True], ids=["docid", "impact"])
 @pytest.mark.parametrize("impact_dtype", ["float32", "float16"])
 def test_text_probe_pruned_compiles(one_chip, impact_dtype, monotone):
+    import numpy as np
+
+    from repro.core.text_index import impact_plane_np
     from repro.kernels.text_probe.ops import text_probe_pruned
+
+    # the plane the index builds for impacts stored in ``impact_dtype``
+    plane_dtype = impact_plane_np(
+        np.zeros((1,), impact_dtype), np.zeros((1,), np.int32),
+        np.ones((1,), np.int32),
+    ).dtype
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -135,7 +146,7 @@ def test_text_probe_pruned_compiles(one_chip, impact_dtype, monotone):
 
     hlo = _compile(
         text_pruned,
-        sds((NB_POST, 128), jnp.dtype(impact_dtype)),
+        sds((NB_POST, 128), plane_dtype),
         sds((NB_POST,), jnp.int32),
         sds((NB_POST,), jnp.float32),
         sds((B,), jnp.int32),
