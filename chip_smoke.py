@@ -9,8 +9,10 @@ The default run builds one chip's share of the paper's deployment
 int32 guard allows, S = 8, so 2^23 per chip, cut as the CUTS below say)
 from a seed, places the index on the chip, and serves a zipf trace through
 the normal entry points — ``serving.factory.make_executor`` with the
-cost-based planner (``--algorithm auto``), block-max pruning and the fused
-Pallas kernels on, then ``GeoServer.run_trace``.  It checks the answers:
+cost-based planner (``--algorithm auto``) under geoweb's exact budgets
+(TEXT-FIRST where its budget covers the query, the exhaustive scan
+otherwise), block-max pruning and the fused Pallas kernels on, then
+``GeoServer.run_trace``.  It checks the answers:
 the fused kernels' top-k ids equal the plain-jnp path's on the same index;
 every served answer holds the query's terms and overlaps its footprint;
 and the served recall@10 against ``algorithms.oracle`` holds the floor the
@@ -91,7 +93,8 @@ def geoweb_budgets():
 
     from repro.configs.geoweb import CONFIG
 
-    # pruning on (it subsumes the config's early_termination)
+    # pruning on (it subsumes the config's early_termination); the
+    # config's exact mode stays on
     return replace(CONFIG.budgets, prune=True, early_termination=False)
 
 
@@ -321,8 +324,11 @@ def one_chip() -> dict:
 
     rep = serve(ex, trace)
     mix = dict(sorted(rep.plan_queries.items()))
+    scan = rep.plan_stats.get("scan", {})
     say(phase="serve", queries=rep.n_queries, batches=rep.n_batches,
-        plan_mix=json.dumps(mix, separators=(",", ":")), seconds=clock.lap())
+        plan_mix=json.dumps(mix, separators=(",", ":")),
+        scan_rounds=int(scan.get("scan_rounds", 0)),
+        scan_candidates=int(scan.get("candidates", 0)), seconds=clock.lap())
 
     # fused kernels vs the plain-jnp path, plan by plan, on the same index
     from dataclasses import replace

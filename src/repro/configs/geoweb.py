@@ -30,6 +30,9 @@ class GeoWebConfig:
     budgets: QueryBudgets = QueryBudgets(
         max_candidates=4096, max_tiles=256, k_sweeps=8, sweep_budget=16384,
         top_k=10, early_termination=True,
+        # every answer exact: a shard's hot queries overflow these budgets
+        # under every paper plan, and ``auto`` then takes the scan
+        exact=True,
     )
     weights: RankWeights = RankWeights()
     # lossy-compressed (f16) footprint + impact data — the paper's own

@@ -1,6 +1,7 @@
-"""The paper's three query-processing algorithms, batched & jit-safe.
+"""The paper's three query-processing algorithms, plus an exact scan,
+batched & jit-safe.
 
-All three share the signature::
+All share the signature::
 
     (text_index, spatial_index, pagerank, query, budgets, weights)
         -> TopKResult(ids [B,k], scores [B,k], stats {str: [B] or scalar})
@@ -16,6 +17,11 @@ Algorithms (paper §IV):
                 memory-resident R*-tree), then filter by text, then fetch.
 * K-SWEEP     — tile intervals → ≤ k coalesced sweeps → bulk contiguous
                 fetch → docID translation → text filter → precise scoring.
+
+Their static budgets truncate a query that overflows them.  SCAN answers
+any query exactly (dense footprint pass, then rounds of probes in
+optimistic-score order); the planner routes to it under
+``QueryBudgets.exact``.
 """
 from __future__ import annotations
 
@@ -97,6 +103,10 @@ class QueryBudgets:
     # is below prune_eps).  0 keeps every positive candidate — lossless for
     # the final top-k whenever max_candidates covers the survivors.
     prune_eps: float = field(default=0.0, metadata=dict(static=True))
+    # exact answers under ``algorithm="auto"``: the planner picks a budgeted
+    # plan only where its budgets provably cover the query, and the
+    # exhaustive ``scan`` otherwise (``planner.CostModel.covers``)
+    exact: bool = field(default=False, metadata=dict(static=True))
 
 
 @jax.tree_util.register_dataclass
@@ -731,6 +741,171 @@ def k_sweep(
             ),
             "bytes_seq": streamed_tp * jnp.float32(stream_b),
             "bytes_random": n_uniq * n_terms_real * 32,
+        }
+        return ids, vals, stats
+
+    ids, vals, stats = jax.vmap(one)(query.terms, query.rects, query.amps)
+    return TopKResult(ids, vals, stats)
+
+
+# ---------------------------------------------------------------------------
+# SCAN — exhaustive exact evaluation (the planner's fallback under `exact`)
+# ---------------------------------------------------------------------------
+
+# a query term with a bitmap (``TextIndex.bitmaps``) is masked by it; a
+# term whose posting list fits SCAN_MASK_BLOCKS blocks is scattered into an
+# exact membership mask and exact per-document impacts.  Index builds for
+# exact budgets give every term with SCAN_BITMAP_MIN_DF postings a bitmap.
+SCAN_MASK_BLOCKS = 512
+SCAN_BITMAP_MIN_DF = SCAN_MASK_BLOCKS * tidx.POSTING_BLOCK // 2
+
+
+@register_algorithm("scan")
+def scan(
+    text: tidx.TextIndex,
+    spatial: sidx.SpatialIndex,
+    pagerank: jax.Array,
+    query: QueryBatch,
+    budgets: QueryBudgets,
+    weights: ranking.RankWeights = ranking.RankWeights(),
+) -> TopKResult:
+    """Exact top-k for queries no budgeted plan covers.
+
+    Every document's geo score is computed from the doc-major footprint
+    mirror (a dense pass).  Each query term masks the documents: by its
+    bitmap where the index has one, else, where its posting list fits
+    ``SCAN_MASK_BLOCKS`` blocks, by scattering the list (which also gives
+    its exact impacts).  Each surviving document gets the optimistic
+    score ``combine_scores(Σ_t u_t, g, pr)``, with ``u_t`` the scattered
+    impact or the term's max impact; documents are visited in descending
+    optimistic order, ``max_candidates`` per round, and each round probes
+    every query term for its candidates and merges their exact scores into
+    a running top-k.  The loop stops once the next optimistic score is
+    below the k-th exact score, so the answer is the oracle's.  The exact
+    text score of a document never exceeds its optimistic one: both sum
+    per-term values in term order from 0, termwise ``impact ≤ u_t``, and
+    f32 rounding is monotone.
+    """
+    N = spatial.n_docs
+    C = max(min(budgets.max_candidates, N), 1)
+    k = budgets.top_k
+    n_rounds = -(-N // C)
+    R = spatial.doc_rects.shape[1]
+    NB = text.blk_pos.shape[0]
+    P = text.n_postings
+    n_win = max(text.max_term_blocks, 1)
+    mask_blocks = min(SCAN_MASK_BLOCKS, n_win)
+    n_bm = text.bitmap_term_ids.shape[0]
+    pr = pagerank.astype(jnp.float32)
+    lane = jnp.arange(tidx.POSTING_BLOCK, dtype=jnp.int32)
+    bit = jnp.arange(32, dtype=jnp.uint32)
+    pb = text.posting_bytes
+    db = spatial.doc_bytes
+    logp = jnp.ceil(jnp.log2(jnp.maximum(jnp.float32(P), 2.0)))
+
+    def scatter_term(t, b0, nb):
+        """The term's first ``mask_blocks`` blocks as a dense (membership
+        bool[N], impact f32[N]) pair."""
+        i = jnp.arange(mask_blocks, dtype=jnp.int32)
+        blocks = jnp.clip(b0 + i, 0, NB - 1)
+        live = (i < nb)[:, None] & (lane[None, :] < text.blk_len[blocks][:, None])
+        pos = jnp.clip(text.blk_pos[blocks][:, None] + lane[None, :], 0, P - 1)
+        if text.is_compressed:
+            docs = tidx.decode_posting_blocks(text, blocks)
+        else:
+            docs = text.postings[pos]
+        slot = jnp.where(live, docs, N).reshape(-1)  # N: dropped
+        hit = jnp.zeros((N,), bool).at[slot].set(True, mode="drop")
+        imp = jnp.zeros((N,), jnp.float32).at[slot].set(
+            text.impacts[pos].astype(jnp.float32).reshape(-1), mode="drop"
+        )
+        return hit, imp
+
+    def bitmap_term(t):
+        """(has a bitmap, membership bool[N]) from the term's bitmap row."""
+        if n_bm == 0:
+            return jnp.bool_(False), jnp.ones((N,), bool)
+        rows = text.bitmap_term_ids == t
+        words = text.bitmaps[jnp.argmax(rows)]
+        bits = ((words[:, None] >> bit[None, :]) & 1).astype(bool)
+        return jnp.any(rows), bits.reshape(-1)[:N]
+
+    def one(terms, q_rects, q_amps):
+        d = terms.shape[0]
+        real = terms >= 0
+        safe_terms = jnp.maximum(terms, 0)
+        g = fp.geo_score(spatial.doc_rects, spatial.doc_amps, q_rects, q_amps)
+        qm = fp.query_mass(q_rects, q_amps)
+        # per-term max impact from the block metadata
+        tb0 = text.blk_term_off[safe_terms]
+        tnb = text.blk_term_off[safe_terms + 1] - tb0
+        wi = jnp.arange(n_win, dtype=jnp.int32)
+        bidx = jnp.clip(tb0[:, None] + wi[None, :], 0, NB - 1)
+        tmax = jnp.max(
+            jnp.where(wi[None, :] < tnb[:, None], text.blk_max_impact[bidx], 0.0),
+            axis=1,
+        )
+        t_ub = jnp.zeros((N,), jnp.float32)
+        mask = jnp.ones((N,), bool)
+        scattered = jnp.int32(0)  # blocks scattered into masks
+        for i in range(d):  # term order, as text_score_of_docs sums
+            has_bm, bm = bitmap_term(safe_terms[i])
+            hit, imp = scatter_term(safe_terms[i], tb0[i], tnb[i])
+            short = ~has_bm & (tnb[i] <= mask_blocks)
+            member = jnp.where(has_bm, bm, hit | ~short)
+            t_ub = t_ub + jnp.where(real[i], jnp.where(short, imp, tmax[i]), 0.0)
+            mask = mask & (member | ~real[i])
+            scattered = scattered + jnp.where(real[i] & short, tnb[i], 0)
+        ub = ranking.combine_scores(weights, t_ub, g, pr, qm)
+        ub = jnp.where(mask, ub, -jnp.inf)
+        order = jnp.argsort(-ub, stable=True).astype(jnp.int32)
+        ub_s = ub[order]
+
+        def next_ub(r):
+            return jnp.where(r * C < N, ub_s[jnp.minimum(r * C, N - 1)], -jnp.inf)
+
+        def cond(carry):
+            r, _, best, _ = carry
+            nxt = next_ub(r)
+            return (r < n_rounds) & (nxt > -jnp.inf) & (nxt >= best[k - 1])
+
+        def body(carry):
+            r, best_ids, best, probed = carry
+            pos = r * C + jnp.arange(C, dtype=jnp.int32)
+            ok = (pos < N) & (ub_s[jnp.minimum(pos, N - 1)] > -jnp.inf)
+            cand = jnp.where(ok, order[jnp.minimum(pos, N - 1)], 0)
+            match, tscore = tidx.text_score_of_docs(text, terms, cand)
+            score = ranking.combine_scores(weights, tscore, g[cand], pr[cand], qm)
+            score = jnp.where(ok & match, score, -jnp.inf)
+            ids, vals = ranking.top_k(
+                jnp.concatenate([best, score]),
+                jnp.concatenate([best_ids, cand]),
+                k,
+            )
+            probed = probed + jnp.sum(ok.astype(jnp.int32))
+            return r + 1, ids, vals, probed
+
+        init = (
+            jnp.int32(0),
+            jnp.full((k,), -1, jnp.int32),
+            jnp.full((k,), -jnp.inf, jnp.float32),
+            jnp.int32(0),
+        )
+        rounds, ids, vals, probed = jax.lax.while_loop(cond, body, init)
+        n_real = jnp.sum(real.astype(jnp.int32))
+        probes = probed * n_real
+        mask_bytes = scattered * jnp.float32(tidx.POSTING_BLOCK * pb)
+        stats = {
+            "candidates": probed,
+            "scan_rounds": rounds,
+            # the dense footprint pass reads every doc-major footprint
+            "bytes_spatial": jnp.float32(N * R * db),
+            # masks stream their terms' blocks; probes binary-search
+            "bytes_postings": mask_bytes + probes * logp * jnp.float32(pb),
+            "seeks": n_real + rounds,
+            "n_probes": probes,
+            "bytes_seq": jnp.float32(N * R * db) + mask_bytes,
+            "bytes_random": probes * 32.0,
         }
         return ids, vals, stats
 

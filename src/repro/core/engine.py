@@ -81,11 +81,14 @@ class GeoSearchEngine:
         # one compression entry point: the builder quantizes impacts (f16
         # under any compressed mode) BEFORE computing blk_max_impact, so
         # pruning bounds are taken over the stored values
+        budgets = budgets or alg.QueryBudgets()
         text = build_text_index_np(
             doc_terms, n_terms, n_bitmap_terms, idf=idf,
             compress=(mode != "none"),
             impact_dtype=(np.float16 if mode != "none" else None),
             layout=layout,
+            # exact answers: the scan masks long posting lists by bitmap
+            bitmap_min_df=alg.SCAN_BITMAP_MIN_DF if budgets.exact else None,
         )
         spatial = build_spatial_index_np(
             doc_rects, doc_amps, grid, m_intervals, compress=mode,
@@ -94,7 +97,6 @@ class GeoSearchEngine:
         n = len(doc_terms)
         if pagerank is None:
             pagerank = np.full((n,), 0.1, dtype=np.float32)
-        budgets = budgets or alg.QueryBudgets()
         # sweeps cannot exceed the store
         budgets = replace(
             budgets, sweep_budget=min(budgets.sweep_budget, spatial.n_toeprints)

@@ -462,6 +462,16 @@ class CostModel:
                 "bytes_spatial": streamed
                 * (self.pruned_tp_bytes if bud.prune else tpb),
             }
+        elif plan.algorithm == "scan":
+            # a dense pass over every doc-major footprint, then one round of
+            # max_candidates exact probes (more rounds only when the
+            # optimistic order has not yet certified the top-k)
+            n_c = min(mc, self.n_docs)
+            est = {
+                "n_probes": n_c * d,
+                "bytes_postings": n_c * d * logp * pb,
+                "bytes_spatial": self.n_docs * R * db,
+            }
         else:
             raise ValueError(f"cost model has no estimator for {plan.algorithm!r}")
         key = plan.algorithm
@@ -491,6 +501,24 @@ class CostModel:
         if plan.algorithm == "k_sweep":
             return max(0.0, f.tp_span - bud.k_sweeps * bud.sweep_budget)
         return 0.0
+
+    def covers(self, plan: QueryPlan, f: QueryFeatures) -> bool:
+        """Whether ``plan`` provably answers this query exactly.
+
+        TEXT-FIRST does when the whole driver list fits ``max_candidates``
+        (pruned or not, with no ``prune_eps`` floor: every driver posting
+        is then probed); the scan always does.  GEO-FIRST's and K-SWEEP's
+        coverage is only estimated (tile and block counts), so they never
+        count as exact.
+        """
+        bud = plan.budgets
+        if plan.algorithm == "scan":
+            return True
+        if plan.algorithm == "text_first":
+            return f.df_min <= bud.max_candidates and not (
+                bud.prune and bud.prune_eps > 0
+            )
+        return False
 
     # ------------------------------------------------------------------
     # calibration
@@ -550,12 +578,14 @@ class Planner:
     def make_candidates(
         budgets: alg.QueryBudgets, fused: bool = False
     ) -> tuple[QueryPlan, ...]:
-        return (
+        plans = (
             # pruned TEXT-FIRST has a fused Pallas pipeline too
             QueryPlan("text_first", budgets, fused=fused and budgets.prune),
             QueryPlan("geo_first", budgets),
             QueryPlan("k_sweep", budgets, fused=fused),
         )
+        # exact answers: the scan takes every query no budget covers
+        return plans + (QueryPlan("scan", budgets),) if budgets.exact else plans
 
     @staticmethod
     def from_engine(engine, fused: bool = False, calibrate_with=None) -> "Planner":
@@ -569,13 +599,18 @@ class Planner:
         return planner
 
     # ------------------------------------------------------------------
-    def cost(self, plan: QueryPlan, f: QueryFeatures) -> float:
-        est = self.model.estimate(plan, f)
+    def cost(self, plan: QueryPlan, f: QueryFeatures, est=None, trunc=None) -> float:
+        """Planner objective; infinite for a plan that would not answer
+        exactly under ``budgets.exact``."""
+        if plan.budgets.exact and not self.model.covers(plan, f):
+            return float("inf")
+        est = self.model.estimate(plan, f) if est is None else est
+        trunc = self.model.truncation(plan, f) if trunc is None else trunc
         return (
             self.w_probes * est["n_probes"]
             + self.w_postings * est["bytes_postings"]
             + self.w_spatial * est["bytes_spatial"]
-            + self.w_truncation * self.model.truncation(plan, f)
+            + self.w_truncation * trunc
         )
 
     def plan_query(self, terms, rects, amps) -> QueryPlan:
@@ -603,12 +638,7 @@ class Planner:
         for plan in self.candidates:
             est = self.model.estimate(plan, f)
             trunc = self.model.truncation(plan, f)
-            c = (
-                self.w_probes * est["n_probes"]
-                + self.w_postings * est["bytes_postings"]
-                + self.w_spatial * est["bytes_spatial"]
-                + self.w_truncation * trunc
-            )
+            c = self.cost(plan, f, est, trunc)
             candidates[plan.label] = {
                 "algorithm": plan.algorithm,
                 **est,

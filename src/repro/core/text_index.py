@@ -597,6 +597,7 @@ def build_text_index_np(
     compress: bool = False,
     impact_dtype: np.dtype | str | None = None,
     layout: str = "docid",
+    bitmap_min_df: int | None = None,
 ) -> TextIndex:
     """Build from per-doc term ids (with repetitions = frequencies): a
     list of arrays or a ``-1``-padded ``[N, L]`` matrix.
@@ -613,6 +614,9 @@ def build_text_index_np(
     one compression entry point — ``normalize_compress`` modes pass f16
     here), so ``blk_max_impact`` is computed from the values that are
     actually stored and the pruning bound survives quantization.
+
+    ``bitmap_min_df`` adds a per-document bitmap for every term with at
+    least that many postings (the exact scan's membership masks).
 
     ``layout`` selects the posting order: ``"docid"`` (ascending doc ids,
     the bit-identical reference) or ``"impact"`` (descending
@@ -675,15 +679,21 @@ def build_text_index_np(
     # block bitmaps for the most frequent terms
     n_blocks = (n_docs + BLOCK - 1) // BLOCK
     n_words = n_blocks * WORDS_PER_BLOCK
+    if bitmap_min_df is not None:
+        n_bitmap_terms = max(n_bitmap_terms, int((df >= bitmap_min_df).sum()))
     if n_bitmap_terms > 0:
         top_terms = np.argsort(-df)[:n_bitmap_terms].astype(np.int32)
         bitmaps = np.zeros((n_bitmap_terms, n_words), dtype=np.uint32)
-        for row, w in enumerate(top_terms):
-            lo, hi = offsets[w], offsets[w + 1]
-            ids = postings[lo:hi]
-            words = ids // 32
-            bits = (ids % 32).astype(np.uint32)
-            np.bitwise_or.at(bitmaps[row], words, np.uint32(1) << bits)
+
+        def fill(row):
+            w = top_terms[row]
+            hit = np.zeros((n_words * 32,), bool)
+            hit[postings[offsets[w] : offsets[w + 1]]] = True
+            # bit (doc % 32) of word doc // 32
+            bitmaps[row] = np.packbits(hit, bitorder="little").view("<u4")
+
+        with ThreadPoolExecutor(HOST_THREADS) as pool:
+            list(pool.map(fill, range(n_bitmap_terms)))
     else:
         top_terms = np.zeros((0,), dtype=np.int32)
         bitmaps = np.zeros((0, n_words), dtype=np.uint32)

@@ -1,5 +1,6 @@
-"""End-to-end algorithm tests: all three paper algorithms vs the exact
-oracle, graceful budget degradation, Pallas-scorer equivalence."""
+"""End-to-end algorithm tests: the three paper algorithms and the exact
+scan vs the exact oracle, graceful budget degradation, Pallas-scorer
+equivalence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +24,7 @@ def engine_and_trace():
     return eng, trace
 
 
-ALGOS = ["text_first", "geo_first", "k_sweep"]
+ALGOS = ["text_first", "geo_first", "k_sweep", "scan"]
 
 
 @pytest.mark.parametrize("algo", ALGOS)
@@ -89,6 +90,62 @@ def test_budget_degradation_graceful():
         res = eng.query(trace, algo)
         ids = np.asarray(res.ids)
         assert ((ids >= -1) & (ids < 300)).all()
+
+
+@pytest.mark.parametrize("compress", ["none", "f16"])
+@pytest.mark.parametrize("layout", ["docid", "impact"])
+@pytest.mark.parametrize("masks", ["scatter", "bitmap", "none"])
+def test_scan_equals_oracle(monkeypatch, compress, layout, masks):
+    """The scan's answers are the oracle's, ids and score bits, over
+    several rounds (max_candidates far below the corpus), whether its term
+    masks come from scattered posting lists, from bitmaps, or not at all
+    (lists too long and no bitmap)."""
+    from repro.core import algorithms as alg
+
+    monkeypatch.setattr(alg, "SCAN_MASK_BLOCKS", 1024 if masks == "scatter" else 1)
+    corpus = make_corpus(n_docs=1200, n_terms=150, seed=11)
+    eng = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=32, compress=compress, layout=layout,
+        n_bitmap_terms=150 if masks == "bitmap" else 0,
+        budgets=QueryBudgets(max_candidates=16, top_k=10),
+    )
+    trace = make_query_trace(corpus, n_queries=24, seed=12)
+    got = eng.query(trace, "scan")
+    want = eng.oracle(trace, 10)
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want.ids))
+    np.testing.assert_array_equal(np.asarray(got.scores), np.asarray(want.scores))
+    rounds = np.asarray(got.stats["scan_rounds"])
+    if masks == "scatter":
+        # every term's exact impacts in the bound: one round certifies
+        assert (rounds <= 1).all()
+    else:
+        assert rounds.max() > 1  # the budget forced more than one round
+    assert (rounds <= -(-1200 // 16)).all()
+
+
+def test_exact_build_gives_long_lists_bitmaps():
+    """Exact budgets build a bitmap for every term the scan cannot
+    scatter, and each bitmap holds exactly its term's documents."""
+    from repro.core import algorithms as alg
+
+    corpus = make_corpus(n_docs=3000, n_terms=60, seed=4)
+    eng = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=16,
+        budgets=QueryBudgets(max_candidates=16, exact=True),
+    )
+    text = eng.index.text
+    offs = np.asarray(text.offsets)
+    df = np.diff(offs)
+    ids = np.asarray(text.bitmap_term_ids)
+    assert set(ids) == set(np.flatnonzero(df >= alg.SCAN_BITMAP_MIN_DF))
+    bits = np.unpackbits(
+        np.asarray(text.bitmaps).view(np.uint8), axis=1, bitorder="little"
+    )
+    posts = np.asarray(text.postings)
+    for row, t in enumerate(ids):
+        assert np.array_equal(np.flatnonzero(bits[row]), np.sort(posts[offs[t] : offs[t + 1]]))
 
 
 def test_pallas_scorer_matches_jnp(engine_and_trace):

@@ -118,6 +118,87 @@ def test_cost_model_truncation_risk(small_engine):
             assert model.truncation(plan, huge) > 0.0, (algo, prune)
 
 
+def test_exact_covers_only_provable_plans(small_engine):
+    """Under ``exact`` only plans whose budgets provably cover the query
+    are admissible: TEXT-FIRST while the driver list fits, the scan
+    always, GEO-FIRST and K-SWEEP never (their coverage is estimated)."""
+    _, eng = small_engine
+    model = eng.planner.model
+    bud = replace(eng.budgets, exact=True)
+    planner = Planner(model=model, candidates=Planner.make_candidates(bud))
+    assert [p.algorithm for p in planner.candidates][-1] == "scan"
+    fits = _feat(df_min=float(bud.max_candidates), tp_est=10.0, tp_span=10.0)
+    over = replace(fits, df_min=bud.max_candidates + 1.0)
+    for prune in (False, True):
+        for algo in ["text_first", "geo_first", "k_sweep", "scan"]:
+            plan = QueryPlan(algo, replace(bud, prune=prune))
+            assert model.covers(plan, fits) == (algo in ("text_first", "scan"))
+            assert model.covers(plan, over) == (algo == "scan")
+            if not model.covers(plan, over):
+                assert planner.cost(plan, over) == float("inf")
+    # a prune_eps floor drops candidates, so it never covers
+    eps = QueryPlan("text_first", replace(bud, prune=True, prune_eps=0.1))
+    assert not model.covers(eps, fits)
+
+
+def test_exact_auto_answers_like_the_oracle():
+    """With budgets far below the corpus, ``auto`` under ``exact`` answers
+    every query as the oracle does (the scan takes what TEXT-FIRST's
+    budget cannot cover), while plain ``auto`` loses answers."""
+    corpus = make_corpus(3000, 200, seed=13)
+    budgets = QueryBudgets(
+        max_candidates=64, max_tiles=16, k_sweeps=2, sweep_budget=64, top_k=10,
+        prune=True,
+    )
+    eng = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=32, budgets=replace(budgets, exact=True),
+    )
+    batch = make_query_trace(corpus, n_queries=32, seed=14)
+    want = eng.oracle(batch, 10)
+    got = eng.query(batch, "auto")
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want.ids))
+    np.testing.assert_array_equal(np.asarray(got.scores), np.asarray(want.scores))
+    labels = {p.label for p in eng.planner.plan_rows(batch)}
+    assert labels == {"text_first+prune", "scan"}, labels
+    plain = replace(eng, budgets=budgets)
+    assert plain.recall_at_k(batch, "auto") < 0.9
+
+
+def test_exact_auto_sharded_serving_matches_oracle():
+    """Served through ``GeoServer`` over footprint-routed doc shards, the
+    exact mode still returns the oracle's ids for every query."""
+    from repro.core.distributed import RegionRangePartitioner
+    from repro.core.ranking import topk_recall_np
+    from repro.serving import DeadlineBatcher
+    from repro.serving.factory import make_executor
+
+    corpus = make_corpus(3000, 200, seed=19)
+    budgets = QueryBudgets(
+        max_candidates=64, max_tiles=16, k_sweeps=2, sweep_budget=64, top_k=10,
+        prune=True, exact=True,
+    )
+    ex = make_executor(
+        "sharded", corpus, algorithm="auto", budgets=budgets, n_shards=2,
+        partitioner=RegionRangePartitioner(), routing="footprint", grid=16,
+        fused=True, compress="f16",
+    )
+    trace = make_zipf_trace(corpus, n_queries=48, pool_size=24, seed=20)
+    rep = GeoServer(
+        ex, cache=None,
+        batcher=DeadlineBatcher(max_batch=8, max_terms=8, max_rects=4),
+    ).run_trace(trace, collect_results=True)
+    assert set(rep.plan_queries) <= {"text_first+prune+fused", "scan"}
+    assert rep.plan_stats["scan"]["scan_rounds"] > 0
+    oracle = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=16, budgets=budgets, compress="f16",
+    )
+    want = np.asarray(oracle.oracle(pad_trace_batch(trace)).ids)
+    got = np.stack([r.ids for r in rep.results])
+    assert topk_recall_np(want, got) == 1.0
+
+
 def test_cost_model_calibration_scales(small_engine):
     """Calibration fits clipped per-(algorithm, counter) scales against the
     measured counters and is idempotent-safe to re-run."""
