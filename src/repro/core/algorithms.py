@@ -309,139 +309,144 @@ def _text_first_pruned(
     # geo: combine_scores adds w_geo·g/max(qm, ε) with g ≤ qm·Σ_r amp_r
     # (area(r ∩ q_s) ≤ area(q_s)), so the normalized term is ≤ w_geo·Σ amps.
     plane = text.imp_plane
-    amp_sum_max = jnp.max(
-        jnp.sum(spatial.doc_amps.astype(jnp.float32), axis=-1), initial=0.0
-    )
-    const_ub = weights.w_geo * amp_sum_max + weights.w_pr * jnp.max(
-        pagerank.astype(jnp.float32), initial=0.0
-    )
+    with jax.named_scope("text_first.bounds"):
+        amp_sum_max = jnp.max(
+            jnp.sum(spatial.doc_amps.astype(jnp.float32), axis=-1), initial=0.0
+        )
+        const_ub = weights.w_geo * amp_sum_max + weights.w_pr * jnp.max(
+            pagerank.astype(jnp.float32), initial=0.0
+        )
     w_text = jnp.float32(weights.w_text)
 
     def one(terms, q_rects, q_amps):
-        d = terms.shape[0]
-        safe_terms = jnp.maximum(terms, 0)
-        tlens = text.offsets[safe_terms + 1] - text.offsets[safe_terms]
-        tlens = jnp.where(terms >= 0, tlens, jnp.int32(2**31 - 1))
-        driver = jnp.argmin(tlens).astype(jnp.int32)
-        t0 = safe_terms[driver]
-        any_real = terms[0] >= 0
-        # per-term max impact from the block metadata: bounds what the
-        # non-driver terms can add to any candidate's text score
-        tb0 = text.blk_term_off[safe_terms]
-        tnb = text.blk_term_off[safe_terms + 1] - tb0
-        wi = jnp.arange(n_win, dtype=jnp.int32)
-        bidx = jnp.clip(tb0[:, None] + wi[None, :], 0, NB - 1)
-        tmax = jnp.max(
-            jnp.where(
-                wi[None, :] < tnb[:, None], text.blk_max_impact[bidx], 0.0
-            ),
-            axis=1,
-        )
-        others = (terms >= 0) & (jnp.arange(d, dtype=jnp.int32) != driver)
-        rest_ub = w_text * jnp.sum(jnp.where(others, tmax, 0.0)) + const_ub
-        b0 = text.blk_term_off[t0]
-        nb = jnp.where(any_real, text.blk_term_off[t0 + 1] - b0, 0)
-        # select floor: prune_eps × the best possible optimistic score —
-        # candidates below it are dropped by the select stage, so the θ
-        # buffer may be seeded with it (skipping provably unselectable
-        # blocks even before C candidates have streamed)
-        floor = jnp.maximum(
-            jnp.float32(budgets.prune_eps) * (w_text * tmax[driver] + rest_ub),
-            0.0,
-        )
-        opt, valid, streamed, blocks_scored, blocks_active = _pr(
-            plane,
-            text.blk_max_impact,
-            text.blk_len,
-            b0,
-            nb,
-            w_text,
-            rest_ub,
-            floor,
-            max_candidates=budgets.max_candidates,
-            max_term_blocks=mtb,
-            # impact layout: blk_max_impact is a per-term suffix-max
-            # envelope (monotone non-increasing), so the traversal may
-            # early-exit the driver at its first failing bound
-            monotone=text.layout == "impact",
-        )
-        # select: partial top-C cut by optimistic score over the streamed
-        # survivors (the pruned twin of the unpruned head-of-list cap)
-        kept = valid & streamed
-        val, sel = jax.lax.top_k(jnp.where(kept, opt, -1.0), Cs)
-        ok_c = kept[sel] & (val > floor)
-        # translate selected lattice positions → doc ids + driver impacts;
-        # only the selected candidates' blocks are decoded
-        w_sel = sel // tidx.POSTING_BLOCK
-        lane = sel % tidx.POSTING_BLOCK
-        gb = jnp.clip(b0 + w_sel, 0, NB - 1)
-        apos = jnp.clip(text.blk_pos[gb] + lane, 0, max(P - 1, 0))
-        if text.is_compressed:
-            dec = tidx.decode_posting_blocks(text, gb)  # [Cs, 128]
-            cand = jnp.take_along_axis(dec, lane[:, None], axis=1)[:, 0]
-        else:
-            cand = text.postings[apos]
-        cand = jnp.where(ok_c, cand, jnp.int32(2**31 - 1))
-        imp_d = jnp.where(ok_c, text.impacts[apos].astype(jnp.float32), 0.0)
+        with jax.named_scope("text_first.bounds"):
+            d = terms.shape[0]
+            safe_terms = jnp.maximum(terms, 0)
+            tlens = text.offsets[safe_terms + 1] - text.offsets[safe_terms]
+            tlens = jnp.where(terms >= 0, tlens, jnp.int32(2**31 - 1))
+            driver = jnp.argmin(tlens).astype(jnp.int32)
+            t0 = safe_terms[driver]
+            any_real = terms[0] >= 0
+            # per-term max impact from the block metadata: bounds what the
+            # non-driver terms can add to any candidate's text score
+            tb0 = text.blk_term_off[safe_terms]
+            tnb = text.blk_term_off[safe_terms + 1] - tb0
+            wi = jnp.arange(n_win, dtype=jnp.int32)
+            bidx = jnp.clip(tb0[:, None] + wi[None, :], 0, NB - 1)
+            tmax = jnp.max(
+                jnp.where(
+                    wi[None, :] < tnb[:, None], text.blk_max_impact[bidx], 0.0
+                ),
+                axis=1,
+            )
+            others = (terms >= 0) & (jnp.arange(d, dtype=jnp.int32) != driver)
+            rest_ub = w_text * jnp.sum(jnp.where(others, tmax, 0.0)) + const_ub
+            b0 = text.blk_term_off[t0]
+            nb = jnp.where(any_real, text.blk_term_off[t0 + 1] - b0, 0)
+            # select floor: prune_eps × the best possible optimistic score —
+            # candidates below it are dropped by the select stage, so the θ
+            # buffer may be seeded with it (skipping provably unselectable
+            # blocks even before C candidates have streamed)
+            floor = jnp.maximum(
+                jnp.float32(budgets.prune_eps) * (w_text * tmax[driver] + rest_ub),
+                0.0,
+            )
+        with jax.named_scope("text_first.walk"):
+            opt, valid, streamed, blocks_scored, blocks_active = _pr(
+                plane,
+                text.blk_max_impact,
+                text.blk_len,
+                b0,
+                nb,
+                w_text,
+                rest_ub,
+                floor,
+                max_candidates=budgets.max_candidates,
+                max_term_blocks=mtb,
+                # impact layout: blk_max_impact is a per-term suffix-max
+                # envelope (monotone non-increasing), so the traversal may
+                # early-exit the driver at its first failing bound
+                monotone=text.layout == "impact",
+            )
+        with jax.named_scope("text_first.select"):
+            # select: partial top-C cut by optimistic score over the streamed
+            # survivors (the pruned twin of the unpruned head-of-list cap)
+            kept = valid & streamed
+            val, sel = jax.lax.top_k(jnp.where(kept, opt, -1.0), Cs)
+            ok_c = kept[sel] & (val > floor)
+            # translate selected lattice positions → doc ids + driver impacts;
+            # only the selected candidates' blocks are decoded
+            w_sel = sel // tidx.POSTING_BLOCK
+            lane = sel % tidx.POSTING_BLOCK
+            gb = jnp.clip(b0 + w_sel, 0, NB - 1)
+            apos = jnp.clip(text.blk_pos[gb] + lane, 0, max(P - 1, 0))
+            if text.is_compressed:
+                dec = tidx.decode_posting_blocks(text, gb)  # [Cs, 128]
+                cand = jnp.take_along_axis(dec, lane[:, None], axis=1)[:, 0]
+            else:
+                cand = text.postings[apos]
+            cand = jnp.where(ok_c, cand, jnp.int32(2**31 - 1))
+            imp_d = jnp.where(ok_c, text.impacts[apos].astype(jnp.float32), 0.0)
+        with jax.named_scope("text_first.probe"):
+            def probe_one(i, carry):
+                valid_c, score = carry
+                t = terms[i]
+                is_real = (t >= 0) & (i != driver)
+                member, imp = tidx.probe_term(text, jnp.maximum(t, 0), cand)
+                valid_c = valid_c & (member | ~is_real)
+                score = score + jnp.where(is_real, imp, 0.0)
+                return valid_c, score
 
-        def probe_one(i, carry):
-            valid_c, score = carry
-            t = terms[i]
-            is_real = (t >= 0) & (i != driver)
-            member, imp = tidx.probe_term(text, jnp.maximum(t, 0), cand)
-            valid_c = valid_c & (member | ~is_real)
-            score = score + jnp.where(is_real, imp, 0.0)
-            return valid_c, score
-
-        valid_c, tscore = jax.lax.fori_loop(0, d, probe_one, (ok_c, imp_d))
-        cand = jnp.where(valid_c, cand, jnp.int32(2**31 - 1))
-        tscore = jnp.where(valid_c, tscore, 0.0)
-        g = _geo_score_docs(spatial, cand, valid_c, q_rects, q_amps, geo_scorer)
-        qm = fp.query_mass(q_rects, q_amps)
-        score = ranking.combine_scores(
-            weights, tscore, g, pagerank[jnp.where(valid_c, cand, 0)], qm
-        )
-        score = jnp.where(valid_c, score, -jnp.inf)
-        ids, vals = ranking.top_k(score, cand, budgets.top_k)
-        n_sel = jnp.sum(ok_c.astype(jnp.int32))  # candidates probed
-        n_c = jnp.sum(valid_c.astype(jnp.int32))  # intersection survivors
-        streamed_valid = jnp.sum((valid & streamed).astype(jnp.int32))
-        n_terms_real = jnp.sum((terms >= 0).astype(jnp.int32))
-        probes_per = jnp.maximum(n_terms_real - 1, 0)
-        cand_sorted = jnp.sort(jnp.where(valid_c, cand, jnp.int32(2**31 - 1)))
-        gap = cand_sorted[1:] - cand_sorted[:-1]
-        new_run = (gap > 64) & (cand_sorted[1:] != jnp.int32(2**31 - 1))
-        fetch_runs = jnp.sum(new_run.astype(jnp.int32)) + (n_c > 0).astype(
-            jnp.int32
-        )
-        # stored (possibly compressed) record sizes — static per index
-        pb = text.posting_bytes
-        db = spatial.doc_bytes
-        # the probe streams the scored driver blocks' f32 impact-plane rows
-        # (skipped blocks move zero bytes); the lanes past a short block's
-        # length ride along in its row copy but are not charged
-        streamed_bytes = streamed_valid * jnp.float32(tidx.PLANE_BYTES)
-        stats = {
-            "candidates": n_c,
-            "bytes_spatial": n_c * jnp.float32(R * db),
-            # the streamed plane rows, plus the selected candidates'
-            # random reads of the stored postings
-            "bytes_postings": streamed_bytes + n_sel * jnp.float32(pb),
-            "fetch_runs": fetch_runs,
-            "seeks": fetch_runs + n_terms_real,
-            "n_probes": n_c * probes_per,
-            "text_blocks_total": blocks_active,
-            "text_blocks_skipped": blocks_active - blocks_scored,
-            # probes avoided by the select stage vs. probing every
-            # streamed driver posting
-            "probes_saved": jnp.maximum(streamed_valid - n_sel, 0)
-            * probes_per,
-            "bytes_seq": streamed_bytes,
-            "bytes_random": n_c * jnp.float32(R * db)
-            + n_c * probes_per * 32
-            + n_sel * jnp.float32(pb),
-        }
-        return ids, vals, stats
+            valid_c, tscore = jax.lax.fori_loop(0, d, probe_one, (ok_c, imp_d))
+        with jax.named_scope("text_first.rank"):
+            cand = jnp.where(valid_c, cand, jnp.int32(2**31 - 1))
+            tscore = jnp.where(valid_c, tscore, 0.0)
+            g = _geo_score_docs(spatial, cand, valid_c, q_rects, q_amps, geo_scorer)
+            qm = fp.query_mass(q_rects, q_amps)
+            score = ranking.combine_scores(
+                weights, tscore, g, pagerank[jnp.where(valid_c, cand, 0)], qm
+            )
+            score = jnp.where(valid_c, score, -jnp.inf)
+            ids, vals = ranking.top_k(score, cand, budgets.top_k)
+            n_sel = jnp.sum(ok_c.astype(jnp.int32))  # candidates probed
+            n_c = jnp.sum(valid_c.astype(jnp.int32))  # intersection survivors
+            streamed_valid = jnp.sum((valid & streamed).astype(jnp.int32))
+            n_terms_real = jnp.sum((terms >= 0).astype(jnp.int32))
+            probes_per = jnp.maximum(n_terms_real - 1, 0)
+            cand_sorted = jnp.sort(jnp.where(valid_c, cand, jnp.int32(2**31 - 1)))
+            gap = cand_sorted[1:] - cand_sorted[:-1]
+            new_run = (gap > 64) & (cand_sorted[1:] != jnp.int32(2**31 - 1))
+            fetch_runs = jnp.sum(new_run.astype(jnp.int32)) + (n_c > 0).astype(
+                jnp.int32
+            )
+            # stored (possibly compressed) record sizes — static per index
+            pb = text.posting_bytes
+            db = spatial.doc_bytes
+            # the probe streams the scored driver blocks' f32 impact-plane rows
+            # (skipped blocks move zero bytes); the lanes past a short block's
+            # length ride along in its row copy but are not charged
+            streamed_bytes = streamed_valid * jnp.float32(tidx.PLANE_BYTES)
+            stats = {
+                "candidates": n_c,
+                "bytes_spatial": n_c * jnp.float32(R * db),
+                # the streamed plane rows, plus the selected candidates'
+                # random reads of the stored postings
+                "bytes_postings": streamed_bytes + n_sel * jnp.float32(pb),
+                "fetch_runs": fetch_runs,
+                "seeks": fetch_runs + n_terms_real,
+                "n_probes": n_c * probes_per,
+                "text_blocks_total": blocks_active,
+                "text_blocks_skipped": blocks_active - blocks_scored,
+                # probes avoided by the select stage vs. probing every
+                # streamed driver posting
+                "probes_saved": jnp.maximum(streamed_valid - n_sel, 0)
+                * probes_per,
+                "bytes_seq": streamed_bytes,
+                "bytes_random": n_c * jnp.float32(R * db)
+                + n_c * probes_per * 32
+                + n_sel * jnp.float32(pb),
+            }
+            return ids, vals, stats
 
     ids, vals, stats = jax.vmap(one)(query.terms, query.rects, query.amps)
     return TopKResult(ids, vals, stats)
@@ -831,83 +836,86 @@ def scan(
         return jnp.any(rows), bits.reshape(-1)[:N]
 
     def one(terms, q_rects, q_amps):
-        d = terms.shape[0]
-        real = terms >= 0
-        safe_terms = jnp.maximum(terms, 0)
-        g = fp.geo_score(spatial.doc_rects, spatial.doc_amps, q_rects, q_amps)
-        qm = fp.query_mass(q_rects, q_amps)
-        # per-term max impact from the block metadata
-        tb0 = text.blk_term_off[safe_terms]
-        tnb = text.blk_term_off[safe_terms + 1] - tb0
-        wi = jnp.arange(n_win, dtype=jnp.int32)
-        bidx = jnp.clip(tb0[:, None] + wi[None, :], 0, NB - 1)
-        tmax = jnp.max(
-            jnp.where(wi[None, :] < tnb[:, None], text.blk_max_impact[bidx], 0.0),
-            axis=1,
-        )
-        t_ub = jnp.zeros((N,), jnp.float32)
-        mask = jnp.ones((N,), bool)
-        scattered = jnp.int32(0)  # blocks scattered into masks
-        for i in range(d):  # term order, as text_score_of_docs sums
-            has_bm, bm = bitmap_term(safe_terms[i])
-            hit, imp = scatter_term(safe_terms[i], tb0[i], tnb[i])
-            short = ~has_bm & (tnb[i] <= mask_blocks)
-            member = jnp.where(has_bm, bm, hit | ~short)
-            t_ub = t_ub + jnp.where(real[i], jnp.where(short, imp, tmax[i]), 0.0)
-            mask = mask & (member | ~real[i])
-            scattered = scattered + jnp.where(real[i] & short, tnb[i], 0)
-        ub = ranking.combine_scores(weights, t_ub, g, pr, qm)
-        ub = jnp.where(mask, ub, -jnp.inf)
-        order = jnp.argsort(-ub, stable=True).astype(jnp.int32)
-        ub_s = ub[order]
-
-        def next_ub(r):
-            return jnp.where(r * C < N, ub_s[jnp.minimum(r * C, N - 1)], -jnp.inf)
-
-        def cond(carry):
-            r, _, best, _ = carry
-            nxt = next_ub(r)
-            return (r < n_rounds) & (nxt > -jnp.inf) & (nxt >= best[k - 1])
-
-        def body(carry):
-            r, best_ids, best, probed = carry
-            pos = r * C + jnp.arange(C, dtype=jnp.int32)
-            ok = (pos < N) & (ub_s[jnp.minimum(pos, N - 1)] > -jnp.inf)
-            cand = jnp.where(ok, order[jnp.minimum(pos, N - 1)], 0)
-            match, tscore = tidx.text_score_of_docs(text, terms, cand)
-            score = ranking.combine_scores(weights, tscore, g[cand], pr[cand], qm)
-            score = jnp.where(ok & match, score, -jnp.inf)
-            ids, vals = ranking.top_k(
-                jnp.concatenate([best, score]),
-                jnp.concatenate([best_ids, cand]),
-                k,
+        with jax.named_scope("scan.geo"):
+            g = fp.geo_score(spatial.doc_rects, spatial.doc_amps, q_rects, q_amps)
+            qm = fp.query_mass(q_rects, q_amps)
+        with jax.named_scope("scan.mask"):
+            d = terms.shape[0]
+            real = terms >= 0
+            safe_terms = jnp.maximum(terms, 0)
+            # per-term max impact from the block metadata
+            tb0 = text.blk_term_off[safe_terms]
+            tnb = text.blk_term_off[safe_terms + 1] - tb0
+            wi = jnp.arange(n_win, dtype=jnp.int32)
+            bidx = jnp.clip(tb0[:, None] + wi[None, :], 0, NB - 1)
+            tmax = jnp.max(
+                jnp.where(wi[None, :] < tnb[:, None], text.blk_max_impact[bidx], 0.0),
+                axis=1,
             )
-            probed = probed + jnp.sum(ok.astype(jnp.int32))
-            return r + 1, ids, vals, probed
+            t_ub = jnp.zeros((N,), jnp.float32)
+            mask = jnp.ones((N,), bool)
+            scattered = jnp.int32(0)  # blocks scattered into masks
+            for i in range(d):  # term order, as text_score_of_docs sums
+                has_bm, bm = bitmap_term(safe_terms[i])
+                hit, imp = scatter_term(safe_terms[i], tb0[i], tnb[i])
+                short = ~has_bm & (tnb[i] <= mask_blocks)
+                member = jnp.where(has_bm, bm, hit | ~short)
+                t_ub = t_ub + jnp.where(real[i], jnp.where(short, imp, tmax[i]), 0.0)
+                mask = mask & (member | ~real[i])
+                scattered = scattered + jnp.where(real[i] & short, tnb[i], 0)
+        with jax.named_scope("scan.order"):
+            ub = ranking.combine_scores(weights, t_ub, g, pr, qm)
+            ub = jnp.where(mask, ub, -jnp.inf)
+            order = jnp.argsort(-ub, stable=True).astype(jnp.int32)
+            ub_s = ub[order]
+        with jax.named_scope("scan.probe"):
+            def next_ub(r):
+                return jnp.where(r * C < N, ub_s[jnp.minimum(r * C, N - 1)], -jnp.inf)
 
-        init = (
-            jnp.int32(0),
-            jnp.full((k,), -1, jnp.int32),
-            jnp.full((k,), -jnp.inf, jnp.float32),
-            jnp.int32(0),
-        )
-        rounds, ids, vals, probed = jax.lax.while_loop(cond, body, init)
-        n_real = jnp.sum(real.astype(jnp.int32))
-        probes = probed * n_real
-        mask_bytes = scattered * jnp.float32(tidx.POSTING_BLOCK * pb)
-        stats = {
-            "candidates": probed,
-            "scan_rounds": rounds,
-            # the dense footprint pass reads every doc-major footprint
-            "bytes_spatial": jnp.float32(N * R * db),
-            # masks stream their terms' blocks; probes binary-search
-            "bytes_postings": mask_bytes + probes * logp * jnp.float32(pb),
-            "seeks": n_real + rounds,
-            "n_probes": probes,
-            "bytes_seq": jnp.float32(N * R * db) + mask_bytes,
-            "bytes_random": probes * 32.0,
-        }
-        return ids, vals, stats
+            def cond(carry):
+                r, _, best, _ = carry
+                nxt = next_ub(r)
+                return (r < n_rounds) & (nxt > -jnp.inf) & (nxt >= best[k - 1])
+
+            def body(carry):
+                r, best_ids, best, probed = carry
+                pos = r * C + jnp.arange(C, dtype=jnp.int32)
+                ok = (pos < N) & (ub_s[jnp.minimum(pos, N - 1)] > -jnp.inf)
+                cand = jnp.where(ok, order[jnp.minimum(pos, N - 1)], 0)
+                match, tscore = tidx.text_score_of_docs(text, terms, cand)
+                score = ranking.combine_scores(weights, tscore, g[cand], pr[cand], qm)
+                score = jnp.where(ok & match, score, -jnp.inf)
+                ids, vals = ranking.top_k(
+                    jnp.concatenate([best, score]),
+                    jnp.concatenate([best_ids, cand]),
+                    k,
+                )
+                probed = probed + jnp.sum(ok.astype(jnp.int32))
+                return r + 1, ids, vals, probed
+
+            init = (
+                jnp.int32(0),
+                jnp.full((k,), -1, jnp.int32),
+                jnp.full((k,), -jnp.inf, jnp.float32),
+                jnp.int32(0),
+            )
+            rounds, ids, vals, probed = jax.lax.while_loop(cond, body, init)
+            n_real = jnp.sum(real.astype(jnp.int32))
+            probes = probed * n_real
+            mask_bytes = scattered * jnp.float32(tidx.POSTING_BLOCK * pb)
+            stats = {
+                "candidates": probed,
+                "scan_rounds": rounds,
+                # the dense footprint pass reads every doc-major footprint
+                "bytes_spatial": jnp.float32(N * R * db),
+                # masks stream their terms' blocks; probes binary-search
+                "bytes_postings": mask_bytes + probes * logp * jnp.float32(pb),
+                "seeks": n_real + rounds,
+                "n_probes": probes,
+                "bytes_seq": jnp.float32(N * R * db) + mask_bytes,
+                "bytes_random": probes * 32.0,
+            }
+            return ids, vals, stats
 
     ids, vals, stats = jax.vmap(one)(query.terms, query.rects, query.amps)
     return TopKResult(ids, vals, stats)

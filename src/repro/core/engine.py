@@ -21,6 +21,7 @@ query from posting-list lengths and footprint coverage estimates.
 """
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, replace
 from typing import Callable
 
@@ -33,6 +34,13 @@ from repro.core import ranking
 from repro.core.planner import Planner, QueryPlan
 from repro.core.spatial_index import SpatialIndex, build_spatial_index_np
 from repro.core.text_index import TextIndex, build_text_index_np
+
+
+def program_name(plan: QueryPlan) -> str:
+    """The name a plan's jitted program runs under: ``geo_`` and the plan's
+    label, each character outside ``[A-Za-z0-9_]`` made ``_``
+    (``text_first+prune+fused`` → ``geo_text_first_prune_fused``)."""
+    return "geo_" + re.sub(r"\W", "_", plan.label)
 
 
 @jax.tree_util.register_dataclass
@@ -224,7 +232,6 @@ class GeoSearchEngine:
                 ),
             )
 
-            @jax.jit
             def run(index: GeoIndex, batch: alg.QueryBatch):
                 return fn(
                     index.text,
@@ -236,7 +243,10 @@ class GeoSearchEngine:
                     **kw,
                 )
 
-            cache[key] = run
+            # the program carries its plan's name (``jit_geo_scan``), so its
+            # runs name themselves in a profiler trace
+            run.__name__ = run.__qualname__ = program_name(plan)
+            cache[key] = jax.jit(run)
         return cache[key]
 
     # ------------------------------------------------------------------

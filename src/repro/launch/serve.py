@@ -53,13 +53,19 @@ per-plan ``routing:`` fan-out line (mean shards-touched per query).
 
 Telemetry (``--trace-out/--metrics-out/--audit-out/--events-out``): any of
 these flags builds the server with a :class:`repro.obs.Telemetry` handle
-and exports, post-run, a Chrome/Perfetto ``trace_event`` JSON of every
-query/batch/executor span (open it at https://ui.perfetto.dev), a metrics
-snapshot (Prometheus text for ``.prom``/``.txt`` paths, JSON otherwise),
-the planner audit JSONL (predicted vs measured cost per planned query;
-``--algorithm auto`` only), and the flush/dispatch/complete/evict/coalesce
-event JSONL.  Without the flags the server runs telemetry-free (zero
-overhead).
+and exports, post-run, a Chrome/Perfetto ``trace_event`` JSON (open it
+at https://ui.perfetto.dev): every query's span and its stages, one span
+per batch on its worker's track, and, on the wall clock, the server's
+host stages (``geo.plan`` for planning a miss; ``geo.batch`` for one
+executed batch, with its children ``geo.dispatch``, ``geo.result``,
+``geo.stats`` and ``geo.deliver``) and, for ``--shards``, one span per
+shard from dispatch to host pull.  Each stage is also a
+``jax.profiler.TraceAnnotation``, so it appears in a profiler trace when
+one is running.  The other flags write a metrics snapshot (Prometheus
+text for ``.prom``/``.txt`` paths, JSON otherwise), the planner audit
+JSONL (predicted vs measured cost per planned query; ``--algorithm auto``
+only), and the flush/dispatch/complete/evict/coalesce event JSONL.
+Without the flags the server runs telemetry-free (zero overhead).
 
 ``--algorithm auto`` turns on the cost-based planner
 (:mod:`repro.core.planner`): every miss is routed to the cheapest of

@@ -22,12 +22,17 @@ Two additional span families share the file:
 * **batch spans** — one per executed batch on its worker's track
   (``worker 0..N-1``); per-worker timelines are sequential, so each track
   is monotone (validated by :mod:`repro.obs.validate`).
-* **executor spans** — wall-clock spans measured *inside* the executors
-  (per-shard spans of :class:`~repro.serving.executor.ShardedExecutor`'s
-  sequential scatter-gather loop, the mesh step, the single-device engine
-  call).  They live in a separate trace process ("executors (wall clock)")
-  because open-loop virtual time and host wall time are different clock
-  domains; mixing them on one track would be a lie.
+* **executor spans** — wall-clock spans measured on the host: the
+  server's stages (:meth:`SpanRecorder.stage`, track ``server``:
+  ``geo.plan``, ``geo.batch`` and its children ``geo.dispatch``,
+  ``geo.result``, ``geo.stats``, ``geo.deliver``) and the per-shard spans
+  of :class:`~repro.serving.executor.ShardedExecutor`'s scatter-gather
+  loop, each from a shard's dispatch to its host pull.  They live in a
+  separate trace process ("executors (wall clock)") because open-loop
+  virtual time and host wall time are different clock domains; mixing
+  them on one track would be a lie.  A stage is also a
+  ``jax.profiler.TraceAnnotation``, so while a profiler trace runs the
+  same interval appears there, on the device trace's clock.
 
 Export targets the ``trace_event`` JSON array format (Chrome's
 ``chrome://tracing`` and Perfetto's https://ui.perfetto.dev both open it
@@ -39,13 +44,17 @@ from __future__ import annotations
 
 import json
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 # trace process ids: virtual-clock serving timeline vs wall-clock executors
 PID_SERVING = 1
 PID_EXECUTOR = 2
 TID_QUERIES = 1
 TID_WORKER0 = 10  # worker w -> tid TID_WORKER0 + w
+STAGE_TRACK = "server"  # the executor-process track of the server's stages
 
 
 @dataclass
@@ -87,7 +96,7 @@ class BatchSpan:
 
 @dataclass
 class ExecSpan:
-    track: str  # e.g. "shard 3", "engine", "mesh step"
+    track: str  # e.g. "server", "shard 3"
     name: str
     t0: float  # wall seconds relative to recorder start
     t1: float
@@ -154,6 +163,29 @@ class SpanRecorder:
 
     def span(self, track: str, name: str, t0: float, t1: float, args=None) -> None:
         self.exec_spans.append(ExecSpan(track, name, t0, t1, args))
+
+    @contextmanager
+    def stage(self, name: str, **args):
+        """Record the enclosed host work as the span ``name`` on the
+        ``server`` track, and mark it for a running profiler trace.  The
+        span is appended when it opens, so nested stages follow their
+        parent in start order."""
+        t0 = self.wall_now()
+        span = ExecSpan(STAGE_TRACK, name, t0, t0, args or None)
+        self.exec_spans.append(span)
+        try:
+            with TraceAnnotation(name):
+                yield
+        finally:
+            span.t1 = self.wall_now()
+
+    def stage_seconds(self, start: int = 0) -> dict[str, float]:
+        """Summed seconds of each stage recorded since ``exec_spans[start]``."""
+        out: dict[str, float] = {}
+        for s in self.exec_spans[start:]:
+            if s.track == STAGE_TRACK:
+                out[s.name] = out.get(s.name, 0.0) + (s.t1 - s.t0)
+        return out
 
     # ------------------------------------------------------------------
     # export

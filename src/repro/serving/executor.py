@@ -37,13 +37,14 @@ Fixed-algorithm executors return ``None`` from :meth:`plan_query` and run
 exactly as before.
 
 Telemetry: every executor exposes :meth:`attach_telemetry` (the server
-calls it when built with a :class:`~repro.obs.Telemetry` handle).  With a
-tracer attached, executors record **wall-clock** spans on the trace's
-executor process — the engine call for :class:`SingleDeviceExecutor`, one
-span per shard of :class:`ShardedExecutor`'s sequential scatter-gather
-loop, and the mesh step for :class:`MeshExecutor` — and route their
-engines' compile counters / the planner's probe counters into the metrics
-registry.  ``telemetry=None`` (the default) leaves ``run`` untouched.
+calls it when built with a :class:`~repro.obs.Telemetry` handle) and
+routes its engines' compile counters / the planner's probe counters into
+the metrics registry.  With a tracer attached, :class:`ShardedExecutor`
+records one **wall-clock** span per shard, from the shard's dispatch to
+its host pull.  The single-device and mesh executors record none: a span
+round their dispatch would end before the device finishes (the server's
+``geo.dispatch`` and ``geo.result`` stages time the call and the wait).
+``telemetry=None`` (the default) leaves ``run`` untouched.
 """
 from __future__ import annotations
 
@@ -95,7 +96,6 @@ class SingleDeviceExecutor:
         self.engine = engine
         self.algorithm = algorithm
         self.kw = kw
-        self.telemetry = None
         self.planner: Planner | None = None
         if algorithm == "auto":
             self.planner = Planner.from_engine(
@@ -107,7 +107,6 @@ class SingleDeviceExecutor:
         return self.engine.budgets.top_k
 
     def attach_telemetry(self, telemetry) -> None:
-        self.telemetry = telemetry
         if telemetry and telemetry.metrics is not None:
             self.engine.metrics = telemetry.metrics
             if self.planner is not None:
@@ -122,19 +121,9 @@ class SingleDeviceExecutor:
     def run(
         self, batch: alg.QueryBatch, plan: QueryPlan | None = None
     ) -> alg.TopKResult:
-        tracer = self.telemetry.tracer if self.telemetry else None
-        t0 = tracer.wall_now() if tracer is not None else 0.0
         if plan is not None:
-            res = self.engine.query(batch, plan=plan, **self.kw)
-        else:
-            res = self.engine.query(batch, self.algorithm, **self.kw)
-        if tracer is not None:
-            label = plan.label if plan is not None else self.algorithm
-            tracer.span(
-                "engine", f"query[{label}]", t0, tracer.wall_now(),
-                args={"batch": int(batch.terms.shape[0])},
-            )
-        return res
+            return self.engine.query(batch, plan=plan, **self.kw)
+        return self.engine.query(batch, self.algorithm, **self.kw)
 
 
 class ShardedExecutor:
@@ -558,16 +547,8 @@ class MeshExecutor:
         self, batch: alg.QueryBatch, plan: QueryPlan | None = None
     ) -> alg.TopKResult:
         serve = self._serve_for(plan)
-        tracer = self.telemetry.tracer if self.telemetry else None
-        t0 = tracer.wall_now() if tracer is not None else 0.0
         with self.mesh:
             out = serve(self._index, batch)
-        if tracer is not None:
-            label = plan.label if plan is not None else self.algorithm
-            tracer.span(
-                "mesh step", f"serve[{label}]", t0, tracer.wall_now(),
-                args={"batch": int(batch.terms.shape[0])},
-            )
         if len(out) == 3:
             ids, scores, stats = out
         else:  # hand-built executor around a stats-less make_serve_fn

@@ -58,6 +58,7 @@ from __future__ import annotations
 import heapq
 import itertools
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 
 import jax
@@ -74,6 +75,9 @@ from repro.serving.batcher import (
 )
 from repro.serving.fingerprint import query_fingerprint
 from repro.serving.pending import PendingTable
+
+# what ``GeoServer._stage`` returns without a tracer: one reusable no-op
+_NO_STAGE = nullcontext()
 
 
 @dataclass
@@ -125,6 +129,9 @@ class ServeReport:
     # {"queries", "shards_touched", "batches", "shards_visited"} — the
     # per-query mean shards-touched is the routing win the paper argues for
     routing: dict = field(default_factory=dict)
+    # host seconds per server stage (geo.plan, geo.batch, geo.dispatch, ...)
+    # summed over this run, where the telemetry has a tracer to time them
+    stage_s: dict = field(default_factory=dict)
     # per-trace-position results (run_trace(collect_results=True) only)
     results: list | None = None
     arrival: str = "closed"
@@ -349,10 +356,14 @@ class GeoServer:
         # snapshot cumulative batcher counters so the report is per-run
         b = self.batcher
         base = (b.pad_slots, b.real_slots, b.pad_elements, b.real_elements)
+        tracer = self.telemetry.tracer if self.telemetry else None
+        n_spans = len(tracer.exec_spans) if tracer is not None else 0
         if open_loop:
             self._run_open(trace, report, service_time)
         else:
             self._run_closed(trace, report)
+        if tracer is not None:
+            report.stage_s = tracer.stage_seconds(n_spans)
         report.pad_slots = b.pad_slots - base[0]
         report.real_slots = b.real_slots - base[1]
         pad_el, real_el = b.pad_elements - base[2], b.real_elements - base[3]
@@ -449,8 +460,9 @@ class GeoServer:
             self._inflight[qid] = (key, t_arr, idx)
             if self._pending is not None:
                 self._pending.register(key, qid)
-            plan = self._plan_for(q)
-            self._audit_plan(qid, idx, q, plan, t_arr)
+            with self._stage("geo.plan"):
+                plan = self._plan_for(q)
+                self._audit_plan(qid, idx, q, plan, t_arr)
             pending = PendingQuery(qid, q.terms, q.rects, q.amps, plan)
             raws = (
                 self.batcher.add(pending, t_arr)
@@ -540,8 +552,9 @@ class GeoServer:
             self._inflight[qid] = (key, now, idx)
             if self._pending is not None:
                 self._pending.register(key, qid)
-            plan = self._plan_for(q)
-            self._audit_plan(qid, idx, q, plan, now)
+            with self._stage("geo.plan"):
+                plan = self._plan_for(q)
+                self._audit_plan(qid, idx, q, plan, now)
             pq = PendingQuery(qid, q.terms, q.rects, q.amps, plan)
             for raw in b.add(pq, now):
                 self._execute_open(
@@ -636,6 +649,14 @@ class GeoServer:
     # ------------------------------------------------------------------
     # telemetry helpers (each a no-op without the matching sink)
     # ------------------------------------------------------------------
+    def _stage(self, name: str, **args):
+        """The host stage ``name`` (``SpanRecorder.stage``) where the
+        telemetry has a tracer; else a no-op context."""
+        tel = self.telemetry
+        if tel and tel.tracer is not None:
+            return tel.tracer.stage(name, **args)
+        return _NO_STAGE
+
     def _count(self, name: str, amount: float = 1.0, **labels) -> None:
         tel = self.telemetry
         if tel and tel.metrics is not None:
@@ -843,12 +864,22 @@ class GeoServer:
     # ------------------------------------------------------------------
     def _finish_batch(self, raw: RawBatch, report: ServeReport):
         """Run the executor under the batch's plan; return host results."""
-        if raw.plan is not None:
-            res = self.executor.run(self._to_query_batch(raw), plan=raw.plan)
-        else:
-            res = self.executor.run(self._to_query_batch(raw))
-        ids = np.asarray(res.ids)
-        scores = np.asarray(res.scores)
+        with self._stage("geo.dispatch"):
+            batch = self._to_query_batch(raw)
+            if raw.plan is not None:
+                res = self.executor.run(batch, plan=raw.plan)
+            else:
+                res = self.executor.run(batch)
+        with self._stage("geo.result"):
+            ids = np.asarray(res.ids)
+            scores = np.asarray(res.scores)
+        with self._stage("geo.stats"):
+            self._read_stats(raw, res, report)
+        return ids, scores
+
+    def _read_stats(self, raw: RawBatch, res, report: ServeReport) -> None:
+        """Fold the batch's counters into the report, its per-plan and
+        routing summaries, the metrics and the planner audit."""
         report.n_batches += 1
         report.shapes_used.add(raw.shape)
         label = self._plan_label(raw)
@@ -895,7 +926,6 @@ class GeoServer:
                 tel.audit.join(
                     qid, {k: float(a[row]) for k, a in per_row.items()}
                 )
-        return ids, scores
 
     def _execute(
         self,
@@ -912,53 +942,56 @@ class GeoServer:
         bursts) the later batches' wait behind the earlier ones lands in
         queue-wait, not in their service time or Landlord cost.
         """
-        t_exec = time.perf_counter() - t0
-        ids, scores = self._finish_batch(raw, report)
-        t_done = time.perf_counter() - t0
-        # batch cost shared equally by its real queries (Landlord credit)
-        service = t_done - t_exec
-        cost = service / max(raw.n_real, 1)
-        report.batch_events.append(
-            BatchEvent(flush_t, t_exec, t_done, 0, raw.n_real)
-        )
         label = self._plan_label(raw)
-        self._batch_telemetry(raw, label, reason, flush_t, t_exec, t_done, 0)
-        for row, qid in enumerate(raw.qids):
-            key, t_arr, idx = self._inflight.pop(qid)
-            self._record(
-                report, t_done - t_arr, flush_t - t_arr, t_exec - flush_t, service,
-                t_arr=t_arr, qid=qid, idx=idx, kind="executed", label=label,
+        with self._stage("geo.batch", plan=label):
+            t_exec = time.perf_counter() - t0
+            ids, scores = self._finish_batch(raw, report)
+            t_done = time.perf_counter() - t0
+            # batch cost shared equally by its real queries (Landlord credit)
+            service = t_done - t_exec
+            cost = service / max(raw.n_real, 1)
+            report.batch_events.append(
+                BatchEvent(flush_t, t_exec, t_done, 0, raw.n_real)
             )
-            report._record_plan(label, t_done - t_arr)
-            need_value = (
-                report.results is not None
-                or self.cache is not None
-                or self._pending is not None
-            )
-            value = (
-                QueryResult(ids[row].copy(), scores[row].copy())
-                if need_value
-                else None
-            )
-            self._set_result(report, idx, value)
-            if self.cache is not None:
-                self._put_cache(key, value, cost, t_done)
-            if self._pending is not None:
-                entry = self._pending.resolve(key, qid)
-                if entry is not None:
-                    for t_sub, sub_idx in entry.subscribers:
-                        self._record(
-                            report,
-                            t_done - t_sub,
-                            flush_t - t_sub,
-                            t_exec - flush_t,
-                            service,
-                            t_arr=t_sub, idx=sub_idx, kind="coalesced",
-                            label=label,
-                        )
-                        report._record_plan(label, t_done - t_sub)
-                        self._set_result(report, sub_idx, value)
-                    entry.subscribers.clear()
+            self._batch_telemetry(raw, label, reason, flush_t, t_exec, t_done, 0)
+            with self._stage("geo.deliver"):
+                for row, qid in enumerate(raw.qids):
+                    key, t_arr, idx = self._inflight.pop(qid)
+                    self._record(
+                        report, t_done - t_arr, flush_t - t_arr,
+                        t_exec - flush_t, service, t_arr=t_arr, qid=qid,
+                        idx=idx, kind="executed", label=label,
+                    )
+                    report._record_plan(label, t_done - t_arr)
+                    need_value = (
+                        report.results is not None
+                        or self.cache is not None
+                        or self._pending is not None
+                    )
+                    value = (
+                        QueryResult(ids[row].copy(), scores[row].copy())
+                        if need_value
+                        else None
+                    )
+                    self._set_result(report, idx, value)
+                    if self.cache is not None:
+                        self._put_cache(key, value, cost, t_done)
+                    if self._pending is not None:
+                        entry = self._pending.resolve(key, qid)
+                        if entry is not None:
+                            for t_sub, sub_idx in entry.subscribers:
+                                self._record(
+                                    report,
+                                    t_done - t_sub,
+                                    flush_t - t_sub,
+                                    t_exec - flush_t,
+                                    service,
+                                    t_arr=t_sub, idx=sub_idx,
+                                    kind="coalesced", label=label,
+                                )
+                                report._record_plan(label, t_done - t_sub)
+                                self._set_result(report, sub_idx, value)
+                            entry.subscribers.clear()
 
     def _apply_fills(self, now: float) -> None:
         """Insert deferred results whose batch completed by virtual ``now``.
@@ -990,52 +1023,60 @@ class GeoServer:
         timeline; with one worker this is exactly the single busy-server
         recurrence of PR 2.
         """
-        t0 = time.perf_counter()
-        ids, scores = self._finish_batch(raw, report)
-        if service_time is not None:
-            dt = float(service_time(raw))
-        else:
-            dt = time.perf_counter() - t0
-        w = min(range(self.n_workers), key=lambda i: self._workers[i])
-        start = max(flush_t, self._workers[w])
-        done = start + dt
-        self._workers[w] = done
-        report.batch_events.append(BatchEvent(flush_t, start, done, w, raw.n_real))
-        cost = dt / max(raw.n_real, 1)
         label = self._plan_label(raw)
-        self._batch_telemetry(raw, label, reason, flush_t, start, done, w)
-        for row, qid in enumerate(raw.qids):
-            key, t_arr, idx = self._inflight.pop(qid)
-            self._record(
-                report, done - t_arr, flush_t - t_arr, start - flush_t, dt,
-                t_arr=t_arr, qid=qid, idx=idx, kind="executed", label=label,
+        with self._stage("geo.batch", plan=label):
+            t0 = time.perf_counter()
+            ids, scores = self._finish_batch(raw, report)
+            if service_time is not None:
+                dt = float(service_time(raw))
+            else:
+                dt = time.perf_counter() - t0
+            w = min(range(self.n_workers), key=lambda i: self._workers[i])
+            start = max(flush_t, self._workers[w])
+            done = start + dt
+            self._workers[w] = done
+            report.batch_events.append(
+                BatchEvent(flush_t, start, done, w, raw.n_real)
             )
-            report._record_plan(label, done - t_arr)
-            need_value = (
-                report.results is not None
-                or self.cache is not None
-                or self._pending is not None
-            )
-            value = (
-                QueryResult(ids[row].copy(), scores[row].copy())
-                if need_value
-                else None
-            )
-            self._set_result(report, idx, value)
-            if self.cache is not None:
-                heapq.heappush(
-                    self._pending_fills,
-                    (done, next(self._fill_seq), key, value, cost),
-                )
-            if self._pending is not None:
-                entry = self._pending.on_dispatch(
-                    key, qid, flush_t, start, done, value
-                )
-                if entry is not None:
-                    entry.plan_label = label
-                    # resolve duplicates that subscribed while this query
-                    # sat in its batcher bucket; later duplicates (arriving
-                    # before `done`) are recorded directly at lookup time
-                    for t_sub, sub_idx in entry.subscribers:
-                        self._record_coalesced(report, entry, t_sub, sub_idx)
-                    entry.subscribers.clear()
+            cost = dt / max(raw.n_real, 1)
+            self._batch_telemetry(raw, label, reason, flush_t, start, done, w)
+            with self._stage("geo.deliver"):
+                for row, qid in enumerate(raw.qids):
+                    key, t_arr, idx = self._inflight.pop(qid)
+                    self._record(
+                        report, done - t_arr, flush_t - t_arr, start - flush_t,
+                        dt, t_arr=t_arr, qid=qid, idx=idx, kind="executed",
+                        label=label,
+                    )
+                    report._record_plan(label, done - t_arr)
+                    need_value = (
+                        report.results is not None
+                        or self.cache is not None
+                        or self._pending is not None
+                    )
+                    value = (
+                        QueryResult(ids[row].copy(), scores[row].copy())
+                        if need_value
+                        else None
+                    )
+                    self._set_result(report, idx, value)
+                    if self.cache is not None:
+                        heapq.heappush(
+                            self._pending_fills,
+                            (done, next(self._fill_seq), key, value, cost),
+                        )
+                    if self._pending is not None:
+                        entry = self._pending.on_dispatch(
+                            key, qid, flush_t, start, done, value
+                        )
+                        if entry is not None:
+                            entry.plan_label = label
+                            # resolve duplicates that subscribed while this
+                            # query sat in its batcher bucket; later duplicates
+                            # (arriving before `done`) are recorded directly at
+                            # lookup time
+                            for t_sub, sub_idx in entry.subscribers:
+                                self._record_coalesced(
+                                    report, entry, t_sub, sub_idx
+                                )
+                            entry.subscribers.clear()

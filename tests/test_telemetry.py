@@ -20,6 +20,7 @@ internally consistent with that report:
 """
 import dataclasses
 import math
+import re
 
 import pytest
 
@@ -385,3 +386,216 @@ def test_event_log_and_audit_units(tmp_path):
     out = tmp_path / "audit.jsonl"
     audit.to_jsonl(str(out))
     assert len(out.read_text().splitlines()) == 1
+
+
+# ---------------------------------------------------------------------------
+# host stages (SpanRecorder.stage) and the program's named scopes
+# ---------------------------------------------------------------------------
+
+BATCH_STAGES = ["geo.dispatch", "geo.result", "geo.stats", "geo.deliver"]
+
+
+def _stage_spans(rec):
+    return [s for s in rec.exec_spans if s.track == "server"]
+
+
+def test_closed_loop_stages_nest_in_order():
+    """Every executed batch records ``geo.batch`` with its four children,
+    in order and inside it; every planned miss one ``geo.plan``; the report
+    sums them per run and the export stays valid."""
+    qs = [_pool_query(i, d=3, r=1) for i in range(6)]
+    trace = qs + [dataclasses.replace(qs[0])]
+    srv, tel = _tel_server(max_wait_s=float("inf"), max_batch=4,
+                           cache=LRUCache(16))
+    rep = srv.run_trace(trace, warmup=False)
+    spans = _stage_spans(tel.tracer)
+    batches = [i for i, s in enumerate(spans) if s.name == "geo.batch"]
+    assert len(batches) == rep.n_batches == 2
+    assert sum(s.name == "geo.plan" for s in spans) == rep.cache_misses == 6
+    for i in batches:
+        parent, kids = spans[i], spans[i + 1 : i + 5]
+        assert [k.name for k in kids] == BATCH_STAGES
+        assert parent.args == {"plan": "fixed"}  # RowExecutor: no plans
+        for a, b in zip(kids, kids[1:]):
+            assert a.t1 <= b.t0  # in order, not overlapping
+        assert parent.t0 <= kids[0].t0 and kids[-1].t1 <= parent.t1
+    for s in spans:
+        if s.name == "geo.plan":  # a plan stage never sits inside a batch
+            assert not any(
+                p.t0 <= s.t0 < p.t1 for p in spans if p.name == "geo.batch"
+            )
+    assert set(rep.stage_s) == {"geo.plan", "geo.batch", *BATCH_STAGES}
+    for name, total in rep.stage_s.items():
+        assert total == pytest.approx(
+            sum(s.t1 - s.t0 for s in spans if s.name == name)
+        )
+    trace_json = tel.tracer.to_trace_events()
+    assert validate_trace(trace_json) == []
+    assert {"geo.batch", "geo.plan", *BATCH_STAGES} <= {
+        e["name"] for e in trace_json["traceEvents"]
+    }
+
+
+def test_open_loop_stages_nest_and_export_validly():
+    trace = _random_trace(3, n=120, pool=12)
+    srv, tel = _tel_server(workers=2, coalesce=True, cache=LRUCache(8))
+    rep = srv.run_trace(trace, warmup=False, arrival="poisson",
+                        service_time=_service)
+    spans = _stage_spans(tel.tracer)
+    assert sum(s.name == "geo.batch" for s in spans) == rep.n_batches
+    assert validate_trace(tel.tracer.to_trace_events()) == []
+
+
+class _CountingAnnotation:
+    entered = 0
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        type(self).entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("with_tracer", [False, True])
+def test_profiler_annotations_only_with_a_tracer(monkeypatch, with_tracer):
+    import repro.obs.tracing as tracing
+
+    monkeypatch.setattr(_CountingAnnotation, "entered", 0)
+    monkeypatch.setattr(tracing, "TraceAnnotation", _CountingAnnotation)
+    qs = [_pool_query(i, d=2, r=1) for i in range(8)]
+    tel = Telemetry(metrics=MetricsRegistry(), tracer=None, audit=None,
+                    events=None)
+    if with_tracer:
+        tel = Telemetry()
+    srv = GeoServer(RowExecutor(), batcher=DeadlineBatcher(
+        max_batch=4, max_terms=8, max_rects=4, max_wait_s=float("inf")),
+        telemetry=tel)
+    rep = srv.run_trace(qs, warmup=False)
+    assert rep.n_batches == 2
+    if with_tracer:  # one plan stage per miss, five stages per batch
+        assert _CountingAnnotation.entered == 8 + 5 * 2
+    else:
+        assert _CountingAnnotation.entered == 0 and rep.stage_s == {}
+    # and with no telemetry at all
+    plain = GeoServer(RowExecutor(), batcher=DeadlineBatcher(
+        max_batch=4, max_terms=8, max_rects=4, max_wait_s=float("inf")))
+    before = _CountingAnnotation.entered
+    assert plain.run_trace(qs, warmup=False).stage_s == {}
+    assert _CountingAnnotation.entered == before
+
+
+def test_stages_are_marked_on_the_profiler_clock(tmp_path):
+    """The stages reach a profiler trace as host events, nested as on the
+    recorder's track."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    qs = [_pool_query(i, d=2, r=1) for i in range(4)]
+    srv, tel = _tel_server(max_wait_s=float("inf"), max_batch=4)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        srv.run_trace(qs, warmup=False)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"), recursive=True)
+    evs = sorted(
+        (int(ev.start_ns), -int(ev.duration_ns), ev.name)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("geo.")
+    )
+    names = [n for _, _, n in evs]
+    assert names.count("geo.plan") == 4
+    assert names[names.index("geo.batch"):][:5] == ["geo.batch", *BATCH_STAGES]
+    assert names == [s.name for s in _stage_spans(tel.tracer)]
+
+
+def _tiny_exact_engine():
+    from repro.core.algorithms import QueryBudgets
+    from repro.core.engine import GeoSearchEngine
+    from repro.corpus import make_corpus
+
+    c = make_corpus(n_docs=2048, n_terms=256, max_rects=4, doc_len=16, seed=3)
+    budgets = QueryBudgets(max_candidates=128, max_tiles=64, k_sweeps=4,
+                           sweep_budget=256, exact=True, prune=True)
+    return GeoSearchEngine.build(
+        c.doc_terms, c.doc_rects, c.doc_amps, 256, pagerank=c.pagerank,
+        grid=32, budgets=budgets, compress=True,
+    )
+
+
+def _program_text(engine, plan) -> str:
+    import jax.numpy as jnp
+
+    from repro.core.algorithms import QueryBatch
+
+    batch = QueryBatch(terms=jnp.full((4, 3), -1, jnp.int32),
+                       rects=jnp.zeros((4, 2, 4), jnp.float32),
+                       amps=jnp.zeros((4, 2), jnp.float32))
+    engine.__dict__.pop("_fn_cache", None)
+    fn = engine._compiled(plan, ())
+    return fn.lower(engine.index, batch).compile().as_text()
+
+
+SCOPES = {
+    "scan": {"scan.geo", "scan.mask", "scan.order", "scan.probe"},
+    "text_first": {"text_first.bounds", "text_first.walk", "text_first.select",
+                   "text_first.probe", "text_first.rank"},
+}
+# the outermost geo scope of an op's name path, through transform wrappers:
+# ``jit(geo_scan)/vmap(scan.probe)/while/body/add`` -> ``scan.probe``
+TOP_SCOPE = re.compile(r"(?:^|/)(?:\w+\()*((?:scan|text_first)\.[a-z]+)\b")
+
+
+def _top_scopes(text: str) -> list[str]:
+    out = []
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        m = TOP_SCOPE.search(op_name)
+        out.append(m.group(1) if m else "")
+    return out
+
+
+def _instructions(text: str) -> list[str]:
+    """The program's instructions, without metadata or the debug tables."""
+    return [
+        re.sub(r", metadata=\{[^}]*\}", "", line)
+        for line in text.splitlines()
+        if line.startswith(("  ", "%", "ENTRY", "}"))
+    ]
+
+
+@pytest.mark.parametrize("algorithm", ["scan", "text_first"])
+def test_compiled_programs_name_their_phases(algorithm):
+    """The plan's program runs as ``jit_geo_<plan>``, its ops carry every
+    phase's scope, and the scopes change no instruction."""
+    from repro.core.engine import program_name
+    from repro.core.planner import QueryPlan
+
+    eng = _tiny_exact_engine()
+    plan = QueryPlan(algorithm, eng.budgets)
+    text = _program_text(eng, plan)
+    assert re.search(rf"^HloModule jit_{program_name(plan)}\b", text, re.M)
+    assert program_name(plan) == "geo_" + plan.label.replace("+", "_")
+    scopes = _top_scopes(text)
+    assert set(scopes) - {""} == SCOPES[algorithm]
+    # scopes are metadata: the same instructions without them
+    import contextlib
+
+    import jax
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = _program_text(eng, plan)
+    assert set(_top_scopes(bare)) == {""}
+    assert _instructions(bare) == _instructions(text)
