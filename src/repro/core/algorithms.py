@@ -161,6 +161,12 @@ def _count_unique(ids: jax.Array, valid: jax.Array) -> jax.Array:
     return jnp.sum(((s != nxt) & (s != big)).astype(jnp.int32))
 
 
+def _probe_plane_rows(text: tidx.TextIndex) -> int:
+    """``doc_plane`` rows one ``probe_term`` key reads from a compressed
+    index: one a segment searched under the impact layout, else one."""
+    return text.max_term_segments if text.layout == "impact" else 1
+
+
 def _sorted_dedupe(ids: jax.Array, valid: jax.Array):
     """Sort ids (invalid → +inf sentinel) and mark the last element of each
     run — a fixed-shape dedupe.
@@ -303,6 +309,11 @@ def _text_first_pruned(
     mtb = text.max_term_blocks
     n_win = window_size(mtb)
     Cs = min(budgets.max_candidates, n_win * tidx.POSTING_BLOCK)
+    plane_rows_sel = (
+        Cs * (1 + query.terms.shape[1] * _probe_plane_rows(text))
+        if text.is_compressed
+        else 0
+    )
     # query-independent inputs, hoisted out of the per-query vmap: the
     # geo/pagerank remainder bounds (the block-major impact plane is
     # built with the index).
@@ -381,8 +392,7 @@ def _text_first_pruned(
             gb = jnp.clip(b0 + w_sel, 0, NB - 1)
             apos = jnp.clip(text.blk_pos[gb] + lane, 0, max(P - 1, 0))
             if text.is_compressed:
-                dec = tidx.decode_posting_blocks(text, gb)  # [Cs, 128]
-                cand = jnp.take_along_axis(dec, lane[:, None], axis=1)[:, 0]
+                cand = text.doc_plane[gb, lane]
             else:
                 cand = text.postings[apos]
             cand = jnp.where(ok_c, cand, jnp.int32(2**31 - 1))
@@ -445,6 +455,9 @@ def _text_first_pruned(
                 "bytes_random": n_c * jnp.float32(R * db)
                 + n_c * probes_per * 32
                 + n_sel * jnp.float32(pb),
+                # doc_plane rows read: the select's, then one a candidate,
+                # term slot and segment searched
+                "plane_rows": jnp.int32(plane_rows_sel),
             }
             return ids, vals, stats
 
@@ -914,6 +927,13 @@ def scan(
                 "n_probes": probes,
                 "bytes_seq": jnp.float32(N * R * db) + mask_bytes,
                 "bytes_random": probes * 32.0,
+                # doc_plane rows read: every term slot's mask scatter,
+                # then each round's probes of every term slot
+                "plane_rows": (
+                    d * mask_blocks + rounds * (d * C * _probe_plane_rows(text))
+                    if text.is_compressed
+                    else jnp.int32(0)
+                ),
             }
             return ids, vals, stats
 

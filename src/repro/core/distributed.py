@@ -76,6 +76,7 @@ from repro.core import ranking
 from repro.core.engine import GeoIndex
 from repro.core.spatial_index import SpatialIndex, build_spatial_index_np
 from repro.core.text_index import (
+    DOC_PLANE_PAD,
     TextIndex,
     build_text_index_np,
     global_idf_np as tidx_global_idf,
@@ -317,6 +318,7 @@ class ShardedGeoIndex:
     blk_pos: jax.Array  # i32[S, NBt]
     blk_max_impact: jax.Array  # f32[S, NBt] post-quantization block maxima
     imp_plane: jax.Array  # [S, NBt, 128] block-major impacts
+    doc_plane: jax.Array  # i32[S, NBp, 128] block-major doc ids ([S, 0, 128] raw)
     blk_term_off: jax.Array  # i32[S, M+1]
     # impact-ordered segment CSR (degenerate under layout="docid")
     seg_term_off: jax.Array  # i32[S, M+1]
@@ -473,6 +475,9 @@ def shard_corpus_np(
     stacked["imp_plane"] = np.stack(
         [padded(s[0].imp_plane, NBt_max, 0.0) for s in shards]
     )
+    stacked["doc_plane"] = np.stack(
+        [padded(s[0].doc_plane, NBp_max, DOC_PLANE_PAD) for s in shards]
+    )
     stacked["blk_term_off"] = np.stack(
         [np.asarray(s[0].blk_term_off) for s in shards]
     )
@@ -547,6 +552,7 @@ def shard_corpus_np(
         blk_pos=jnp.asarray(stacked["blk_pos"]),
         blk_max_impact=jnp.asarray(stacked["blk_max_impact"]),
         imp_plane=jnp.asarray(stacked["imp_plane"]),
+        doc_plane=jnp.asarray(stacked["doc_plane"]),
         blk_term_off=jnp.asarray(stacked["blk_term_off"]),
         seg_term_off=jnp.asarray(stacked["seg_term_off"]),
         seg_pos=jnp.asarray(stacked["seg_pos"]),
@@ -594,8 +600,8 @@ def sharded_index_specs(
         postings=lead, impacts=lead, offsets=lead,
         post_packed=lead, blk_first=lead, blk_bits=lead, blk_len=lead,
         blk_word_off=lead, blk_n_exc=lead, blk_pos=lead, blk_max_impact=lead,
-        imp_plane=lead, blk_term_off=lead, seg_term_off=lead, seg_pos=lead,
-        seg_len=lead,
+        imp_plane=lead, doc_plane=lead, blk_term_off=lead, seg_term_off=lead,
+        seg_pos=lead, seg_len=lead,
         tp_rects=lead, tp_amps=lead, tp_doc_ids=lead, tp_amp_scale=lead,
         tp_planes=lead, tile_starts=lead, tile_ends=lead,
         doc_rects=lead, doc_amps=lead, doc_mbr=lead, doc_mass=lead,
@@ -680,6 +686,7 @@ def make_serve_fn(
             blk_pos=idx.blk_pos[0],
             blk_max_impact=idx.blk_max_impact[0],
             imp_plane=idx.imp_plane[0],
+            doc_plane=idx.doc_plane[0],
             blk_term_off=idx.blk_term_off[0],
             seg_term_off=idx.seg_term_off[0], seg_pos=idx.seg_pos[0],
             seg_len=idx.seg_len[0],

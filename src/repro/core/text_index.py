@@ -43,9 +43,10 @@ minimizing total words: ``ceil(len·bits/32)`` base words (tail-trimmed)
 plus one patch word per delta that does not fit.  A patch word packs
 ``slot | high_bits << 8`` — the slot index (< 128, 8 bits) and the bits
 above the base width (≤ 24, enforced by ``bits ≥ bit_length(max) − 24``).
-Decode extracts the base bits as before, then replays the patch list
-(:func:`decode_posting_blocks`).  In practice the chosen base width covers
-~90% of deltas and the outliers ride in the exception list.
+Decoding extracts the base bits, then replays the patch list; the
+query path reads the decoded ids from ``doc_plane`` instead.  In practice
+the chosen base width covers ~90% of deltas and the outliers ride in the
+exception list.
 
 Posting layouts (``build_text_index_np(layout=)``)
 --------------------------------------------------
@@ -71,14 +72,18 @@ plus the block-max metadata ``blk_max_impact f32[NB]`` are built in BOTH
 storage modes: they are the skip unit of the WAND-style pruned traversal
 (kernels/text_probe), which is independent of how doc ids are stored.
 
-Query-time probes binary-search the block heads (``blk_first``) and decode
-exactly one block per key (shift/mask + prefix sum + exception patch) —
-the compressed words are the only doc-id bytes the query path touches, so
-the modeled ``posting_bytes`` (see the property) is what actually streams.
+Query-time probes binary-search the block heads (``blk_first``) and read
+exactly one block per key as one row of the resident block-major doc-id
+plane ``doc_plane i32[NB, POSTING_BLOCK]`` (:func:`decode_posting_blocks`),
+built from the CSR doc ids with the index — the doc-id twin of
+``imp_plane``.  A block is one 512-byte row gather there, where decoding
+it from the packed words takes two element gathers per slot.  The packed
+words stay the store of record and ``posting_bytes`` models them.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
@@ -93,6 +98,8 @@ WORDS_PER_BLOCK = BLOCK // 32
 POSTING_BLOCK = 128  # postings per delta/bit-pack compression block
 # bytes per posting in the pruned probe's f32 impact plane (imp_plane)
 PLANE_BYTES = 4
+# doc_plane's value past a block's length: no doc id equals it
+DOC_PLANE_PAD = -1
 # PForDelta patch word: slot (8 bits, block slots < 128) | high_bits << 8
 PFOR_SLOT_BITS = 8
 PFOR_HIGH_BITS = 32 - PFOR_SLOT_BITS
@@ -139,6 +146,10 @@ class TextIndex:
     # blk_len: the rows the pruned TEXT-FIRST probe copies block by block
     # (impact_plane_np)
     imp_plane: jax.Array  # f32[NB, POSTING_BLOCK]
+    # block-major copy of the doc ids, DOC_PLANE_PAD past blk_len: the rows
+    # every compressed-store reader gathers (doc_plane; [0, POSTING_BLOCK]
+    # when uncompressed — the raw postings are read directly)
+    doc_plane: jax.Array  # i32[NB, POSTING_BLOCK]
     # impact-ordered segment CSR (degenerate under layout="docid": the
     # probes never read it, so it stays one zero entry)
     seg_term_off: jax.Array  # i32[M+1] CSR of impact segments per term
@@ -261,6 +272,50 @@ def impact_plane_np(
     if v.shape[0]:
         with ThreadPoolExecutor(HOST_THREADS) as pool:
             list(pool.map(rows, range(0, NB, _PACK_CHUNK)))
+    return plane
+
+
+_PLANE_CHUNK = 1 << 13  # doc_plane rows per upload (4 MiB of i32)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_rows(plane, rows, b0):
+    return jax.lax.dynamic_update_slice(plane, rows, (b0, jnp.int32(0)))
+
+
+def doc_plane(
+    postings: np.ndarray, blk_pos: np.ndarray, blk_len: np.ndarray
+) -> jax.Array:
+    """Block-major doc-id plane — i32 ``[NB, POSTING_BLOCK]`` on the default
+    device.
+
+    Row b holds block b's doc ids (``postings[blk_pos[b] : +blk_len[b]]``)
+    and :data:`DOC_PLANE_PAD` past ``blk_len``.  Rows are filled on the
+    host in chunks and written into the device array in place, so a large
+    plane never exists twice on the host (the plane of one chip's geoweb
+    shard is 2.6 GB).
+    """
+    NB = blk_pos.shape[0]
+    j = np.arange(POSTING_BLOCK, dtype=np.int64)
+
+    def rows(b0):
+        b1 = min(b0 + _PLANE_CHUNK, NB)
+        idx = blk_pos[b0:b1, None].astype(np.int64) + j[None, :]
+        live = j[None, :] < blk_len[b0:b1, None]
+        got = postings[np.where(live, idx, 0)] if postings.shape[0] else 0
+        return np.where(live, got, DOC_PLANE_PAD).astype(np.int32)
+
+    if NB <= _PLANE_CHUNK:
+        return jnp.asarray(rows(0))
+    # fixed-size chunks (one compile); the last one ends at NB, rewriting
+    # rows of its neighbour with the same values
+    starts = [min(b0, NB - _PLANE_CHUNK) for b0 in range(0, NB, _PLANE_CHUNK)]
+    plane = jnp.full((NB, POSTING_BLOCK), DOC_PLANE_PAD, jnp.int32)
+    with ThreadPoolExecutor(HOST_THREADS) as pool:
+        for w in range(0, len(starts), HOST_THREADS):  # bounded look-ahead
+            wave = starts[w : w + HOST_THREADS]
+            for b0, r in zip(wave, pool.map(rows, wave)):
+                plane = _put_rows(plane, r, jnp.int32(b0))
     return plane
 
 
@@ -716,9 +771,11 @@ def build_text_index_np(
         frame_off = offsets
     if compress:
         pack = pack_postings_np(postings, frame_off, impacts=impacts)
+        plane = doc_plane(postings, pack["blk_pos"], pack["blk_len"])
         postings = np.zeros((0,), np.int32)  # packed words are the store
     else:
         pack = _empty_pack(frame_off)
+        plane = jnp.zeros((0, POSTING_BLOCK), jnp.int32)
         pack["blk_max_impact"] = block_max_impacts_np(
             impacts, pack["blk_pos"], pack["blk_len"]
         )
@@ -741,6 +798,7 @@ def build_text_index_np(
         bitmap_term_ids=jnp.asarray(top_terms),
         **{k: jnp.asarray(v) for k, v in pack.items()},
         **{k: jnp.asarray(v) for k, v in seg.items()},
+        doc_plane=plane,
         n_docs=n_docs,
         n_terms=n_terms,
         max_term_blocks=int(max(term_blocks.max(initial=0), 1)),
@@ -816,51 +874,12 @@ def term_slice(index: TextIndex, term: jax.Array) -> tuple[jax.Array, jax.Array]
 
 
 def decode_posting_blocks(index: TextIndex, blocks: jax.Array) -> jax.Array:
-    """Decode compressed blocks to doc ids — i32[..., POSTING_BLOCK].
+    """Doc ids of compressed blocks — i32[..., POSTING_BLOCK].
 
-    Pure shift/mask extraction of each block's 128 base-width deltas from
-    the packed words, then a replay of the block's PForDelta patch list
-    (each patch word restores one delta's high bits), then a prefix sum
-    from ``blk_first``.  Slots past ``blk_len`` are garbage — blocks are
-    stored tail-trimmed, so those reads fall into the exception words or
-    the next block; mask with ``blk_len`` before trusting membership.
+    One row of ``doc_plane`` per block.  Slots past ``blk_len`` hold
+    :data:`DOC_PLANE_PAD`; readers mask them with ``blk_len``.
     """
-    bits = index.blk_bits[blocks]  # [...]
-    w0 = index.blk_word_off[blocks]
-    j = jnp.arange(POSTING_BLOCK, dtype=jnp.int32)
-    bitpos = j * bits[..., None]  # [..., 128]
-    word = w0[..., None] + (bitpos >> 5)
-    off = (bitpos & 31).astype(jnp.uint32)
-    W = max(index.post_packed.shape[0], 1)
-    lo_w = index.post_packed[jnp.clip(word, 0, W - 1)]
-    hi_w = index.post_packed[jnp.clip(word + 1, 0, W - 1)]
-    # two-word extraction; the hi shift amount stays < 32 via the mask and
-    # the off == 0 case (where 32 - off would be 32) selects 0 anyway
-    hi_part = jnp.where(
-        off > 0, hi_w << ((jnp.uint32(32) - off) & jnp.uint32(31)), jnp.uint32(0)
-    )
-    mask = (jnp.uint32(1) << bits[..., None].astype(jnp.uint32)) - 1  # bits ≤ 31
-    delta = (((lo_w >> off) | hi_part) & mask).astype(jnp.int32)
-    delta = jnp.where(j == 0, 0, delta)
-    # PForDelta patch replay: exception words live right after the block's
-    # tail-trimmed base words; each restores one slot's high bits.  The
-    # loop bound is the batch-wide max patch count (traced — fori_loop
-    # lowers to a while_loop), so exception-free batches decode as before.
-    n_exc = index.blk_n_exc[blocks]  # [...]
-    base_words = jnp.maximum(
-        (index.blk_len[blocks] * bits + 31) >> 5, 1
-    )
-    ew0 = w0 + base_words
-
-    def _patch(e, d):
-        pw = index.post_packed[jnp.clip(ew0 + e, 0, W - 1)]  # [...]
-        slot = (pw & jnp.uint32((1 << PFOR_SLOT_BITS) - 1)).astype(jnp.int32)
-        high = (pw >> jnp.uint32(PFOR_SLOT_BITS)).astype(jnp.int32)
-        add = jnp.where(e < n_exc, high << bits, 0)  # [...]
-        return d + jnp.where(j == slot[..., None], add[..., None], 0)
-
-    delta = jax.lax.fori_loop(0, jnp.max(n_exc), _patch, delta)
-    return index.blk_first[blocks][..., None] + jnp.cumsum(delta, axis=-1)
+    return index.doc_plane[blocks]
 
 
 def _probe_term_packed(

@@ -73,6 +73,14 @@ def _impact_plane_ref(impacts, blk_pos, blk_len):
     return plane
 
 
+def _doc_plane_ref(postings, blk_pos, blk_len):
+    """Block b's doc ids in row b, DOC_PLANE_PAD past its length."""
+    plane = np.full((blk_pos.shape[0], POSTING_BLOCK), tidx.DOC_PLANE_PAD, np.int32)
+    for b, (p, n) in enumerate(zip(blk_pos, blk_len)):
+        plane[b, :n] = postings[p : p + n]
+    return plane
+
+
 def _pfor_width_ref(real_deltas: np.ndarray) -> tuple[int, int]:
     """Pick a block's PForDelta base width — ``(bits, n_exc)``.
 
@@ -354,9 +362,11 @@ def _build_text_index_ref(
         frame_off = offsets
     if compress:
         pack = _pack_postings_ref(postings, frame_off, impacts=impacts)
+        pack["doc_plane"] = _doc_plane_ref(postings, pack["blk_pos"], pack["blk_len"])
         postings = np.zeros((0,), np.int32)  # packed words are the store
     else:
         pack = _empty_pack(frame_off)
+        pack["doc_plane"] = np.zeros((0, POSTING_BLOCK), np.int32)
         pack["blk_max_impact"] = _block_max_ref(
             impacts, pack["blk_pos"], pack["blk_len"]
         )
@@ -614,6 +624,24 @@ def test_pack_postings_matches_loop(seed):
     for k in want:
         assert got[k].dtype == want[k].dtype, k
         np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+@pytest.mark.parametrize("chunk", [4, 7, 1 << 13])
+def test_doc_plane_matches_loop(monkeypatch, chunk):
+    """The plane assembled in fixed-size chunks (the last one overlapping
+    its neighbour) equals the per-block loop, ragged and empty blocks
+    included."""
+    monkeypatch.setattr(tidx, "_PLANE_CHUNK", chunk)
+    rng = np.random.default_rng(9)
+    counts = rng.integers(0, 300, 11)
+    counts[3] = 0
+    offsets = np.zeros(12, np.int64)
+    offsets[1:] = np.cumsum(counts)
+    postings = rng.integers(0, 1 << 22, int(offsets[-1])).astype(np.int32)
+    _, blk_pos, blk_len = tidx.logical_posting_blocks_np(offsets)
+    got = np.asarray(tidx.doc_plane(postings, blk_pos, blk_len))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, _doc_plane_ref(postings, blk_pos, blk_len))
 
 
 @pytest.mark.parametrize("m_intervals", [1, 2, 3, 5])

@@ -14,12 +14,13 @@ from repro.core.spatial_index import (
     quantize_amps_np,
 )
 from repro.core.text_index import (
+    DOC_PLANE_PAD,
     POSTING_BLOCK,
     build_text_index_np,
     decode_posting_blocks,
     probe_term,
 )
-from repro.corpus import make_corpus, make_zipf_trace, pad_trace_batch
+from repro.corpus import make_corpus, make_query_trace, make_zipf_trace, pad_trace_batch
 from repro.kernels.sweep_score.ops import sweep_score, sweep_score_pruned
 from repro.kernels.sweep_score.ref import sweep_score_pruned_ref, sweep_score_ref
 
@@ -97,6 +98,72 @@ def test_probe_term_matches_uncompressed():
         m_r, i_r = probe_term(raw, jnp.int32(t), doc_ids)
         np.testing.assert_array_equal(np.asarray(m_c), np.asarray(m_r))
         np.testing.assert_array_equal(np.asarray(i_c), np.asarray(i_r))
+
+
+@pytest.mark.parametrize("layout", ["docid", "impact"])
+def test_doc_plane_matches_csr_and_packed_store(bit_extract_decode, layout):
+    """Every doc_plane row holds its block's CSR doc ids below blk_len and
+    the pad past it, and agrees on every block with a bit-extracting
+    decode of the packed words, PForDelta exception blocks included."""
+    corpus = make_corpus(n_docs=2000, n_terms=150, seed=3)
+    comp = build_text_index_np(
+        corpus.doc_terms, corpus.n_terms, compress=True, layout=layout
+    )
+    raw = build_text_index_np(
+        corpus.doc_terms, corpus.n_terms, compress=False, layout=layout
+    )
+    assert int(np.asarray(comp.blk_n_exc).sum()) > 0  # exception blocks
+    plane = np.asarray(comp.doc_plane)
+    NB = comp.blk_first.shape[0]
+    assert plane.shape == (NB, POSTING_BLOCK) and plane.dtype == np.int32
+    assert raw.doc_plane.shape == (0, POSTING_BLOCK)  # raw ids are the store
+    blk_pos, blk_len = np.asarray(comp.blk_pos), np.asarray(comp.blk_len)
+    live = np.arange(POSTING_BLOCK)[None, :] < blk_len[:, None]
+    csr = np.asarray(raw.postings)[
+        np.where(live, blk_pos[:, None] + np.arange(POSTING_BLOCK), 0)
+    ]
+    np.testing.assert_array_equal(np.where(live, plane, 0), np.where(live, csr, 0))
+    assert (plane[~live] == DOC_PLANE_PAD).all()
+    packed = np.asarray(bit_extract_decode(comp, jnp.arange(NB, dtype=jnp.int32)))
+    np.testing.assert_array_equal(np.where(live, packed, 0), np.where(live, plane, 0))
+
+
+@pytest.mark.parametrize("compress", ["none", "f16"])
+@pytest.mark.parametrize("layout", ["docid", "impact"])
+def test_plane_rows_counts_doc_plane_reads(compress, layout):
+    """``plane_rows`` counts the doc_plane rows a batch row reads: the
+    scan's mask scatters and probe rounds, pruned TEXT-FIRST's select and
+    probes; 0 where the raw postings are the store."""
+    from repro.core import algorithms as alg
+    from repro.kernels.text_probe.ops import window_size
+
+    corpus = make_corpus(n_docs=1200, n_terms=150, seed=11)
+    mc = 16
+    eng = GeoSearchEngine.build(
+        corpus.doc_terms, corpus.doc_rects, corpus.doc_amps, corpus.n_terms,
+        pagerank=corpus.pagerank, grid=32, compress=compress, layout=layout,
+        budgets=QueryBudgets(max_candidates=mc, top_k=10, prune=True),
+    )
+    trace = make_query_trace(corpus, n_queries=24, seed=12)
+    text = eng.index.text
+    scan = eng.query(trace, "scan")
+    tf = eng.query(trace, "text_first")
+    scan_rows = np.asarray(scan.stats["plane_rows"])
+    tf_rows = np.asarray(tf.stats["plane_rows"])
+    assert scan_rows.shape == tf_rows.shape == (24,)
+    if compress == "none":
+        assert (scan_rows == 0).all() and (tf_rows == 0).all()
+        return
+    d = trace.terms.shape[1]
+    segs = text.max_term_segments if layout == "impact" else 1
+    mask_blocks = min(alg.SCAN_MASK_BLOCKS, text.max_term_blocks)
+    rounds = np.asarray(scan.stats["scan_rounds"])
+    assert rounds.max() > 0
+    np.testing.assert_array_equal(
+        scan_rows, d * mask_blocks + rounds * d * mc * segs
+    )
+    Cs = min(mc, window_size(text.max_term_blocks) * POSTING_BLOCK)
+    assert (tf_rows == Cs * (1 + d * segs)).all() and (tf_rows > 0).all()
 
 
 # ---------------------------------------------------------------------------

@@ -187,3 +187,61 @@ def test_mesh_executor_serving_stack():
     assert r["recall"] >= 0.99
     assert r["served"] == 64
     assert r["hit_rate"] >= 0.30
+
+
+def test_mesh_executor_stacks_doc_plane():
+    """A compressed MeshExecutor stacks each shard's doc-id plane: shape
+    [S, NBp, 128], each shard's live slots are exactly its documents of
+    each term, every other slot (past blk_len, padded blocks) is the pad,
+    and the mesh's answers still match the exact oracle."""
+    r = run_in_subprocess(textwrap.dedent("""
+        import json, numpy as np
+        from repro.launch.mesh import make_host_mesh
+        from repro.corpus import make_corpus, make_query_trace
+        from repro.core import GeoSearchEngine, QueryBudgets
+        from repro.core.distributed import MortonPartitioner
+        from repro.core.text_index import DOC_PLANE_PAD, POSTING_BLOCK
+        from repro.serving import MeshExecutor
+
+        corpus = make_corpus(n_docs=512, n_terms=100, seed=0)
+        budgets = QueryBudgets(max_candidates=1024, max_tiles=2048, k_sweeps=8,
+                               sweep_budget=1024, top_k=10)
+        mesh = make_host_mesh((4, 1), ("data", "model"))
+        mx = MeshExecutor.build(
+            corpus.doc_terms, corpus.doc_rects, corpus.doc_amps,
+            corpus.n_terms, pagerank=corpus.pagerank, mesh=mesh,
+            partitioner=MortonPartitioner(), grid=32, budgets=budgets,
+            compress="f16")
+        idx = mx._index
+        plane = np.asarray(idx.doc_plane)
+        blk_len = np.asarray(idx.blk_len)
+        bto = np.asarray(idx.blk_term_off)
+        gid = np.asarray(idx.doc_offset)
+        S, NBp = idx.blk_first.shape
+        live = np.arange(POSTING_BLOCK)[None, None, :] < blk_len[:, :NBp, None]
+        ok = bool((plane[~live] == DOC_PLANE_PAD).all())
+        for s in range(S):
+            for t in range(corpus.n_terms):
+                rows = plane[s, bto[s, t]:bto[s, t + 1]]
+                lv = live[s, bto[s, t]:bto[s, t + 1]]
+                got = np.sort(gid[s][rows[lv]])
+                want = [g for g in range(len(corpus.doc_terms))
+                        if t in corpus.doc_terms[g] and g in set(gid[s])]
+                ok = ok and np.array_equal(got, np.asarray(want))
+        eng = GeoSearchEngine.build(
+            corpus.doc_terms, corpus.doc_rects, corpus.doc_amps,
+            corpus.n_terms, pagerank=corpus.pagerank, grid=32,
+            budgets=budgets, compress="f16")
+        q = make_query_trace(corpus, n_queries=16, seed=1)
+        g, w = np.asarray(mx.run(q).ids), np.asarray(eng.oracle(q).ids)
+        hits = sum(len(set(w[b][w[b]>=0]) & set(g[b][g[b]>=0]))
+                   for b in range(16))
+        tot = int(sum((w[b]>=0).sum() for b in range(16)))
+        print(json.dumps({"shape": list(plane.shape), "dtype": str(plane.dtype),
+                          "nbp": int(NBp), "S": int(S), "rows_ok": ok,
+                          "recall": hits / max(tot, 1)}))
+    """))
+    assert r["shape"] == [r["S"], r["nbp"], 128] and r["S"] == 4
+    assert r["dtype"] == "int32" and r["nbp"] > 0
+    assert r["rows_ok"]
+    assert r["recall"] >= 0.99

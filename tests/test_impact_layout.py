@@ -14,6 +14,7 @@ from repro.core.text_index import (
     POSTING_BLOCK,
     build_text_index_np,
     decode_posting_blocks,
+    doc_plane,
     impact_levels_np,
     pack_postings_np,
 )
@@ -202,7 +203,8 @@ def test_impact_layout_pays_segment_bytes(zipf_corpus_and_batch):
 
 def _pack(plists):
     """Pack a list of per-term sorted posting arrays; return a decode
-    handle (`decode_posting_blocks` only touches the packed columns)."""
+    handle: the packed columns plus the doc-id plane
+    (`decode_posting_blocks` reads only ``doc_plane``)."""
     offsets = np.zeros((len(plists) + 1,), np.int64)
     offsets[1:] = np.cumsum([len(p) for p in plists])
     postings = (
@@ -211,29 +213,37 @@ def _pack(plists):
         else np.zeros((0,), np.int64)
     )
     cols = pack_postings_np(postings, offsets)
+    cols["doc_plane"] = doc_plane(postings, cols["blk_pos"], cols["blk_len"])
     return SimpleNamespace(**{k: jnp.asarray(v) for k, v in cols.items()})
 
 
-def _decode_term(idx, t):
+def _decode_term(idx, t, decode=decode_posting_blocks):
     bto = np.asarray(idx.blk_term_off)
     blk_len = np.asarray(idx.blk_len)
     ids = [
-        np.asarray(decode_posting_blocks(idx, jnp.int32(b)))[: int(blk_len[b])]
+        np.asarray(decode(idx, jnp.int32(b)))[: int(blk_len[b])]
         for b in range(int(bto[t]), int(bto[t + 1]))
     ]
     return np.concatenate(ids) if ids else np.zeros((0,), np.int64)
 
 
-def test_pfor_zero_exception_block():
+def _assert_decodes(idx, t, want, bit_extract_decode):
+    """Term ``t`` reads back as ``want`` from the doc-id plane and from a
+    bit-extracting decode of the packed words."""
+    np.testing.assert_array_equal(_decode_term(idx, t), want)
+    np.testing.assert_array_equal(_decode_term(idx, t, bit_extract_decode), want)
+
+
+def test_pfor_zero_exception_block(bit_extract_decode):
     """Uniform small deltas: the width argmin lands on the plain framing
     (no exception words) and decodes exactly."""
     plist = np.arange(0, 2 * POSTING_BLOCK * 3, 3, dtype=np.int64)
     idx = _pack([plist])
     assert int(np.asarray(idx.blk_n_exc).sum()) == 0
-    np.testing.assert_array_equal(_decode_term(idx, 0), plist)
+    _assert_decodes(idx, 0, plist, bit_extract_decode)
 
 
-def test_pfor_exception_heavy_block():
+def test_pfor_exception_heavy_block(bit_extract_decode):
     """Half tiny deltas, half huge: patching the outliers (one exception
     word each) beats widening the whole block, so the argmin framing
     carries many exceptions — and still decodes exactly."""
@@ -248,10 +258,10 @@ def test_pfor_exception_heavy_block():
     words_noexc = -(-POSTING_BLOCK * 21 // 32)
     bits = int(np.asarray(idx.blk_bits)[0])
     assert -(-POSTING_BLOCK * bits // 32) + n_exc < words_noexc
-    np.testing.assert_array_equal(_decode_term(idx, 0), plist)
+    _assert_decodes(idx, 0, plist, bit_extract_decode)
 
 
-def test_pfor_single_posting_and_max_gap():
+def test_pfor_single_posting_and_max_gap(bit_extract_decode):
     """A single-posting term, and terms whose one delta is a maximal
     doc-id gap — wider than PFOR_HIGH_BITS, so the base width's floor
     (bits ≥ bit_length − PFOR_HIGH_BITS) must keep every exception's
@@ -268,10 +278,10 @@ def test_pfor_single_posting_and_max_gap():
     ]
     idx = _pack(plists)
     for t, want in enumerate(plists):
-        np.testing.assert_array_equal(_decode_term(idx, t), want)
+        _assert_decodes(idx, t, want, bit_extract_decode)
 
 
-def test_pfor_ragged_tail_block():
+def test_pfor_ragged_tail_block(bit_extract_decode):
     """A list whose last block is part-full: tail-trimmed base words plus
     exceptions decode exactly, and padding lanes never leak."""
     rng = np.random.default_rng(41)
@@ -281,10 +291,10 @@ def test_pfor_ragged_tail_block():
     plist = np.cumsum(deltas) - 1
     idx = _pack([plist])
     assert int(np.asarray(idx.blk_n_exc).sum()) >= 1
-    np.testing.assert_array_equal(_decode_term(idx, 0), plist)
+    _assert_decodes(idx, 0, plist, bit_extract_decode)
 
 
-def test_pfor_roundtrip_random_impact_layout():
+def test_pfor_roundtrip_random_impact_layout(bit_extract_decode):
     """Random corpus under layout="impact": segment-local delta streams
     (docIDs restart ascending at each segment) round-trip exactly."""
     corpus = make_corpus(n_docs=700, n_terms=90, seed=42)
@@ -296,7 +306,6 @@ def test_pfor_roundtrip_random_impact_layout():
     )
     offs = np.asarray(raw.offsets)
     for t in range(corpus.n_terms):
-        np.testing.assert_array_equal(
-            _decode_term(comp, t),
-            np.asarray(raw.postings)[offs[t] : offs[t + 1]],
-        )
+        want = np.asarray(raw.postings)[offs[t] : offs[t + 1]]
+        _assert_decodes(comp, t, want, bit_extract_decode)
+
