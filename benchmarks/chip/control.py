@@ -49,7 +49,7 @@ def control_answers(queries, ref_low, d_terms: int, q_rects: int, batch: int = 8
     return [got[id(q)] for q in queries]
 
 
-def readings(cell, seed: int, n_queries: int, device=None) -> dict:
+def readings(cell, seed: int, n_queries: int, devices=None) -> dict:
     """The comparison's numbers for the control on one seed."""
     import jax.numpy as jnp
 
@@ -62,10 +62,10 @@ def readings(cell, seed: int, n_queries: int, device=None) -> dict:
     start = config["warm_chunks"] * mix["chunk"]
     queries = stream[start : start + n_queries]
     b = config["batcher"]
-    low = Reference(corpus, config, dtype=jnp.bfloat16, device=device)
+    low = Reference(corpus, config, dtype=jnp.bfloat16, devices=devices)
     answers = control_answers(queries, low, b["terms"], b["rects"])
     del low
-    ref = Reference(corpus, config, device=device)
+    ref = Reference(corpus, config, devices=devices)
     return check.compare(queries, answers, ref, b["terms"], b["rects"])["numbers"]
 
 
@@ -79,11 +79,11 @@ def main(argv=None) -> int:
     from benchmarks.chip import harness
 
     cell = harness.load_cell(ROOT, args.workload)
-    dev = harness.chips(cell.chips)[0]
+    devs = harness.chips(cell.chips)[: cell.chips]
     harness.enable_compile_cache(ROOT)
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        nums = readings(cell, seed, args.queries, device=dev)
+        nums = readings(cell, seed, args.queries, devices=devs)
         limits = cell.config["check"]["limits"]
         failed = [k for k in nums if nums[k] > limits[k]]
         print(json.dumps({"seed": seed, "numbers": nums, "fails": failed,

@@ -1,10 +1,12 @@
 """One run of one cell: set-up, the measured window, the check, one line.
 
 A cell is a ``workloads`` entry of ``BENCHMARK.json``: it names a
-configuration (``configs/<file>.json``, the deployment) and a traffic mix
-(``traffic/<name>.json``).  Each metric is computed by its own reader,
-``metrics/<name>.py``.  Everything is found by name, so a new cell, mix
-or metric is a new file and an entry in ``BENCHMARK.json``.
+configuration (``configs/<file>.json``, the deployment and its executor
+layout) and a traffic mix (``traffic/<name>.json``, whose generator is
+``generators/<generator>.py``).  Each metric is computed by its own
+reader, ``metrics/<name>.py``.  Everything is found by name, so a new
+cell, mix, generator, layout or metric is a new file and an entry in
+``BENCHMARK.json``.
 
 The window drives the program's ``GeoServer.run_trace`` in closed-loop
 mode on the wall clock, one chunk of the stream after another, until
@@ -140,30 +142,73 @@ class CompileCounter:
 # ----------------------------------------------------------------------
 # the system under test
 # ----------------------------------------------------------------------
-def build_server(config: dict, corpus, device, telemetry=None):
-    """The configured deployment: executor (index on ``device``), result
-    cache, batcher and ``GeoServer``, built with the program's own
-    builders."""
+def build_executor(config: dict, corpus, devices):
+    """The configuration's executor, with its index on ``devices`` (the
+    cell's chips), built by the program's ``make_executor``.
+
+    The configuration's ``executor`` block gives the layout: ``kind``
+    (``single`` or ``mesh``), and for a mesh ``routing`` and
+    ``partitioner`` (by name).  A ``single`` index sits
+    on the first chip; a ``mesh`` spans the cell's chips, one doc shard a
+    chip, each placed by the program's own sharding specs.
+    """
     import jax
 
     from repro.core.algorithms import QueryBudgets
+    from repro.core.distributed import resolve_partitioner
     from repro.core.ranking import RankWeights
-    from repro.serving import DeadlineBatcher, GeoServer, make_cache
     from repro.serving.factory import make_executor
 
+    layout = dict(config["executor"])
+    kind = layout.pop("kind")
+    if kind not in ("single", "mesh"):
+        raise ValueError(f"the benchmark places no {kind!r} executor on chips")
+    if "partitioner" in layout:
+        layout["partitioner"] = resolve_partitioner(layout["partitioner"])
+    if kind == "mesh":
+        from repro.launch.mesh import make_host_mesh
+
+        if len(jax.devices()) != len(devices):
+            raise ValueError(f"a mesh cell of {len(devices)} chips needs a host "
+                             f"of as many, JAX found {len(jax.devices())}")
+        layout["mesh"] = make_host_mesh((len(devices), 1), ("data", "model"))
     try:
         host = jax.devices("cpu")[0]
     except RuntimeError:  # no CPU backend: arrays land on the chip directly
-        host = device
+        host = devices[0]
     with jax.default_device(host):
         ex = make_executor(
-            "single", corpus, algorithm=config["algorithm"],
+            kind, corpus, algorithm=config["algorithm"],
             budgets=QueryBudgets(**config["budgets"]),
             weights=RankWeights(**config["weights"]), grid=config["grid"],
             m_intervals=config["m_intervals"], fused=config["fused"],
-            compress=config["compress"],
+            compress=config["compress"], **layout,
         )
-    ex.engine.index = jax.block_until_ready(jax.device_put(ex.engine.index, device))
+    if kind == "single":
+        ex.engine.index = jax.block_until_ready(
+            jax.device_put(ex.engine.index, devices[0]))
+    # a mesh's build placed each shard by its spec, on the mesh's devices
+    for x in index_leaves(ex):
+        if not x.devices() <= set(devices):
+            raise RuntimeError(f"index array on {x.devices()}, not on the cell's chips")
+    return ex
+
+
+def index_leaves(ex) -> list:
+    """The arrays of the executor's index, whatever executor holds it."""
+    import jax
+
+    tree = ex.engine.index if hasattr(ex, "engine") else ex._index  # single, mesh
+    return [x for x in jax.tree.leaves(tree) if isinstance(x, jax.Array)]
+
+
+def build_server(config: dict, corpus, devices, telemetry=None):
+    """The configured deployment: executor (index on ``devices``), result
+    cache, batcher and ``GeoServer``, built with the program's own
+    builders."""
+    from repro.serving import DeadlineBatcher, GeoServer, make_cache
+
+    ex = build_executor(config, corpus, devices)
     b = config["batcher"]
     batcher = DeadlineBatcher(
         max_batch=b["batch"], max_terms=b["terms"], max_rects=b["rects"],
@@ -195,7 +240,8 @@ class Annotated:
     def run(self, batch, plan):
         import jax
 
-        with jax.profiler.TraceAnnotation(f"exec:{plan.label}"):
+        label = plan.label if plan is not None else self._ex.algorithm
+        with jax.profiler.TraceAnnotation(f"exec:{label}"):
             return self._ex.run(batch, plan=plan)
 
 
@@ -210,8 +256,10 @@ def warm_plans(ex, stream, batch: dict) -> list:
     t0 = time.perf_counter()
     distinct = {id(q): q for q in stream}
     plan_of = {k: ex.plan_query(q.terms, q.rects, q.amps) for k, q in distinct.items()}
-    plans = {p.label: p for p in plan_of.values()}
-    first = Counter(plan_of[id(q)].label for q in stream[:512])
+    # a fixed algorithm plans nothing (None): the executor's one program
+    label = {k: p.label if p is not None else ex.algorithm for k, p in plan_of.items()}
+    plans = {label[k]: p for k, p in plan_of.items()}
+    first = Counter(label[id(q)] for q in stream[:512])
     print(f"setup planned {time.perf_counter() - t0:.2f}s first512={dict(first)}",
           file=sys.stderr, flush=True)
     B, D, R = batch["batch"], batch["terms"], batch["rects"]
@@ -326,7 +374,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         peaks_of(devs[0].device_kind)
     else:
         devs = jax.devices()
-    dev = devs[0]
+    dev, used = devs[0], devs[: cell.chips]
     counter = CompileCounter()
     log(f"setup start {time.perf_counter() - t_start:.2f}s")
     config, mix = cell.config, cell.mix
@@ -340,8 +388,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
 
         telemetry = Telemetry(metrics=None, tracer=SpanRecorder(), audit=None,
                               events=None)
-    server, ex = build_server(config, corpus, dev, telemetry)
-    raw = ex._ex if isinstance(ex, Annotated) else ex
+    server, ex = build_server(config, corpus, used, telemetry)
+    index = index_leaves(ex._ex if isinstance(ex, Annotated) else ex)
     if wrap_executor is not None:
         server.executor = ex = wrap_executor(ex)
     log(f"setup build+place {time.perf_counter() - t_start:.2f}s")
@@ -381,13 +429,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     window_compiles = counter.requests - n_req
     log(f"window {run.window_s:.2f}s queries={run.queries} chunks={len(run.reports)}"
         f" compile_requests={window_compiles}")
-    stats = dev.memory_stats() or {}
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in used]
     device = {
         "platform": dev.platform,
         "kind": dev.device_kind,
         "count": len(devs),
-        "memory_peak_bytes": stats.get("peak_bytes_in_use", 0),
+        "memory_peak_bytes": max(peaks),
     }
+    if len(used) > 1:
+        device["memory_peak_bytes_each"] = peaks
     if trace:
         device["busy_s"] = run.device.busy_s
         device["window_s"] = run.device.window_s
@@ -399,13 +449,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
 
     # the check, once the program's state is freed
-    for x in jax.tree.leaves(raw.engine.index):
+    for x in index:
         x.delete()
-    del server, ex, raw
+    del server, ex, index
     gc.collect()
     jax.clear_caches()
     t_ref = time.perf_counter()
-    ref = Reference(corpus, config, device=dev)
+    ref = Reference(corpus, config, devices=used)
     log(f"check placed {time.perf_counter() - t_ref:.2f}s")
     b = config["batcher"]
     res = check.compare(queries, answers, ref, b["terms"], b["rects"])

@@ -18,11 +18,16 @@ column, no tables):
 ``dtype`` is the arithmetic's precision: float32 as the configuration
 states it, or bfloat16 for the control that the comparison must fail.
 Documents are processed in blocks of rows so that the pass fits beside
-nothing else on the chip once the program's state is freed.
+nothing else on the chip once the program's state is freed.  Over several
+devices the blocks are spread in runs of consecutive blocks, each scored
+on the device that holds it, and the runs' top-k are merged on the host
+(ties to the lower id): every document's score is computed alone, so the
+answers are the one-device reference's bits.
 """
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -72,8 +77,9 @@ def scores(doc_terms, doc_rects, doc_amps, pagerank, terms, q_rects, q_amps,
     each served document.
 
     terms i32[B, D] (-1 pads), q_rects f32[B, Q, 4], q_amps f32[B, Q],
-    tables f32[B, D, L + 1], served i32[B, k].  Returns (ids i32[B, k],
-    scores f32[B, k], at_served f32[B, k]); -inf marks no document.
+    tables f32[B, D, L + 1], served i32[B, k] (ids of these documents, an
+    id outside them scores -inf).  Returns (ids i32[B, k], scores f32[B, k],
+    at_served f32[B, k]); -inf marks no document.
     """
     w_text, w_geo, w_pr = weights
     B, D = terms.shape
@@ -122,25 +128,45 @@ def scores(doc_terms, doc_rects, doc_amps, pagerank, terms, q_rects, q_amps,
     top, ids = jax.lax.top_k(f, k)
     ids = jnp.where(jnp.isfinite(top), ids, -1)
     at = jnp.take_along_axis(f, jnp.clip(served, 0, f.shape[1] - 1), axis=1)
-    at = jnp.where(served >= 0, at, -jnp.inf)
+    at = jnp.where((served >= 0) & (served < f.shape[1]), at, -jnp.inf)
     return ids, top, at
 
 
-class Reference:
-    """The corpus on the device, and the reference answers over it."""
+@dataclass
+class _Part:
+    """A run of consecutive blocks, on one device."""
 
-    def __init__(self, corpus, config: dict, dtype=jnp.float32, device=None):
-        put = functools.partial(jax.device_put, device=device)
+    first: int  # id of its first document
+    doc_terms: jax.Array
+    doc_rects: jax.Array
+    doc_amps: jax.Array
+    pagerank: jax.Array
+
+
+class Reference:
+    """The corpus on the devices, and the reference answers over it."""
+
+    def __init__(self, corpus, config: dict, dtype=jnp.float32, devices=None,
+                 block_docs: int = BLOCK_DOCS):
+        devices = list(devices or [None])  # None: JAX's default device
         self.n_docs, self.doc_len = corpus.doc_terms.shape
-        self.rows = min(BLOCK_DOCS, self.n_docs)
+        self.rows = min(block_docs, self.n_docs)
         if self.n_docs % self.rows:
             raise ValueError("n_docs must be a multiple of the reference block")
         stored = np.float16 if config["compress"] == "f16" else np.float32
-        self.doc_terms = put(corpus.doc_terms)
         # the rects and amps the configuration stores, decoded to f32
-        self.doc_rects = put(corpus.doc_rects.astype(stored).astype(np.float32))
-        self.doc_amps = put(corpus.doc_amps.astype(stored).astype(np.float32))
-        self.pagerank = put(corpus.pagerank)
+        rects = corpus.doc_rects.astype(stored).astype(np.float32)
+        amps = corpus.doc_amps.astype(stored).astype(np.float32)
+        n_blocks = self.n_docs // self.rows
+        runs = np.array_split(np.arange(n_blocks), min(len(devices), n_blocks))
+        self.parts = []
+        for dev, run in zip(devices, runs):
+            a, b = run[0] * self.rows, (run[-1] + 1) * self.rows
+            put = functools.partial(jax.device_put, device=dev)
+            self.parts.append(_Part(
+                int(a), put(corpus.doc_terms[a:b]), put(rects[a:b]),
+                put(amps[a:b]), put(corpus.pagerank[a:b]),
+            ))
         self.compress = config["compress"]
         w = config["weights"]
         self.weights = (float(w["w_text"]), float(w["w_geo"]), float(w["w_pr"]))
@@ -152,18 +178,32 @@ class Reference:
         batch: terms i32[B, D], rects f32[B, Q, 4], amps f32[B, Q],
         served i32[B, k]."""
         terms = np.asarray(terms, np.int32)
-        df = np.asarray(doc_freqs(self.doc_terms, jnp.asarray(terms.reshape(-1)),
-                                  self.rows))
+        served = np.asarray(served, np.int32)
+        counts = [doc_freqs(p.doc_terms, terms.reshape(-1), self.rows)
+                  for p in self.parts]
+        df = sum(np.asarray(c, np.int64) for c in counts)
         tables = impact_tables(df, self.n_docs, self.doc_len, self.compress)
         tables = tables.reshape(terms.shape + (self.doc_len + 1,))
-        out = scores(
-            self.doc_terms, self.doc_rects, self.doc_amps, self.pagerank,
-            jnp.asarray(terms), jnp.asarray(rects, jnp.float32),
-            jnp.asarray(amps, jnp.float32), jnp.asarray(tables),
-            jnp.asarray(served, jnp.int32), k=self.k, rows=self.rows,
-            dtype=self.dtype, weights=self.weights,
-        )
-        return tuple(np.asarray(x) for x in out)
+        rects = np.asarray(rects, np.float32)
+        amps = np.asarray(amps, np.float32)
+        outs = [
+            scores(
+                p.doc_terms, p.doc_rects, p.doc_amps, p.pagerank, terms, rects,
+                amps, tables, np.where(served >= 0, served - p.first, -1),
+                k=self.k, rows=self.rows, dtype=self.dtype, weights=self.weights,
+            )
+            for p in self.parts
+        ]  # every device's call dispatched before any result is read
+        outs = [[np.asarray(x) for x in o] for o in outs]
+        ids = np.concatenate([np.where(i >= 0, i + p.first, -1)
+                              for (i, _, _), p in zip(outs, self.parts)], axis=1)
+        top = np.concatenate([t for _, t, _ in outs], axis=1)
+        at = np.max([a for _, _, a in outs], axis=0)
+        # merge the runs' top-k: best score first, ties to the lower id
+        order = np.lexsort((ids, -top), axis=-1)[:, : self.k]
+        top = np.take_along_axis(top, order, axis=1)
+        ids = np.take_along_axis(ids, order, axis=1)
+        return np.where(np.isfinite(top), ids, -1).astype(np.int32), top, at
 
 
 def pad_queries(queries, d_terms: int, q_rects: int):

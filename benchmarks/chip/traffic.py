@@ -1,35 +1,26 @@
-"""One general query-stream generator, driven by a traffic mix's data file.
+"""Query streams, driven by a traffic mix's data file.
 
-A mix (``traffic/<name>.json``) sets the parameters:
+A mix (``traffic/<name>.json``) names its generator under ``generator``:
+``generators/<name>.py``, whose ``generate(corpus, mix, seed)`` returns
+the stream.  Every mix sets
 
 * ``queries`` — length of the stream generated (the window takes what it
   needs from the front, in chunks);
 * ``chunk`` — queries a client sends as one closed-loop round;
-* ``pool_size`` / ``pool_seed`` — distinct searches in a pool drawn once
-  from the fixed ``pool_seed``;
-* ``zipf_a`` — the pool is asked with Zipf ranks truncated to the pool:
-  P(rank k) proportional to k^-zipf_a for k = 1..pool_size;
-* ``hot_frac`` / ``n_hot_cities`` — share of searches about the largest
-  cities;
-* ``d_terms`` / ``q_rects`` / ``scales`` — 1..d_terms terms drawn from one
-  document, 1..q_rects rects, and the rect extents in city radii.
 
-The pool is ``repro.corpus.make_zipf_trace``'s pool for the seed
-``pool_seed``, draw for draw.  Each chunk's multiset of searches is drawn
-from ``pool_seed`` too; the run's seed only orders the searches inside
-each chunk.  So every seed does the same work, chunk by chunk.
+and whatever else its generator reads (see each generator's docstring).
+A generator draws the searches from the mix's own fixed seed; the run's
+seed only orders the searches inside each chunk (:func:`shuffle_chunks`),
+so every seed does the same work, chunk by chunk.
 """
 from __future__ import annotations
 
+import importlib.util
+import os
+
 import numpy as np
 
-from benchmarks.chip.corpus import Query, footprint
-
-
-def _doc_terms(rng, corpus, d_terms: int) -> np.ndarray:
-    nt = int(rng.integers(1, d_terms + 1))
-    doc = corpus.doc_terms[rng.integers(0, len(corpus.doc_terms))]
-    return np.unique(rng.choice(doc, size=min(nt, len(doc)), replace=False))
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def zipf_pmf(a: float, n: int) -> np.ndarray:
@@ -38,32 +29,27 @@ def zipf_pmf(a: float, n: int) -> np.ndarray:
     return p / p.sum()
 
 
-def generate(corpus, mix: dict, seed) -> list[Query]:
-    """The stream of ``mix["queries"]`` queries over ``corpus``: the same
-    searches in every chunk for every ``seed``, in the order ``seed``
-    draws inside the chunk."""
-    rng = np.random.default_rng(mix["pool_seed"])
-    n_cities = len(corpus.cities)
-    hot = np.argsort(-corpus.cities[:, 2])[: mix["n_hot_cities"]]
-
-    def one() -> Query:
-        if rng.random() < mix["hot_frac"]:
-            city = int(hot[rng.integers(0, len(hot))])
-        else:
-            city = int(rng.integers(0, n_cities))
-        terms = _doc_terms(rng, corpus, mix["d_terms"])
-        rects, amps = footprint(rng, corpus.cities[city], mix["q_rects"], mix["scales"])
-        return Query(terms.astype(np.int32), rects, amps)
-
-    pool_size = mix["pool_size"]
-    pool = [one() for _ in range(pool_size)]
-    ranks = rng.choice(pool_size, size=mix["queries"], p=zipf_pmf(mix["zipf_a"], pool_size))
+def shuffle_chunks(items: np.ndarray, size: int, seed) -> None:
+    """Permute ``items`` in place inside each run of ``size``, in an order
+    drawn from ``seed``."""
     order = np.random.default_rng(seed)
-    size = mix["chunk"]
-    for s in range(0, len(ranks), size):
-        ranks[s : s + size] = order.permutation(ranks[s : s + size])
-    return [pool[r] for r in ranks]
+    for s in range(0, len(items), size):
+        items[s : s + size] = order.permutation(items[s : s + size])
 
 
-def chunks(stream: list[Query], size: int) -> list[list[Query]]:
+def generator(name: str):
+    """The ``generate(corpus, mix, seed)`` function of ``generators/<name>.py``."""
+    path = os.path.join(HERE, "generators", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"chip_generator_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.generate
+
+
+def generate(corpus, mix: dict, seed) -> list:
+    """The stream of the mix's generator over ``corpus`` for ``seed``."""
+    return generator(mix["generator"])(corpus, mix, seed)
+
+
+def chunks(stream: list, size: int) -> list[list]:
     return [stream[i : i + size] for i in range(0, len(stream), size)]

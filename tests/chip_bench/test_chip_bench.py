@@ -10,6 +10,8 @@ for the chip's shard.
 """
 from __future__ import annotations
 
+import hashlib
+import inspect
 import json
 import os
 import re
@@ -48,11 +50,24 @@ def tiny(cell: str, queries: int = 2048) -> harness.Cell:
     return c
 
 
-def run_tiny(cell: str, seed: int = 5, trace: bool = False, wrap=None) -> dict:
+def run_tiny(cell: str, seed: int = 5, trace: bool = False, wrap=None,
+             seconds: float = 0.2) -> dict:
+    c = tiny(cell)
     return harness.run_cell(
-        tiny(cell), seed, 0.2, trace, time.perf_counter(), ROOT,
+        c, seed, seconds, trace, time.perf_counter(), ROOT,
         require_chip=False, wrap_executor=wrap,
     )
+
+
+def _forced_devices(script: str, n: int = 4, args=()) -> dict:
+    """Run ``script`` on ``n`` forced host devices; its last line is JSON."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n}",
+               PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"), ROOT]))
+    p = subprocess.run([sys.executable, "-c", script, *args], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
 
 
 # ----------------------------------------------------------------------
@@ -348,6 +363,27 @@ def test_seeds_rename_terms_and_reorder_the_same_searches():
     assert len(set(map(search, qa))) > 200
 
 
+def _digest(stream) -> str:
+    h = hashlib.sha256()
+    for q in stream:
+        for a in (q.terms, q.rects, q.amps):
+            h.update(a.tobytes())
+            h.update(f"{a.dtype}{a.shape}".encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed, want", [
+    (1, "8e93f58cc91fbfb39bcacdc57cb809c1345757c4651fd298731c710e6122322d"),
+    (2**31 + 5, "5558e14638ac74aff4c234073a65f7bb8eb913dd9be6d3f5e77f9a2170dafa14"),
+])
+def test_pool_generator_gives_the_zipf_stream_it_always_gave(seed, want):
+    """Terms, rects and amps of every query, as the stream read before the
+    mix named its generator (digests of that stream at this size)."""
+    c = tiny("geoweb.zipf")
+    assert c.mix["generator"] == "pool"
+    assert _digest(harness.inputs(c, seed)[1]) == want
+
+
 # ----------------------------------------------------------------------
 # whole runs on the CPU: the line, the check, the control, the faults
 # ----------------------------------------------------------------------
@@ -411,6 +447,28 @@ def test_planted_fault_makes_the_run_incorrect(fault):
     assert line["correct"] is False and line["failed"] > 0
 
 
+class _Dups(_Fault):
+    """Every batch's first answer names document 0 twice, at its top."""
+
+    def run(self, batch, plan):
+        self._fault.append(plan)  # here: the list of batches run
+        res = self._ex.run(batch, plan=plan)
+        return type(res)(res.ids.at[0, :2].set(0), res.scores.at[0, :2].set(1e9),
+                         res.stats)
+
+
+def test_duplicate_answers_make_the_run_incorrect():
+    """One chunk of 64 answers: every batch's first names a document twice."""
+    batches = []
+    line = run_tiny("geoweb.zipf", seed=2**31 + 4,
+                    wrap=lambda ex: _Dups(ex, batches), seconds=0.0)
+    assert line["attempted"] == 64
+    # one in each of the window's batches (64 queries, batches of at most
+    # 8 of one plan); ``batches`` holds the warm-up's too
+    assert 8 <= line["check"]["dup_ids"]["value"] < len(batches)
+    assert line["correct"] is False
+
+
 @pytest.mark.parametrize("cell", ["geoweb.zipf", "geoweb-cached.zipf"])
 def test_control_in_lower_precision_is_not_correct(cell):
     from benchmarks.chip import control
@@ -419,6 +477,135 @@ def test_control_in_lower_precision_is_not_correct(cell):
     nums = control.readings(c, 21, 192)
     limits = c.config["check"]["limits"]
     assert any(nums[k] > limits[k] for k in nums), nums
+
+
+# ----------------------------------------------------------------------
+# executor layouts and the reference over several devices
+# ----------------------------------------------------------------------
+LAYOUT_KEYS = {"single": {"kind"}, "mesh": {"kind", "routing", "partitioner"}}
+
+
+def _config(name: str) -> dict:
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    return harness.load_json(os.path.join(ROOT, entry["file"]))
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_configuration_names_its_executor_layout(name):
+    """Every configuration's block is one the harness can build: a layout
+    it places on chips, with keys the program's ``make_executor`` takes."""
+    from repro.core.distributed import resolve_partitioner
+    from repro.serving.factory import make_executor
+
+    layout = _config(name)["executor"]
+    assert layout["kind"] in LAYOUT_KEYS
+    assert set(layout) <= LAYOUT_KEYS[layout["kind"]]
+    assert set(layout) - {"kind"} <= set(inspect.signature(make_executor).parameters)
+    if "partitioner" in layout:
+        resolve_partitioner(layout["partitioner"])
+
+
+@pytest.mark.parametrize("name", ["geoweb", "geoweb-cached"])
+def test_one_chip_deployments_keep_the_single_layout(name):
+    assert _config(name)["executor"] == {"kind": "single"}
+
+
+def test_single_layout_places_the_index_on_the_first_device():
+    import jax
+
+    c = tiny("geoweb.zipf")
+    corpus, _ = harness.inputs(c, 1)
+    dev = jax.devices()[0]
+    ex = harness.build_executor(c.config, corpus, [dev])
+    leaves = harness.index_leaves(ex)
+    assert leaves and all(x.devices() == {dev} for x in leaves)
+
+
+MESH_RUN = """
+import json, sys, time
+import jax
+from benchmarks.chip import harness
+
+root = sys.argv[1]
+c = harness.load_cell(root, "geoweb-mesh4.zipf")
+c.config.update(n_docs=4096, n_terms=1024, avg_postings_per_doc=16, grid=64)
+c.mix = dict(c.mix, queries=1024)
+corpus, _ = harness.inputs(c, 1)
+ex = harness.build_executor(c.config, corpus, jax.devices()[: c.chips])
+on = set().union(*(x.devices() for x in harness.index_leaves(ex)))
+del ex
+line = harness.run_cell(c, 2**31 + 13, 0.3, False, time.perf_counter(), root,
+                        require_chip=False)
+line["index_devices"] = len(on)
+line["chips"] = c.chips
+print(json.dumps(line))
+"""
+
+
+def _mesh_deployment(root) -> None:
+    """A four-chip deployment added as files only: a configuration file
+    naming a mesh layout and its entries in ``BENCHMARK.json``."""
+    bench = json.loads(json.dumps(BENCH))
+    config = _config("geoweb")
+    # the mesh's plans have no exhaustive scan: budgets that cover every
+    # query at the CPU size (every shard's postings within max_candidates)
+    config.update(algorithm="text_first", fused=False,
+                  executor={"kind": "mesh", "partitioner": "morton",
+                            "routing": "footprint"})
+    config["budgets"].update(max_candidates=4096, max_tiles=4096, k_sweeps=8,
+                             sweep_budget=4096, prune=False, exact=False)
+    path = "benchmarks/chip/configs/geoweb-mesh4.json"
+    os.makedirs(os.path.join(root, os.path.dirname(path)))
+    with open(os.path.join(root, path), "w") as f:
+        json.dump(config, f)
+    bench["configs"].append(dict(bench["configs"][0], name="geoweb-mesh4", file=path))
+    bench["workloads"].append(dict(bench["workloads"][0], name="geoweb-mesh4.zipf",
+                                   config="geoweb-mesh4", chips=4))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+
+def test_mesh_layout_runs_a_cell_on_four_forced_devices(tmp_path):
+    _mesh_deployment(tmp_path)
+    line = _forced_devices(MESH_RUN, args=[str(tmp_path)])
+    assert line["chips"] == 4 and line["index_devices"] == 4
+    assert line["device"]["count"] == 4
+    assert len(line["device"]["memory_peak_bytes_each"]) == 4
+    assert line["correct"] is True and line["failed"] == 0, line["check"]
+    assert line["attempted"] >= 64
+
+
+REFERENCE_SPREAD = """
+import json
+import jax
+import numpy as np
+from benchmarks.chip import harness
+from benchmarks.chip.reference import Reference, pad_queries
+
+c = harness.load_cell(".", "geoweb.zipf")
+c.config.update(n_docs=4096, n_terms=1024, avg_postings_per_doc=16, grid=64)
+c.mix = dict(c.mix, queries=256)
+corpus, stream = harness.inputs(c, 2**31 + 21)
+one = Reference(corpus, c.config)
+many = Reference(corpus, c.config, devices=jax.devices(), block_docs=512)
+same, found = True, 0
+for s in range(0, 64, 8):
+    t, r, a = pad_queries(stream[s : s + 8], 4, 2)
+    ids0, top0, _ = one.answer(t, r, a, np.full((8, 10), -1, np.int32))
+    served = ids0[:, ::-1]  # documents from several runs of blocks, -1 pads
+    x = one.answer(t, r, a, served)
+    y = many.answer(t, r, a, served)
+    same &= all(np.array_equal(u, v) for u, v in zip(x, y))
+    found += int((ids0 >= 0).sum())
+print(json.dumps({"same": bool(same), "parts": len(many.parts), "found": found,
+                  "devices": len({p.doc_terms.devices().pop() for p in many.parts})}))
+"""
+
+
+def test_reference_over_several_devices_gives_the_one_device_bits():
+    r = _forced_devices(REFERENCE_SPREAD)
+    assert r["parts"] == 4 and r["devices"] == 4
+    assert r["found"] > 100 and r["same"] is True
 
 
 # ----------------------------------------------------------------------
